@@ -9,7 +9,7 @@ Prints ``name,us_per_call,derived`` CSV rows.  Module → paper artifact map:
   bench_loss_eval          — Fig. 4 / Fig. B.12 (loss-eval cost vs DoF)
   bench_operator_learning  — Table 2 (wave operator learning, ID/OOD)
   bench_topo_opt           — Table 3 (cantilever SIMP)
-  bench_kernels            — Pallas kernel microbench (interpret mode)
+  bench_kernels            — Pallas kernel microbench (interpret mode off-TPU)
   bench_transient          — repro.transient rollouts (heat/wave, CSR vs ELL)
   bench_weakform           — fused multi-term WeakForm assemble vs separate+add
   bench_batched_assembly   — vmap-batched multi-instance assembly vs B singles
@@ -49,6 +49,9 @@ def main(argv=None) -> None:
         help="reduced problem sizes (sets BENCH_QUICK=1 for all modules)",
     )
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.quick:
         os.environ["BENCH_QUICK"] = "1"
 

@@ -49,7 +49,13 @@ from ..telemetry import annotate
 from . import forms, weakform
 from .elements import get_element
 from .mesh import FunctionSpace
-from .routing import MatrixRouting, VectorRouting, build_matrix_routing, build_vector_routing
+from .routing import (
+    MatrixRouting,
+    VectorRouting,
+    build_matrix_routing,
+    build_vector_routing,
+    table_segment_sum,
+)
 from .sparse import CSR, BatchedCSR
 
 __all__ = [
@@ -177,24 +183,24 @@ def facet_context(
 # ---------------------------------------------------------------------------
 
 def reduce_matrix(k_local: jnp.ndarray, routing: MatrixRouting, mode: str = "sorted"):
-    """``S_mat · vec(K_local)`` as a deterministic segment reduction."""
+    """``S_mat · vec(K_local)`` as a deterministic segment reduction:
+    ``"sorted"`` is a permutation gather + sorted segment-sum, ``"direct"``
+    gathers each nnz slot's contributions through the routing's static
+    table (no scatter-add — see :mod:`repro.core.routing`)."""
     v = k_local.reshape(-1)
     if mode == "sorted":
-        vals = jax.ops.segment_sum(
+        return jax.ops.segment_sum(
             v[routing.perm_dev],
             routing.seg_ids_dev,
             num_segments=routing.nnz,
             indices_are_sorted=True,
         )
-    else:  # direct scatter-add (one XLA scatter; benchmark comparison)
-        vals = jax.ops.segment_sum(
-            v, routing.seg_ids_unsorted_dev, num_segments=routing.nnz
-        )
-    return vals
+    return table_segment_sum(v, routing.table_dev)
 
 
 def reduce_vector(f_local: jnp.ndarray, routing: VectorRouting, mode: str = "sorted"):
-    """``S_vec · vec(F_local)`` — reduce to touched dofs, scatter once."""
+    """``S_vec · vec(F_local)`` — reduce to touched dofs (modes as in
+    :func:`reduce_matrix`), then spread them to all dofs by a gather."""
     v = f_local.reshape(-1)
     if mode == "sorted":
         packed = jax.ops.segment_sum(
@@ -204,12 +210,9 @@ def reduce_vector(f_local: jnp.ndarray, routing: VectorRouting, mode: str = "sor
             indices_are_sorted=True,
         )
     else:
-        packed = jax.ops.segment_sum(
-            v, routing.seg_ids_unsorted_dev,
-            num_segments=routing.touched.shape[0],
-        )
-    out = jnp.zeros((routing.num_dofs,), dtype=v.dtype)
-    return out.at[routing.touched_dev].set(packed)
+        packed = table_segment_sum(v, routing.table_dev)
+    return jnp.take(packed, routing.spread_dev, axis=-1, mode="fill",
+                    fill_value=0)
 
 
 # ---------------------------------------------------------------------------
@@ -316,10 +319,10 @@ def build_plan(space: FunctionSpace, quad_order: int | None = None,
                reduce_mode: str = "direct") -> AssemblyPlan:
     """Precompute one :class:`AssemblyPlan` for a function space.
 
-    ``reduce_mode``: 'direct' lowers to one XLA scatter-add (2.5× faster on
-    CPU, still deterministic — no atomics in XLA); 'sorted' is the gather +
-    sorted-segment-sum path (TPU-preferred layout).  Both are
-    bit-reproducible; see EXPERIMENTS.md §Perf-FEM.
+    ``reduce_mode``: 'direct' gathers each output slot's contributions
+    through a static padded table and sums them (no scatter-add, which
+    XLA:TPU can only emulate serially in float64); 'sorted' is the
+    permutation gather + sorted segment-sum path.  Both are deterministic.
     """
     mesh, element = space.mesh, space.element
     pts, w = element.default_rule(quad_order)

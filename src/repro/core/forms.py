@@ -123,13 +123,18 @@ def eval_tensor_coefficient(coef, ctx: FormContext, d: int):
 # ---------------------------------------------------------------------------
 
 def diffusion(ctx: FormContext, rho=None) -> jnp.ndarray:
-    """∫ ρ ∇φ_b · ∇φ_a  — Eq. (A.12), the paper's flagship contraction."""
+    """∫ ρ ∇φ_b · ∇φ_a  — Eq. (A.12), the paper's flagship contraction.
+
+    Written as broadcast products and sums, not as one einsum: XLA:TPU
+    lowers that float64 batched dot to bf16 pieces laid out element-major,
+    each tiny (k, d) block padded to a 4×128 tile — 32 GB of temporaries
+    for the 2M triangles of a 1M-DOF mesh.  The elementwise form fuses
+    into one loop with no temporaries."""
     rho_q = eval_coefficient(rho, ctx)
-    # single fused contraction: (K_local)_{eab} = Σ_q ŵ_q|detJ| ρ G_a·G_b
-    return jnp.einsum(
-        "eq,eq,eqai,eqbi->eab", ctx.wdet, rho_q, ctx.grad, ctx.grad,
-        optimize=True,
-    )
+    # (K_local)_{eab} = Σ_q ŵ_q|detJ| ρ G_a·G_b
+    g = ctx.grad                                                # (E, Q, k, d)
+    gg = jnp.sum(g[:, :, :, None, :] * g[:, :, None, :, :], axis=-1)
+    return jnp.sum((ctx.wdet * rho_q)[..., None, None] * gg, axis=1)
 
 
 def anisotropic_diffusion(ctx: FormContext, a=None) -> jnp.ndarray:

@@ -25,6 +25,11 @@ backend        apply path
                all devices (:class:`repro.core.operator.ShardedMatFreeOperator`)
 =============  =============================================================
 
+The two Pallas backends do not compile for a TPU today (Mosaic rejects
+their in-kernel 1-D gather), so on a TPU backend they raise
+``NotImplementedError`` with the compiler's reason instead of building a
+kernel that cannot run; off-TPU they run in the Pallas interpreter.
+
 ``make_matvec(op, backend)`` returns the apply closure;
 ``make_residual(op, backend)`` returns the fused ``(u, f) ↦ K·u − f``
 (the ``ell_pallas`` variant runs the fused
@@ -36,6 +41,8 @@ round-trip).  Third-party backends register with
 from __future__ import annotations
 
 from typing import Callable
+
+import jax
 
 from .. import telemetry
 from .sparse import CSR, csr_to_ell
@@ -73,6 +80,27 @@ def _require_matfree(op):
     return op
 
 
+# what the TPU compiler (Mosaic, JAX 0.9.0, v5e) answers for each Pallas
+# backend's kernel — compiled for a described v5e topology
+_MOSAIC_REFUSALS = {
+    "ell_pallas": "Mosaic rejects the kernel's 1-D gather x[cols] "
+                  "('NotImplementedError: Only 2D gather is supported' in "
+                  "f32; 'ZeroDivisionError: integer modulo by zero' in the "
+                  "f64 lowering)",
+    "ell_stream": "Mosaic rejects the kernel's 1-D gather x[cols] "
+                  "('NotImplementedError: Only 2D gather is supported', f32 "
+                  "and f64)",
+}
+
+
+def _refuse_on_tpu(backend: str) -> None:
+    if jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            f"matvec backend {backend!r} does not compile for a TPU: "
+            f"{_MOSAIC_REFUSALS[backend]}; use 'csr', 'ell' or 'matfree'"
+        )
+
+
 def _csr_matvec(op) -> Callable:
     return op.matvec  # CSR / BatchedCSR / LinearOperator all expose matvec
 
@@ -82,6 +110,7 @@ def _ell_matvec(op) -> Callable:
 
 
 def _ell_pallas_matvec(op) -> Callable:
+    _refuse_on_tpu("ell_pallas")
     from ..kernels import ell_matvec
 
     ell = csr_to_ell(_require_csr(op, "ell_pallas"))
@@ -89,6 +118,7 @@ def _ell_pallas_matvec(op) -> Callable:
 
 
 def _ell_stream_matvec(op) -> Callable:
+    _refuse_on_tpu("ell_stream")
     from ..kernels import ell_matvec_stream
 
     ell = csr_to_ell(_require_csr(op, "ell_stream"))
@@ -127,6 +157,7 @@ def _ell_residual(op) -> Callable:
 
 
 def _ell_pallas_residual(op) -> Callable:
+    _refuse_on_tpu("ell_pallas")
     from ..kernels import ell_residual
 
     ell = csr_to_ell(_require_csr(op, "ell_pallas"))
@@ -134,6 +165,7 @@ def _ell_pallas_residual(op) -> Callable:
 
 
 def _ell_stream_residual(op) -> Callable:
+    _refuse_on_tpu("ell_stream")
     from ..kernels import ell_residual_stream
 
     ell = csr_to_ell(_require_csr(op, "ell_stream"))

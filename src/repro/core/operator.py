@@ -108,15 +108,21 @@ class LinearOperator:
 # fall back to forming K_e (still matrix-free at the global level).
 # ---------------------------------------------------------------------------
 
+# the diffusion action and diagonal are broadcast products and sums, not
+# einsums, for the reason given in forms.diffusion (float64 batched dots
+# with tiny minor dims blow up in HBM and compile slowly on a TPU)
 def _diffusion_act(ctx, vs, xe, rho=None):
     rho_q = forms.eval_coefficient(rho, ctx)
-    gu = jnp.einsum("eqai,ea->eqi", ctx.grad, xe)
-    return jnp.einsum("eqai,eqi->ea", ctx.grad, (ctx.wdet * rho_q)[..., None] * gu)
+    g = ctx.grad                                        # (E, Q, k, d)
+    gu = jnp.sum(g * xe[:, None, :, None], axis=2)      # (E, Q, d)
+    flux = (ctx.wdet * rho_q)[..., None] * gu
+    return jnp.sum(g * flux[:, :, None, :], axis=(1, 3))
 
 
 def _diffusion_diag(ctx, vs, rho=None):
     rho_q = forms.eval_coefficient(rho, ctx)
-    return jnp.einsum("eq,eq,eqai,eqai->ea", ctx.wdet, rho_q, ctx.grad, ctx.grad)
+    return jnp.sum((ctx.wdet * rho_q)[..., None]
+                   * jnp.sum(ctx.grad * ctx.grad, axis=-1), axis=1)
 
 
 def _mass_act(ctx, vs, xe, c=None):
@@ -555,6 +561,27 @@ class ShardedMatFreeOperator(LinearOperator):
 
     def diagonal(self) -> jnp.ndarray:
         return _sharded_diag_jit(self)
+
+    def element_shards(self) -> dict:
+        """The element-aligned arrays of one apply (cell DoFs, segment ids,
+        per-element geometry and coefficient leaves, padded to a multiple of
+        the device count), placed on the mesh with the same partition specs
+        the apply's ``shard_map`` splits them by — to check where the
+        element work lands.  The apply does not use this."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        (_, _, cd, seg, _, geo, geo_specs, leaves_p, leaf_specs,
+         _) = _shard_scaffold(self)
+        shard = P(self.axis_name)
+        named = {"cell_dofs": (cd, shard), "segment_ids": (seg, shard)}
+        named.update((f"geometry_{i}", a) for i, a in
+                     enumerate(zip(geo, geo_specs)))
+        named.update((f"leaf_{i}", a) for i, a in
+                     enumerate(zip(leaves_p, leaf_specs)))
+        return {
+            name: jax.device_put(a, NamedSharding(self.mesh, spec))
+            for name, (a, spec) in named.items() if spec == shard
+        }
 
 
 def _shard_scaffold(sop: ShardedMatFreeOperator):

@@ -12,18 +12,60 @@ sort-based deterministic reduction (see DESIGN.md §2):
   seg_ids)`` — mathematically identical to ``S_mat · vec(K_local)``,
   deterministic, no atomics.
 
-A "direct" variant (unsorted ``segment_sum``, i.e. one XLA scatter-add) is
-kept for benchmarking the two lowering strategies.
+The default "direct" Reduce gathers each segment's contributions through a
+static padded table (:func:`segment_table`) and sums them densely — no
+scatter-add.  XLA:TPU has no native float64 scatter-add (it emulates one
+with a serial two-word scatter), while a float64 gather is two native f32
+gathers.  The "sorted" variant above is kept for comparison.
 """
 
 from __future__ import annotations
 
 import dataclasses
+from functools import cached_property
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["MatrixRouting", "VectorRouting", "build_matrix_routing", "build_vector_routing"]
+__all__ = [
+    "MatrixRouting",
+    "VectorRouting",
+    "build_matrix_routing",
+    "build_vector_routing",
+    "segment_table",
+    "table_segment_sum",
+]
+
+
+def segment_table(perm: np.ndarray, seg_ids: np.ndarray,
+                  num_segments: int) -> np.ndarray:
+    """``(L, num_segments)`` int32 positions of each segment's entries, from
+    the sort permutation ``perm`` and the sorted ``seg_ids`` it produces;
+    ``L`` is the largest segment and padding points past the end (gathered
+    as zero by :func:`table_segment_sum`).  Segments run along the minor
+    axis: a TPU lays out a tiny minor axis of size L padded to 128 lanes,
+    which made the summed gather take minutes to compile at 1M rows."""
+    seg_ids = np.asarray(seg_ids)
+    counts = np.bincount(seg_ids, minlength=num_segments)
+    width = max(int(counts.max()) if counts.size else 0, 1)
+    slot = np.arange(seg_ids.size) - (np.cumsum(counts) - counts)[seg_ids]
+    table = np.full((width, num_segments), seg_ids.size, dtype=np.int32)
+    table[slot, seg_ids] = perm
+    return table
+
+
+def _staged(table: np.ndarray) -> jnp.ndarray:
+    # a concrete device array even when first asked for inside a trace
+    with jax.ensure_compile_time_eval():
+        return jnp.asarray(table)
+
+
+def table_segment_sum(v: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
+    """Segment sums of the last axis of ``v`` over a :func:`segment_table`:
+    one gather and a dense sum per segment."""
+    return jnp.sum(jnp.take(v, table, axis=-1, mode="fill", fill_value=0),
+                   axis=-2)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,9 +91,11 @@ class MatrixRouting:
     def __post_init__(self):
         object.__setattr__(self, "perm_dev", jnp.asarray(self.perm))
         object.__setattr__(self, "seg_ids_dev", jnp.asarray(self.seg_ids))
-        object.__setattr__(
-            self, "seg_ids_unsorted_dev", jnp.asarray(self.seg_ids_unsorted)
-        )
+
+    @cached_property
+    def table_dev(self) -> jnp.ndarray:
+        """(L, nnz) :func:`segment_table` of the local slots, on device."""
+        return _staged(segment_table(self.perm, self.seg_ids, self.nnz))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,10 +112,22 @@ class VectorRouting:
     def __post_init__(self):
         object.__setattr__(self, "perm_dev", jnp.asarray(self.perm))
         object.__setattr__(self, "seg_ids_dev", jnp.asarray(self.seg_ids))
-        object.__setattr__(
-            self, "seg_ids_unsorted_dev", jnp.asarray(self.seg_ids_unsorted)
-        )
         object.__setattr__(self, "touched_dev", jnp.asarray(self.touched))
+
+    @cached_property
+    def spread_dev(self) -> jnp.ndarray:
+        """(num_dofs,) position of each DoF in ``touched`` (past the end if
+        untouched): spreads the packed sums by a gather, not a scatter."""
+        spread = np.full(self.num_dofs, self.touched.shape[0], np.int32)
+        spread[self.touched] = np.arange(self.touched.shape[0])
+        return _staged(spread)
+
+    @cached_property
+    def table_dev(self) -> jnp.ndarray:
+        """(L, n_touched) :func:`segment_table` of the local slots, on
+        device."""
+        return _staged(
+            segment_table(self.perm, self.seg_ids, self.touched.shape[0]))
 
 
 def build_matrix_routing(

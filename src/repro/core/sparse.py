@@ -12,10 +12,13 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .routing import _staged, segment_table
 
 __all__ = ["CSR", "ELL", "BatchedCSR", "csr_to_ell", "ell_layout"]
 
@@ -44,11 +47,84 @@ def _dev(x) -> jnp.ndarray:
     return arr
 
 
+# per-pattern row layouts of the CSR entries, keyed like the device mirrors
+# (strong reference to the key array, FIFO-bounded)
+_ROW_LAYOUTS: dict[int, tuple[np.ndarray, "RowLayout"]] = {}
+_ROW_LAYOUTS_LIMIT = 128
+
+
+class RowLayout(NamedTuple):
+    """How :meth:`CSR.matvec` reaches each row's entries, by gathers only.
+
+    ``table`` ``(K, rows)`` holds positions into ``vals`` (padding past the
+    end, read as zero).  With ``offsets`` (a static tuple) slot k of row i
+    is column ``i + offsets[k]`` — the few diagonals of a banded pattern,
+    read as shifted slices of ``x``; otherwise ``cols`` ``(K, rows)`` holds
+    the columns (padding: i itself) and ``x`` is gathered.
+    """
+
+    table: jnp.ndarray
+    cols: jnp.ndarray | None = None
+    offsets: tuple | None = None
+
+
+def _row_layout(csr) -> RowLayout:
+    """The pattern's :class:`RowLayout`: diagonals when a square pattern has
+    at most four times as many distinct column offsets as its widest row
+    has entries, a column table otherwise."""
+    hit = _ROW_LAYOUTS.get(id(csr.row_of_nnz))
+    if hit is not None:
+        return hit[1]
+    rows = np.asarray(csr.row_of_nnz)
+    indices = np.asarray(csr.indices)
+    offsets = np.unique(indices - rows)
+    table = segment_table(np.arange(rows.size), rows, csr.shape[0])
+    if csr.shape[0] == csr.shape[1] and offsets.size <= 4 * table.shape[0]:
+        diag_table = np.full((offsets.size, csr.shape[0]), rows.size,
+                             dtype=np.int32)
+        diag_table[np.searchsorted(offsets, indices - rows), rows] = \
+            np.arange(rows.size)
+        layout = RowLayout(_staged(diag_table),
+                           offsets=tuple(int(o) for o in offsets))
+    else:
+        pad = table >= rows.size
+        cols = np.where(pad, np.arange(csr.shape[0])[None, :],
+                        indices[np.where(pad, 0, table)])
+        layout = RowLayout(_staged(table), cols=_staged(cols.astype(np.int32)))
+    while len(_ROW_LAYOUTS) >= _ROW_LAYOUTS_LIMIT:
+        _ROW_LAYOUTS.pop(next(iter(_ROW_LAYOUTS)))
+    _ROW_LAYOUTS[id(csr.row_of_nnz)] = (csr.row_of_nnz, layout)
+    return layout
+
+
+def _shifted(x: jnp.ndarray, offsets: tuple) -> jnp.ndarray:
+    """``(..., K, n)``: row k holds ``x[i + offsets[k]]`` (zero outside)."""
+    n = x.shape[-1]
+    lo, hi = max(0, -min(offsets)), max(0, max(offsets))
+    xp = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(lo, hi)])
+    return jnp.stack([jax.lax.slice_in_dim(xp, lo + o, lo + o + n, axis=-1)
+                      for o in offsets], axis=-2)
+
+
+def row_layout_matvec(vals, x, layout: RowLayout, *, square: bool = True):
+    """The :meth:`CSR.matvec` arithmetic on a :class:`RowLayout` (whose
+    arrays may be traced)."""
+    a = jnp.take(vals, layout.table, axis=-1, mode="fill", fill_value=0)
+    if layout.offsets is not None:
+        xc = _shifted(x, layout.offsets)
+    else:
+        xc = jnp.take(x, layout.cols, axis=-1)
+    if not square:
+        return jnp.sum(a * xc, axis=-2)
+    return jnp.sum(a * (xc - x[..., None, :]), axis=-2) + jnp.sum(a, axis=-2) * x
+
+
 def clear_device_mirrors():
     """Release every cached (host, device) pattern-array pair, ELL layout and
     operator diagonal — part of the ``repro.core.clear_assembly_caches``
     memory-release path."""
     _DEVICE_MIRRORS.clear()
+    _ROW_LAYOUTS.clear()
     _ELL_LAYOUTS.clear()
     _DIAGONALS.clear()
 
@@ -120,14 +196,14 @@ class CSR:
 
     # -- ops ---------------------------------------------------------------
     def matvec(self, x: jnp.ndarray) -> jnp.ndarray:
-        """y = A @ x via gather + sorted segment-sum (deterministic)."""
-        contrib = self.vals * x[_dev(self.indices)]
-        return jax.ops.segment_sum(
-            contrib,
-            _dev(self.row_of_nnz),
-            num_segments=self.shape[0],
-            indices_are_sorted=True,
-        )
+        """y = A @ x row by row through the static row layout — gathers and
+        dense sums, no scatter-add (see :mod:`repro.core.routing`).  A
+        square operator is applied as ``y_i = Σ_j a_ij (x_j − x_i) +
+        (Σ_j a_ij) x_i``: a stiffness row's O(1) terms would otherwise
+        cancel down to its O(h²) result, which costs the digits that
+        XLA:TPU's float64 emulation does not carry."""
+        return row_layout_matvec(self.vals, x, _row_layout(self),
+                                 square=self.shape[0] == self.shape[1])
 
     def rmatvec(self, x: jnp.ndarray) -> jnp.ndarray:
         """y = A.T @ x (scatter over columns)."""

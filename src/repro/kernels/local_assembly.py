@@ -12,6 +12,11 @@ the systolic array; lane-parallelism is the TPU-idiomatic fusion).
 Grid:      (ceil(E / BE),)
 BlockSpecs: coords (k·d, BE) VMEM;  rho (1, BE) VMEM;  out (k², BE) VMEM.
 BE = 2048 lanes → VMEM footprint ≈ (kd + 1 + k²)·BE·4B ≈ 210 KB (tri, f32).
+
+``interpret`` resolves from the platform (:mod:`repro.kernels.interpret`).
+The kernel does not compile for a TPU today: in f64 the x64 rewriter cannot
+pass the ``tpu_custom_call``, and in f32 Mosaic fails to legalize
+``func.return``.
 """
 
 from __future__ import annotations
@@ -21,6 +26,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .interpret import resolve_interpret
 
 __all__ = ["local_stiffness_p1_kernel", "local_stiffness_p1"]
 
@@ -97,10 +104,16 @@ def _tet_kernel(coords_ref, rho_ref, out_ref):
             )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "block_e"))
 def local_stiffness_p1(coords: jnp.ndarray, rho: jnp.ndarray, *,
-                       interpret: bool = True, block_e: int = BLOCK_E):
+                       interpret: bool | None = None, block_e: int = BLOCK_E):
     """coords (E, k, d) AoS, rho (E,) → (E, k, k); dispatches on d."""
+    return _local_stiffness_p1(coords, rho,
+                               interpret=resolve_interpret(interpret),
+                               block_e=block_e)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block_e"))
+def _local_stiffness_p1(coords, rho, *, interpret: bool, block_e: int):
     e, k, d = coords.shape
     assert k == d + 1 and d in (2, 3)
     kernel = _tri_kernel if d == 2 else _tet_kernel

@@ -1,8 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels, with backend dispatch.
 
-``interpret`` resolves inside :mod:`repro.kernels.spmv_ell` from the active
-JAX backend (Mosaic on TPU, the DMA-emulating interpreter elsewhere), with
-``REPRO_PALLAS_INTERPRET`` / per-call ``interpret=`` overrides.
+``interpret`` resolves from the active JAX backend
+(:mod:`repro.kernels.interpret`: Mosaic on TPU, the DMA-emulating
+interpreter elsewhere); tests pass ``interpret=`` explicitly.
 
 Two SpMV memory plans back the ELL operators (kernel module docstring):
 the broadcast plan (:func:`ell_matvec`) replicates ``x`` into VMEM per row
@@ -15,7 +15,6 @@ from __future__ import annotations
 
 from .local_assembly import local_stiffness_p1
 from .spmv_ell import (
-    _interpret_default,
     autotune_stream,
     galerkin_residual_ell,
     galerkin_residual_ell_stream,
@@ -35,8 +34,7 @@ __all__ = [
 
 def batch_map_stiffness(coords, rho, *, interpret: bool | None = None):
     """Stage-I Batch-Map for P1 simplices: (E,k,d),(E,) → (E,k,k)."""
-    itp = _interpret_default() if interpret is None else interpret
-    return local_stiffness_p1(coords, rho, interpret=itp)
+    return local_stiffness_p1(coords, rho, interpret=interpret)
 
 
 def ell_matvec(ell, x, *, interpret: bool | None = None):

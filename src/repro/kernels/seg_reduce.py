@@ -10,6 +10,10 @@ lane-parallel gather+sum shape as the ELL SpMV kernel:
     vals[n] = Σ_l  vec(K_local ‖ 0)[ idx[n, l] ]
 
 Grid: (ceil(nnz / BN),); blocks (BN, L) indices + broadcast source vector.
+
+``interpret`` resolves from the platform (:mod:`repro.kernels.interpret`).
+The kernel does not compile for a TPU today: its 1-D ``jnp.take`` gather is
+the one Mosaic rejects in the ELL SpMV (``Only 2D gather is supported``).
 """
 
 from __future__ import annotations
@@ -22,6 +26,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from ..telemetry import annotate
+from .interpret import resolve_interpret
 
 __all__ = ["build_padded_reduce", "seg_reduce"]
 
@@ -47,10 +52,15 @@ def _kernel(idx_ref, src_ref, out_ref):
     out_ref[...] = jnp.sum(jnp.take(src, idx, axis=0), axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "block_n"))
 def seg_reduce(local_vals: jnp.ndarray, padded_idx: jnp.ndarray, *,
-               interpret: bool = True, block_n: int = BLOCK_N):
+               interpret: bool | None = None, block_n: int = BLOCK_N):
     """local_vals: (E, ka, kb) or flat (E·ka·kb,) → (nnz,) global CSR vals."""
+    return _seg_reduce(local_vals, padded_idx,
+                       interpret=resolve_interpret(interpret), block_n=block_n)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block_n"))
+def _seg_reduce(local_vals, padded_idx, *, interpret: bool, block_n: int):
     v = local_vals.reshape(-1)
     src = jnp.concatenate([v, jnp.zeros((1,), v.dtype)])       # sentinel 0
     nnz, l = padded_idx.shape

@@ -27,10 +27,18 @@ inflates ``W`` toward N and the plan degenerates to the broadcast one —
 ``stream_window`` is recorded through :mod:`repro.telemetry` so regressions
 are visible.
 
-``interpret`` resolves from the active JAX backend: the Mosaic path on TPU,
+``interpret`` resolves from the active JAX backend
+(:func:`repro.kernels.interpret.resolve_interpret`): the Mosaic path on TPU,
 the (DMA-emulating) interpreter elsewhere — so CPU CI runs the same kernel
-logic and real hardware never silently interprets.  Override per call
-(``interpret=``) or per process (``REPRO_PALLAS_INTERPRET=0/1``).
+logic and real hardware never silently interprets.  Tests override it per
+call (``interpret=``).
+
+Neither plan compiles for a TPU today: Mosaic rejects the in-kernel 1-D
+gather ``jnp.take(x, cols)`` with ``NotImplementedError: Only 2D gather is
+supported`` (f32 and f64), and the broadcast plan's f64 lowering also fails
+with ``ZeroDivisionError: integer modulo by zero``.  The ``ell_pallas`` /
+``ell_stream`` matvec backends therefore refuse a TPU backend up front
+(:mod:`repro.core.matvec`).
 
 The fused residual variants compute ``r = K·u − f`` in the same kernel — the
 TensorPILS training objective's inner op (one pass, no extra HBM round-trip).
@@ -39,7 +47,6 @@ TensorPILS training objective's inner op (one pass, no extra HBM round-trip).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +56,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import telemetry
 from ..telemetry import annotate
+from .interpret import resolve_interpret
 
 __all__ = [
     "spmv_ell",
@@ -62,18 +70,6 @@ __all__ = [
 BLOCK_N = 4096
 N_BUFFERS = 2           # double buffering: DMA block b+1 while computing b
 _LANE = 128             # 1-D window length granularity (TPU lane count)
-
-
-def _interpret_default() -> bool:
-    """Interpret only off-TPU; ``REPRO_PALLAS_INTERPRET=0/1`` overrides."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "")
-    if env not in ("", None):
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
-def _resolve_interpret(interpret: bool | None) -> bool:
-    return _interpret_default() if interpret is None else bool(interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -187,7 +183,7 @@ def spmv_ell(vals: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray, *,
              interpret: bool | None = None, block_n: int = BLOCK_N):
     """vals/cols (N, L), x (N,) → y (N,) — broadcast plan.  Padded cols must
     self-reference rows with zero vals (the ELL builder guarantees this)."""
-    itp = _resolve_interpret(interpret)
+    itp = resolve_interpret(interpret)
     cols_p, _ = _staged_cols(cols, block_n)
     return _spmv_ell_padded(vals, cols_p, x, interpret=itp, block_n=block_n)
 
@@ -195,7 +191,7 @@ def spmv_ell(vals: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray, *,
 def galerkin_residual_ell(vals, cols, u, f, *, interpret: bool | None = None,
                           block_n: int = BLOCK_N):
     """Fused r = K·u − f (TensorPILS inner op) — broadcast plan."""
-    itp = _resolve_interpret(interpret)
+    itp = resolve_interpret(interpret)
     cols_p, _ = _staged_cols(cols, block_n)
     return _residual_ell_padded(vals, cols_p, u, f, interpret=itp,
                                 block_n=block_n)
@@ -414,7 +410,7 @@ def spmv_ell_stream(vals: jnp.ndarray, cols, x: jnp.ndarray, *,
             "window schedule is a host precompute); pass the ELL layout's "
             "numpy cols, or use spmv_ell for traced columns"
         )
-    itp = _resolve_interpret(interpret)
+    itp = resolve_interpret(interpret)
     plan = _stream_plan(cols, block_n)
     return _spmv_stream_padded(
         vals, jnp.asarray(plan.cols_local), _stream_x(x, plan),
@@ -433,7 +429,7 @@ def galerkin_residual_ell_stream(vals, cols, u, f, *,
             "galerkin_residual_ell_stream needs a static column table; use "
             "galerkin_residual_ell for traced columns"
         )
-    itp = _resolve_interpret(interpret)
+    itp = resolve_interpret(interpret)
     plan = _stream_plan(cols, block_n)
     return _residual_stream_padded(
         vals, jnp.asarray(plan.cols_local), _stream_x(u, plan), f,

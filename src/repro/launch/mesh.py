@@ -17,12 +17,8 @@ __all__ = ["make_production_mesh", "make_host_mesh"]
 
 
 def _make_mesh(shape, axes):
-    import jax.sharding as jsh
-
-    if hasattr(jsh, "AxisType"):  # jax >= 0.5: explicit-sharding axis types
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jsh.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -25,6 +25,8 @@ import sys
 
 import jax.numpy as jnp
 
+from .compile_cache import configure_compile_cache
+
 
 def _run_smoke() -> int:
     from .. import serve, telemetry
@@ -82,6 +84,7 @@ def main(argv=None) -> int:
     ap.add_argument("--jsonl", default=None,
                     help="append telemetry metric rows (BENCH_JSON) here")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     if args.smoke:
         return _run_smoke()

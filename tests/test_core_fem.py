@@ -1,5 +1,7 @@
 """Unit + integration tests for the TensorGalerkin core (assembly, solvers, BCs)."""
 
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,7 @@ from repro.core import (
     unit_cube_tet,
     unit_square_tri,
 )
+from repro.core import weakform as wf
 from repro.core.elements import get_element
 from repro.core.mesh import element_for_mesh
 from repro.core.quadrature import triangle_rule, tetrahedron_rule, quad_rule
@@ -114,6 +117,54 @@ def test_reduce_modes_agree():
         np.asarray(a_direct.assemble_stiffness().vals),
         atol=1e-13,
     )
+
+
+@pytest.mark.parametrize("batch", [(), (3,)])
+def test_table_segment_sum_matches_segment_sum(batch):
+    """The scatter-free Reduce: gathering each segment's entries through a
+    padded static table sums what segment_sum sums, empty segments
+    included, over any leading batch axes."""
+    from repro.core.routing import segment_table, table_segment_sum
+
+    rng = np.random.default_rng(3)
+    n_seg, size = 40, 300
+    ids = rng.integers(0, n_seg - 5, size)           # last segments empty
+    perm = np.argsort(ids, kind="stable")
+    table = segment_table(perm, ids[perm], n_seg)
+    assert table.shape == (np.bincount(ids).max(), n_seg)
+    v = jnp.asarray(rng.standard_normal(batch + (size,)))
+    want = np.stack([np.asarray(jax.ops.segment_sum(row, ids,
+                                                    num_segments=n_seg))
+                     for row in np.asarray(v).reshape(-1, size)])
+    got = np.asarray(table_segment_sum(v, jnp.asarray(table)))
+    np.testing.assert_allclose(got.reshape(-1, n_seg), want, atol=1e-13)
+
+
+@pytest.mark.parametrize("mesh,diagonals", [
+    (lambda: unit_square_tri(9), True),    # banded: 9 column offsets
+    (lambda: disk_tri(4), False),          # unstructured numbering
+])
+def test_csr_matvec_row_layouts_match_dense(mesh, diagonals):
+    """CSR.matvec reads rows as shifted diagonals on a banded pattern and
+    through a column table otherwise; both equal the dense product, for
+    one vector and for a batch of operators."""
+    from repro.core.sparse import _row_layout
+
+    m = mesh()
+    space = FunctionSpace(m, element_for_mesh(m))
+    k = GalerkinAssembler(space).assemble(wf.diffusion() + 0.5 * wf.mass())
+    assert (_row_layout(k).offsets is not None) == diagonals
+    rng = np.random.default_rng(5)
+    x = rng.standard_normal(k.shape[0])
+    np.testing.assert_allclose(np.asarray(k.matvec(jnp.asarray(x))),
+                               np.asarray(k.to_dense()) @ x, atol=1e-12)
+    vals = jnp.stack([k.vals, 2.0 * k.vals])
+    xb = jnp.asarray(rng.standard_normal((2, k.shape[0])))
+    got = jax.vmap(lambda v, xi: dataclasses.replace(k, vals=v).matvec(xi))(
+        vals, xb)
+    want = np.stack([np.asarray(k.to_dense()) @ np.asarray(xb[0]),
+                     2.0 * np.asarray(k.to_dense()) @ np.asarray(xb[1])])
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-12)
 
 
 def test_assembly_deterministic():

@@ -193,16 +193,31 @@ def test_autotune_stream_returns_valid_config():
 
 
 def test_interpret_default_resolution(monkeypatch):
-    """interpret resolves from the active backend (off-TPU → interpret),
-    with the env var overriding in both directions."""
-    from repro.kernels.spmv_ell import _interpret_default
+    """interpret resolves from the active backend (off-TPU → interpret);
+    an explicit ``interpret=`` wins."""
+    from repro.kernels.interpret import resolve_interpret
 
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    assert _interpret_default() == (jax.default_backend() != "tpu")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert _interpret_default() is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert _interpret_default() is True
+    assert resolve_interpret() == (jax.default_backend() != "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False
+    assert resolve_interpret(True) is True
+
+
+@pytest.mark.parametrize("backend", ["ell_pallas", "ell_stream"])
+def test_pallas_backends_refuse_tpu(monkeypatch, backend):
+    """Neither Pallas SpMV compiles for a TPU (Mosaic rejects the 1-D
+    gather), so selecting one on a TPU backend raises up front instead of
+    building a kernel that cannot run."""
+    from repro.core import make_matvec, make_residual, weakform as wf
+
+    m = unit_square_tri(4)
+    space = FunctionSpace(m, element_for_mesh(m))
+    k = GalerkinAssembler(space).assemble(wf.diffusion())
+    make_matvec(k, backend)  # off-TPU: builds (runs interpreted)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for factory in (make_matvec, make_residual):
+        with pytest.raises(NotImplementedError, match="Only 2D gather"):
+            factory(k, backend)
 
 
 # ---------------------------------------------------------------------------

@@ -94,6 +94,24 @@ def test_sharded_handles_nondivisible_element_count():
         np.asarray(sop.matvec(x)), np.asarray(op.matvec(x)), atol=1e-12)
 
 
+@pytest.mark.parametrize("store", ["coords", "context", "local"])
+def test_sharded_element_arrays_span_the_mesh(store):
+    """Every element-aligned array of the apply is split over all devices
+    of the mesh, one padded block of elements per device."""
+    m, space, plan = _setup(9)
+    rho = jnp.asarray(RNG.uniform(0.5, 2.0, m.num_cells))
+    sop = matfree_operator(plan, wf.diffusion(rho), store=store).sharded()
+    devices = set(sop.mesh.devices.flat)
+    ndev = len(devices)
+    shards = sop.element_shards()
+    assert {"cell_dofs", "segment_ids"} <= set(shards)
+    for name, a in shards.items():
+        assert a.sharding.device_set == devices, name
+        assert a.shape[0] == -(-m.num_cells // ndev) * ndev, name
+        assert {s.data.shape[0] for s in a.addressable_shards} \
+            == {a.shape[0] // ndev}, name
+
+
 def test_sharded_vector_valued_space():
     m, space, plan = _setup(6, value_size=2)
     form = wf.elasticity(1.2, 0.6)

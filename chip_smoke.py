@@ -24,7 +24,8 @@ runs in this process, unchanged.
 
 Four chips (``--four-chips``): the same 1,002,001-DOF solve with the
 ``matfree_sharded`` backend over all four chips against ``matfree`` on one
-chip, the sharded element arrays checked to span four devices, and
+chip, the element arrays that the compiled sharded apply receives checked
+to be split over the four chips, and
 ``assemble_sharded`` CSR values against ``assemble``.
 """
 
@@ -133,8 +134,10 @@ def solve_phase(resolution: int = RESOLUTION) -> None:
     h = 1.0 / resolution
     err = float(np.max(np.abs(np.asarray(res.u) - _exact(prob))))
     bound = ERROR_CONSTANT * h * h
+    solver_relres = float(info.residual) / float(np.linalg.norm(np.asarray(f)))
     print(f"solve: iterations {int(info.iters)}, converged "
-          f"{bool(info.converged)}, host relres {relres:.3e} "
+          f"{bool(info.converged)}, solver relres {solver_relres:.3e}, "
+          f"host relres {relres:.3e} "
           f"(bound {RESIDUAL_BOUND:.0e}), max nodal error {err:.3e} "
           f"(bound {ERROR_CONSTANT}·h² = {bound:.3e})")
     print(f"solve: first assembly {t_asm:.3f}s, cold solve {cold:.3f}s "
@@ -205,6 +208,26 @@ def serve_phase(resolution: int = SERVE_RESOLUTION) -> None:
     _check(rc == 0, f"repro.launch.serve --smoke returned {rc}")
 
 
+def _element_input_splits(sop, n_cells: int) -> dict:
+    """How the compiled sharded apply receives each element-aligned array
+    of the operator: ``{leaf: (devices, blocks along the element axis)}``,
+    read from the input shardings of the compiled program (arrays the apply
+    does not read are left out)."""
+    import jax
+
+    x = jax.ShapeDtypeStruct((sop.shape[0],), np.float64)
+    compiled = jax.jit(lambda s, v: s.matvec(v)).lower(sop, x).compile()
+    pairs = jax.tree.map(lambda a, s: (np.shape(a), s), sop,
+                         compiled.input_shardings[0][0])
+    flat, _ = jax.tree_util.tree_flatten_with_path(
+        pairs, is_leaf=lambda t: isinstance(t, tuple) and len(t) == 2
+        and isinstance(t[0], tuple))
+    return {jax.tree_util.keystr(path[-1:]): (len(s.device_set),
+                                         n_cells // s.shard_shape(shape)[0])
+            for path, (shape, s) in flat
+            if s is not None and shape[:1] == (n_cells,)}
+
+
 def four_chip_phase(resolution: int = RESOLUTION, n_devices: int = 4) -> None:
     import jax
 
@@ -218,11 +241,12 @@ def four_chip_phase(resolution: int = RESOLUTION, n_devices: int = 4) -> None:
           f"{len(jax.devices())} devices")
 
     sop = matfree_operator(prob.plan, wf.diffusion()).sharded()
-    spans = {name: len(a.sharding.device_set)
-             for name, a in sop.element_shards().items()}
-    print(f"four chips: devices spanned by each element array {spans}")
-    _check(all(v == n_devices for v in spans.values()),
-           f"every sharded element array spans {n_devices} devices")
+    splits = _element_input_splits(sop, prob.mesh.num_cells)
+    print(f"four chips: element arrays of the compiled sharded apply, "
+          f"(devices, blocks along the element axis): {splits}")
+    _check(bool(splits) and all(v == (n_devices, n_devices)
+                                for v in splits.values()),
+           f"every element array is split over {n_devices} devices")
 
     def run(backend):
         return prob.solve(f=_source, spec=spec, backend=backend,
@@ -299,8 +323,7 @@ def main(argv=None) -> int:
         print(f"failed phases: {failed}", file=sys.stderr)
         return 1
     print(json.dumps({"ok": True, "device": {
-        "platform": dev.platform, "kind": dev.device_kind,
-        "count": len(devices)}}))
+        "platform": dev.platform, "kind": dev.device_kind, "count": want}}))
     return 0
 
 

@@ -54,6 +54,7 @@ from .routing import (
     VectorRouting,
     build_matrix_routing,
     build_vector_routing,
+    scatter_free,
     table_segment_sum,
 )
 from .sparse import CSR, BatchedCSR
@@ -183,25 +184,33 @@ def facet_context(
 # ---------------------------------------------------------------------------
 
 def reduce_matrix(k_local: jnp.ndarray, routing: MatrixRouting, mode: str = "sorted"):
-    """``S_mat · vec(K_local)`` as a deterministic segment reduction:
-    ``"sorted"`` is a permutation gather + sorted segment-sum, ``"direct"``
-    gathers each nnz slot's contributions through the routing's static
-    table (no scatter-add — see :mod:`repro.core.routing`)."""
+    """``S_mat · vec(K_local)`` as a deterministic segment reduction, in one
+    of the modes of :mod:`repro.core.routing`."""
     v = k_local.reshape(-1)
     if mode == "sorted":
-        return jax.ops.segment_sum(
+        vals = jax.ops.segment_sum(
             v[routing.perm_dev],
             routing.seg_ids_dev,
             num_segments=routing.nnz,
             indices_are_sorted=True,
         )
-    return table_segment_sum(v, routing.table_dev)
+    elif mode == "table":
+        vals = table_segment_sum(v, routing.table_dev)
+    else:  # direct scatter-add (one XLA scatter)
+        vals = jax.ops.segment_sum(
+            v, routing.seg_ids_unsorted_dev, num_segments=routing.nnz
+        )
+    return vals
 
 
 def reduce_vector(f_local: jnp.ndarray, routing: VectorRouting, mode: str = "sorted"):
-    """``S_vec · vec(F_local)`` — reduce to touched dofs (modes as in
-    :func:`reduce_matrix`), then spread them to all dofs by a gather."""
+    """``S_vec · vec(F_local)`` — reduce to touched dofs, then spread them to
+    all dofs: by one scatter, or in ``"table"`` mode by a gather."""
     v = f_local.reshape(-1)
+    if mode == "table":
+        packed = table_segment_sum(v, routing.table_dev)
+        return jnp.take(packed, routing.spread_dev, axis=-1, mode="fill",
+                        fill_value=0)
     if mode == "sorted":
         packed = jax.ops.segment_sum(
             v[routing.perm_dev],
@@ -210,9 +219,19 @@ def reduce_vector(f_local: jnp.ndarray, routing: VectorRouting, mode: str = "sor
             indices_are_sorted=True,
         )
     else:
-        packed = table_segment_sum(v, routing.table_dev)
-    return jnp.take(packed, routing.spread_dev, axis=-1, mode="fill",
-                    fill_value=0)
+        packed = jax.ops.segment_sum(
+            v, routing.seg_ids_unsorted_dev,
+            num_segments=routing.touched.shape[0],
+        )
+    out = jnp.zeros((routing.num_dofs,), dtype=v.dtype)
+    return out.at[routing.touched_dev].set(packed)
+
+
+def default_reduce_mode() -> str:
+    """``"table"`` where :func:`~repro.core.routing.scatter_free` (a TPU),
+    ``"direct"`` elsewhere (one scatter-add: the fastest Reduce on the
+    CPU)."""
+    return "table" if scatter_free() else "direct"
 
 
 # ---------------------------------------------------------------------------
@@ -316,14 +335,18 @@ class AssemblyPlan:
 
 
 def build_plan(space: FunctionSpace, quad_order: int | None = None,
-               reduce_mode: str = "direct") -> AssemblyPlan:
+               reduce_mode: str | None = None) -> AssemblyPlan:
     """Precompute one :class:`AssemblyPlan` for a function space.
 
-    ``reduce_mode``: 'direct' gathers each output slot's contributions
-    through a static padded table and sums them (no scatter-add, which
-    XLA:TPU can only emulate serially in float64); 'sorted' is the
-    permutation gather + sorted segment-sum path.  Both are deterministic.
+    ``reduce_mode``: 'direct' lowers to one XLA scatter-add (2.5× faster on
+    CPU, still deterministic — no atomics in XLA); 'sorted' is the gather +
+    sorted-segment-sum path; 'table' gathers each output slot's
+    contributions through a static padded table (no scatter-add, which
+    XLA:TPU can only emulate serially in float64).  All are
+    bit-reproducible; ``None`` takes :func:`default_reduce_mode`.
     """
+    if reduce_mode is None:
+        reduce_mode = default_reduce_mode()
     mesh, element = space.mesh, space.element
     pts, w = element.default_rule(quad_order)
     geo_name = {
@@ -851,14 +874,13 @@ class GalerkinAssembler:
     """
 
     def __init__(self, space: FunctionSpace, quad_order: int | None = None,
-                 reduce_mode: str = "direct"):
+                 reduce_mode: str | None = None):
         self.space = space
         self.mesh = space.mesh
         self.element = space.element
-        self.reduce_mode = reduce_mode
-
         self.plan = build_plan(space, quad_order, reduce_mode)
         st = self.plan.static
+        self.reduce_mode = st.reduce_mode
         # compatibility aliases onto the plan's static tables
         self.w, self.phi, self.gradhat = st.w, st.phi, st.gradhat
         self.geo_phi, self.geo_grad = st.geo_phi, st.geo_grad

@@ -562,27 +562,6 @@ class ShardedMatFreeOperator(LinearOperator):
     def diagonal(self) -> jnp.ndarray:
         return _sharded_diag_jit(self)
 
-    def element_shards(self) -> dict:
-        """The element-aligned arrays of one apply (cell DoFs, segment ids,
-        per-element geometry and coefficient leaves, padded to a multiple of
-        the device count), placed on the mesh with the same partition specs
-        the apply's ``shard_map`` splits them by — to check where the
-        element work lands.  The apply does not use this."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        (_, _, cd, seg, _, geo, geo_specs, leaves_p, leaf_specs,
-         _) = _shard_scaffold(self)
-        shard = P(self.axis_name)
-        named = {"cell_dofs": (cd, shard), "segment_ids": (seg, shard)}
-        named.update((f"geometry_{i}", a) for i, a in
-                     enumerate(zip(geo, geo_specs)))
-        named.update((f"leaf_{i}", a) for i, a in
-                     enumerate(zip(leaves_p, leaf_specs)))
-        return {
-            name: jax.device_put(a, NamedSharding(self.mesh, spec))
-            for name, (a, spec) in named.items() if spec == shard
-        }
-
 
 def _shard_scaffold(sop: ShardedMatFreeOperator):
     """Static partitioning tables + the traced geometry/leaf shards and their

@@ -251,7 +251,23 @@ def _as_matvec(a) -> Callable:
 # Conjugate gradients (SPD systems: Poisson, elasticity)
 # ---------------------------------------------------------------------------
 
+# CG folds its correction into the iterate and recomputes the residual each
+# time the residual has fallen by _CG_REPLACE; it restarts from the
+# recomputed residual when that differs from the recursive one by more than
+# _CG_AGREE of its norm
+_CG_REPLACE = 1e-3
+_CG_AGREE = 0.1
+
+
 def cg(matvec, b, x0=None, *, tol=1e-10, atol=1e-10, maxiter=10000, m=_identity):
+    """Preconditioned CG with residual replacement and group update (van der
+    Vorst & Ye 2000): steps accumulate in a correction ``dx`` (rounded
+    relative to its own small size, not to ``x``); each time the residual
+    has fallen by ``_CG_REPLACE`` the correction is folded into ``x`` and
+    the residual recomputed as ``b − A x``.  Updating ``x`` in place leaves
+    a rounding error in every entry each iteration, which a stiffness
+    operator (‖A‖‖x‖/‖b‖ ≈ 4e5 at 1M DOFs) turns into a true residual that
+    stalls far above the recursive one: 3e-9 against 1e-10 on a TPU v5e."""
     matvec = _as_matvec(matvec)
     x0 = jnp.zeros_like(b) if x0 is None else x0
     bnorm = jnp.linalg.norm(b)
@@ -259,28 +275,43 @@ def cg(matvec, b, x0=None, *, tol=1e-10, atol=1e-10, maxiter=10000, m=_identity)
 
     r0 = b - matvec(x0)
     z0 = m(r0)
-    state = (x0, r0, z0, z0, jnp.vdot(r0, z0), jnp.array(0))
+    rnorm0 = jnp.linalg.norm(r0)
+    fold0 = jnp.array(False)
+    state = (x0, jnp.zeros_like(b), r0, z0, jnp.vdot(r0, z0), rnorm0,
+             jnp.maximum(target, _CG_REPLACE * rnorm0), fold0, jnp.array(0))
 
     def cond(s):
-        _, r, *_, it = s
-        return (jnp.linalg.norm(r) > target) & (it < maxiter)
+        *_, rnorm, _, _, it = s
+        return (rnorm > target) & (it < maxiter)
 
+    # one matvec per pass: a CG step applies A to p; a fold pass (after the
+    # residual fell below the segment's stop) applies it to x + dx instead
     def body(s):
-        x, r, z, p, rz, it = s
-        ap = matvec(p)
-        alpha = rz / jnp.vdot(p, ap)
-        x = x + alpha * p
-        r = r - alpha * ap
-        z = m(r)
-        rz_new = jnp.vdot(r, z)
-        beta = rz_new / rz
-        p = z + beta * p
-        return (x, r, z, p, rz_new, it + 1)
+        x, dx, r, p, rz, rnorm, stop, fold, it = s
+        xf = x + dx
+        av = matvec(jnp.where(fold, xf, p))
+        # fold: restart from the true residual, keeping the search direction
+        # only while it agrees with the recursive one (else CG diverges)
+        r_true = b - av
+        keep = jnp.linalg.norm(r_true - r) <= _CG_AGREE * rnorm
+        # step
+        alpha = rz / jnp.vdot(p, av)
+        r_new = jnp.where(fold, r_true, r - alpha * av)
+        z = m(r_new)
+        rz_new = jnp.vdot(r_new, z)
+        p_new = jnp.where(fold, jnp.where(keep, p, z), z + (rz_new / rz) * p)
+        rnorm_new = jnp.linalg.norm(r_new)
+        stop = jnp.where(fold, jnp.maximum(target, _CG_REPLACE * rnorm_new),
+                         stop)
+        return (jnp.where(fold, xf, x),
+                jnp.where(fold, 0.0, dx + alpha * p),
+                r_new, p_new, rz_new, rnorm_new, stop,
+                ~fold & (rnorm_new <= stop) & (rnorm_new > target),
+                it + jnp.where(fold, 0, 1))
 
     with annotate("tg.solve.cg"):
-        x, r, *_, it = jax.lax.while_loop(cond, body, state)
-    rnorm = jnp.linalg.norm(r)
-    return x, SolveInfo(it, rnorm, rnorm <= target)
+        x, dx, _, _, _, rnorm, *_, it = jax.lax.while_loop(cond, body, state)
+    return x + dx, SolveInfo(it, rnorm, rnorm <= target)
 
 
 # ---------------------------------------------------------------------------

@@ -438,7 +438,8 @@ def test_deprecated_shims_warn_and_match_form_api():
 def test_routing_device_mirrors_are_prestaged():
     m, space, asm = _setup(4)
     r = asm.mat_routing
-    for name in ("perm_dev", "seg_ids_dev", "table_dev"):
+    for name in ("perm_dev", "seg_ids_dev", "seg_ids_unsorted_dev",
+                 "table_dev"):
         arr = getattr(r, name)
         assert isinstance(arr, jnp.ndarray)
     np.testing.assert_array_equal(np.asarray(r.perm_dev), r.perm)

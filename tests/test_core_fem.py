@@ -111,12 +111,32 @@ def test_reduce_modes_agree():
     m = unit_square_tri(7)
     space = FunctionSpace(m, element_for_mesh(m))
     a_sorted = GalerkinAssembler(space, reduce_mode="sorted")
-    a_direct = GalerkinAssembler(space, reduce_mode="direct")
-    np.testing.assert_allclose(
-        np.asarray(a_sorted.assemble_stiffness().vals),
-        np.asarray(a_direct.assemble_stiffness().vals),
-        atol=1e-13,
-    )
+    for mode in ("direct", "table"):
+        a = GalerkinAssembler(space, reduce_mode=mode)
+        np.testing.assert_allclose(
+            np.asarray(a_sorted.assemble_stiffness().vals),
+            np.asarray(a.assemble_stiffness().vals),
+            atol=1e-13,
+        )
+        np.testing.assert_allclose(
+            np.asarray(a_sorted.assemble_rhs(wf.source(1.0))),
+            np.asarray(a.assemble_rhs(wf.source(1.0))),
+            atol=1e-13,
+        )
+
+
+@pytest.mark.parametrize("backend,mode", [("cpu", "direct"),
+                                          ("tpu", "table")])
+def test_default_reduce_mode_follows_the_platform(monkeypatch, backend, mode):
+    """Plans built without a reduce_mode scatter-add on the CPU and gather
+    through the static tables on a TPU, which has no float64 scatter-add."""
+    from repro.core import assembly
+
+    monkeypatch.setattr(assembly.jax, "default_backend", lambda: backend)
+    m = unit_square_tri(3)
+    space = FunctionSpace(m, element_for_mesh(m))
+    assert assembly.build_plan(space).static.reduce_mode == mode
+    assert GalerkinAssembler(space).reduce_mode == mode
 
 
 @pytest.mark.parametrize("batch", [(), (3,)])
@@ -144,12 +164,13 @@ def test_table_segment_sum_matches_segment_sum(batch):
     (lambda: unit_square_tri(9), True),    # banded: 9 column offsets
     (lambda: disk_tri(4), False),          # unstructured numbering
 ])
-def test_csr_matvec_row_layouts_match_dense(mesh, diagonals):
-    """CSR.matvec reads rows as shifted diagonals on a banded pattern and
-    through a column table otherwise; both equal the dense product, for
-    one vector and for a batch of operators."""
+def test_csr_matvec_row_layouts_match_dense(monkeypatch, mesh, diagonals):
+    """On a TPU CSR.matvec reads rows as shifted diagonals on a banded
+    pattern and through a column table otherwise; both equal the dense
+    product, for one vector and for a batch of operators."""
     from repro.core.sparse import _row_layout
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     m = mesh()
     space = FunctionSpace(m, element_for_mesh(m))
     k = GalerkinAssembler(space).assemble(wf.diffusion() + 0.5 * wf.mass())
@@ -264,6 +285,24 @@ def test_solver_residual_meets_paper_tolerance():
     x, _ = bicgstab(k.matvec, f, m=jacobi_preconditioner(k), tol=1e-10)
     rel = float(jnp.linalg.norm(k.matvec(x) - f) / jnp.linalg.norm(f))
     assert rel < 1e-10
+
+
+@pytest.mark.parametrize("n", [64, 80])
+def test_cg_true_residual_meets_tolerance_in_float32(n):
+    """CG folds its steps into the iterate and recomputes the residual, so
+    a converged solve meets its tolerance in the true residual too.  In
+    float32 on a 1-D Laplacian (‖A‖‖x‖/‖b‖ grows like n²) updating x in
+    place lets the true residual stall above the recursive one — the
+    effect emulated float64 has at 1M DOFs on a TPU."""
+    main, off = np.full(n, 2.0, np.float32), np.full(n - 1, -1.0, np.float32)
+    a = np.diag(main) + np.diag(off, 1) + np.diag(off, -1)
+    b = np.sin(np.linspace(0.0, 3.0, n)).astype(np.float32) / n**2
+    aj = jnp.asarray(a, jnp.float32)
+    x, info = cg(lambda v: aj @ v, jnp.asarray(b), tol=1e-4, atol=0.0)
+    true = np.linalg.norm(a.astype(np.float64) @ np.asarray(x, np.float64)
+                          - b) / np.linalg.norm(b)
+    assert bool(info.converged)
+    assert true <= 1e-4
 
 
 def test_ell_spmv_matches_csr():

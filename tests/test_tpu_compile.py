@@ -61,7 +61,11 @@ def one_chip(topo):
     # read back without the chip; keep the persistent cache out of it
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    with pytest.MonkeyPatch.context() as mp:
+        # plans and operators built here take the paths they take on the
+        # chip (scatter-free Reduce and CSR matvec)
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
@@ -80,7 +84,9 @@ def _device_bytes(compiled, scale: float = 1.0) -> float:
 def _small_plan():
     mesh = unit_square_tri(SMALL_RESOLUTION)
     space = FunctionSpace(mesh, element_for_mesh(mesh, 1))
-    return mesh, space, build_plan(space)
+    plan = build_plan(space)
+    assert plan.static.reduce_mode == "table"
+    return mesh, space, plan
 
 
 @pytest.mark.parametrize("layout", ["diagonals", "columns"])
@@ -145,6 +151,7 @@ def test_serve_batched_solve_compiles(one_chip):
 
     req = serve.poisson_requests(n_requests=1,
                                  resolution=SERVE_RESOLUTION)[0]
+    assert req.plan.static.reduce_mode == "table"
     fn = _build_executable(req, admission_key(req))
     leaves = tuple(jnp.broadcast_to(lf, (SERVE_BATCH,) + lf.shape)
                    for lf in req.leaves)

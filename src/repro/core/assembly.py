@@ -36,7 +36,6 @@ The assembly subsystem is a **functional core** behind a thin class facade:
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from functools import partial
 
@@ -534,19 +533,16 @@ def _assemble_flat(coords, leaves, *, static, spec, arity):
         ("single", static, spec, arity),
         lambda: lambda c, lv: _eval_form(static, c, spec, lv, arity),
     )
-    if not telemetry.is_enabled():
-        return fn(coords, leaves)
-    t0 = time.perf_counter()
     out = fn(coords, leaves)
-    is_mat = arity == weakform.MATRIX
-    telemetry.record_assembly(
-        "assemble" if is_mat else "assemble_rhs",
-        num_dofs=static.num_dofs,
-        nnz=static.mat_routing.nnz if is_mat else None,
-        num_cells=int(coords.shape[0]),
-        form="+".join(kind for kind, _, _ in spec),
-        wall_us=(time.perf_counter() - t0) * 1e6,
-    )
+    if telemetry.is_enabled():
+        is_mat = arity == weakform.MATRIX
+        telemetry.record_assembly(
+            "assemble" if is_mat else "assemble_rhs",
+            num_dofs=static.num_dofs,
+            nnz=static.mat_routing.nnz if is_mat else None,
+            num_cells=int(coords.shape[0]),
+            form="+".join(kind for kind, _, _ in spec),
+        )
     return out
 
 
@@ -591,18 +587,15 @@ def _assemble_batched_flat(coords, leaves, *, static, spec, arity, axes):
         )(c, lv)
 
     fn = _cached_form_fn(("batched", static, spec, arity, axes), build)
-    if not telemetry.is_enabled():
-        return fn(coords, leaves)
-    t0 = time.perf_counter()
     out = fn(coords, leaves)
-    is_mat = arity == weakform.MATRIX
-    telemetry.record_assembly(
-        "assemble_batched" if is_mat else "assemble_rhs_batched",
-        num_dofs=static.num_dofs,
-        nnz=static.mat_routing.nnz if is_mat else None,
-        form="+".join(kind for kind, _, _ in spec),
-        wall_us=(time.perf_counter() - t0) * 1e6,
-    )
+    if telemetry.is_enabled():
+        is_mat = arity == weakform.MATRIX
+        telemetry.record_assembly(
+            "assemble_batched" if is_mat else "assemble_rhs_batched",
+            num_dofs=static.num_dofs,
+            nnz=static.mat_routing.nnz if is_mat else None,
+            form="+".join(kind for kind, _, _ in spec),
+        )
     return out
 
 
@@ -706,19 +699,16 @@ def _assemble_sharded_flat(coords, leaves, *, static, spec, arity, mesh, axis_na
         lambda: partial(_sharded_impl, static=static, spec=spec, arity=arity,
                         mesh=mesh, axis_name=axis_name),
     )
-    if not telemetry.is_enabled():
-        return fn(coords, leaves)
-    t0 = time.perf_counter()
     out = fn(coords, leaves)
-    is_mat = arity == weakform.MATRIX
-    telemetry.record_assembly(
-        "assemble_sharded" if is_mat else "assemble_rhs_sharded",
-        num_dofs=static.num_dofs,
-        nnz=static.mat_routing.nnz if is_mat else None,
-        num_cells=int(coords.shape[0]),
-        form="+".join(kind for kind, _, _ in spec),
-        wall_us=(time.perf_counter() - t0) * 1e6,
-    )
+    if telemetry.is_enabled():
+        is_mat = arity == weakform.MATRIX
+        telemetry.record_assembly(
+            "assemble_sharded" if is_mat else "assemble_rhs_sharded",
+            num_dofs=static.num_dofs,
+            nnz=static.mat_routing.nnz if is_mat else None,
+            num_cells=int(coords.shape[0]),
+            form="+".join(kind for kind, _, _ in spec),
+        )
     return out
 
 

@@ -56,7 +56,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..telemetry import annotate, events, spans
+from ..telemetry import annotate, events, metrics, spans
 from .sparse import CSR, BatchedCSR, _dev, cached_diagonal
 
 __all__ = [
@@ -251,6 +251,11 @@ def _as_matvec(a) -> Callable:
 # Conjugate gradients (SPD systems: Poisson, elasticity)
 # ---------------------------------------------------------------------------
 
+# the counter of Krylov loop traces, labelled by ``method``: each loop body
+# bumps it while it is traced, so it counts traces and compiles of the loop,
+# never runs of a compiled executable
+KRYLOV_TRACES = "krylov_traces"
+
 # CG folds its correction into the iterate and recomputes the residual each
 # time the residual has fallen by _CG_REPLACE; it restarts from the
 # recomputed residual when that differs from the recursive one by more than
@@ -287,6 +292,7 @@ def cg(matvec, b, x0=None, *, tol=1e-10, atol=1e-10, maxiter=10000, m=_identity)
     # one matvec per pass: a CG step applies A to p; a fold pass (after the
     # residual fell below the segment's stop) applies it to x + dx instead
     def body(s):
+        metrics.counter_inc(KRYLOV_TRACES, method="cg")
         x, dx, r, p, rz, rnorm, stop, fold, it = s
         xf = x + dx
         av = matvec(jnp.where(fold, xf, p))
@@ -338,6 +344,7 @@ def bicgstab(matvec, b, x0=None, *, tol=1e-10, atol=1e-10, maxiter=10000, m=_ide
         return (jnp.linalg.norm(r) > target) & (it < maxiter)
 
     def body(s):
+        metrics.counter_inc(KRYLOV_TRACES, method="bicgstab")
         x, r, rho, alpha, omega, v, p, it = s
         rho_new = jnp.vdot(rhat, r)
         beta = (rho_new / jnp.where(rho == 0, 1e-30, rho)) * (

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry import annotate
 from .routing import _staged, scatter_free, segment_table
 
 __all__ = ["CSR", "ELL", "BatchedCSR", "csr_to_ell", "ell_layout"]
@@ -108,6 +109,7 @@ def _shifted(x: jnp.ndarray, offsets: tuple) -> jnp.ndarray:
                       for o in offsets], axis=-2)
 
 
+@annotate("tg.spmv")
 def row_layout_matvec(vals, x, layout: RowLayout, *, square: bool = True):
     """The :meth:`CSR.matvec` arithmetic on a :class:`RowLayout` (whose
     arrays may be traced)."""
@@ -210,13 +212,14 @@ class CSR:
         if scatter_free():
             return row_layout_matvec(self.vals, x, _row_layout(self),
                                      square=self.shape[0] == self.shape[1])
-        contrib = self.vals * x[_dev(self.indices)]
-        return jax.ops.segment_sum(
-            contrib,
-            _dev(self.row_of_nnz),
-            num_segments=self.shape[0],
-            indices_are_sorted=True,
-        )
+        with annotate("tg.spmv"):
+            contrib = self.vals * x[_dev(self.indices)]
+            return jax.ops.segment_sum(
+                contrib,
+                _dev(self.row_of_nnz),
+                num_segments=self.shape[0],
+                indices_are_sorted=True,
+            )
 
     def rmatvec(self, x: jnp.ndarray) -> jnp.ndarray:
         """y = A.T @ x (scatter over columns)."""
@@ -352,7 +355,8 @@ class BatchedCSR:
     # -- ops ---------------------------------------------------------------
     def matvec(self, x: jnp.ndarray) -> jnp.ndarray:
         """Y_b = A_b @ x_b for ``x: (B, n)`` (``(n,)`` broadcasts across the
-        batch) — one vmapped gather + segment-sum."""
+        batch) — one vmapped gather + segment-sum, under
+        :meth:`CSR.matvec`'s ``tg.spmv`` scope."""
         in_x = None if x.ndim == 1 else 0
         return jax.vmap(lambda v, xi: self._one(v).matvec(xi),
                         in_axes=(0, in_x))(self.vals, x)

@@ -10,7 +10,6 @@ Problem classes own (mesh → space → assembler → condenser) and expose:
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 
 import jax
@@ -86,18 +85,18 @@ class _ProblemBase:
         and the ``converged`` flag on the result; ``return_info=True``
         appends the raw :class:`~repro.core.solvers.SolveInfo`."""
         be = backend or self._default_backend()
-        matvec = make_matvec(k, be)
-        t0 = time.perf_counter()
-        u, info = _method(spec.method)(
-            matvec, f, m=make_preconditioner(k, spec.precond),
-            tol=spec.tol, atol=spec.atol, maxiter=spec.maxiter)
+        # krylov: build the matvec and preconditioner, trace, compile and
+        # dispatch the loop; wait: the host blocks on the loop's result
+        with telemetry.span("tg.solve.krylov"):
+            u, info = _method(spec.method)(
+                make_matvec(k, be), f, m=make_preconditioner(k, spec.precond),
+                tol=spec.tol, atol=spec.atol, maxiter=spec.maxiter)
         where = f"{type(self).__name__}.solve"
-        events.check_convergence(info, where=where)
-        if telemetry.is_enabled():
+        with telemetry.span("tg.solve.wait"):
+            events.check_convergence(info, where=where)
             events.record_solve(where, info, method=spec.method, backend=be,
-                                precond=spec.precond_name,
-                                wall_us=(time.perf_counter() - t0) * 1e6)
-        rel = float(jnp.linalg.norm(k.matvec(u) - f) / jnp.linalg.norm(f))
+                                precond=spec.precond_name)
+            rel = float(jnp.linalg.norm(k.matvec(u) - f) / jnp.linalg.norm(f))
         res = _SolveResult(u, int(info.iters), rel, bool(info.converged))
         return (res, info) if return_info else res
 
@@ -122,33 +121,34 @@ class _ProblemBase:
         (:func:`repro.core.elemalg.condensed_solve` machinery)."""
         from ..core import elemalg
 
-        op_full = matfree_operator(self.plan, form)
-        if sharded:
-            op_full = op_full.sharded()
-        op = op_full.condensed(self.bc)
-        if isinstance(dirichlet_values, (int, float)) and dirichlet_values == 0.0:
-            # homogeneous: the lift reduces to masking — skip the dead
-            # matrix-free apply of the all-zero boundary field
-            f = self.bc.project_residual(load)
-        else:
-            f = self.bc.lift(op_full, load, dirichlet_values)
-        t0 = time.perf_counter()
-        if condensed:
-            sys = elemalg.condense(op, elemalg.vertex_split(self.space))
-            u, info = sys.solve(f, spec)
-        else:
-            u, info = _method(spec.method)(
-                op.matvec, f, m=make_preconditioner(op, spec.precond),
-                tol=spec.tol, atol=spec.atol, maxiter=spec.maxiter)
+        with telemetry.span("tg.solve.krylov"):
+            op_full = matfree_operator(self.plan, form)
+            if sharded:
+                op_full = op_full.sharded()
+            op = op_full.condensed(self.bc)
+            if (isinstance(dirichlet_values, (int, float))
+                    and dirichlet_values == 0.0):
+                # homogeneous: the lift reduces to masking — skip the dead
+                # matrix-free apply of the all-zero boundary field
+                f = self.bc.project_residual(load)
+            else:
+                f = self.bc.lift(op_full, load, dirichlet_values)
+            if condensed:
+                sys = elemalg.condense(op, elemalg.vertex_split(self.space))
+                u, info = sys.solve(f, spec)
+            else:
+                u, info = _method(spec.method)(
+                    op.matvec, f, m=make_preconditioner(op, spec.precond),
+                    tol=spec.tol, atol=spec.atol, maxiter=spec.maxiter)
         where = f"{type(self).__name__}.solve"
-        events.check_convergence(info, where=where)
-        if telemetry.is_enabled():
+        with telemetry.span("tg.solve.wait"):
+            events.check_convergence(info, where=where)
             events.record_solve(
                 where, info, method=spec.method,
                 backend="matfree_sharded" if sharded else "matfree",
-                precond="condensed" if condensed else spec.precond_name,
-                wall_us=(time.perf_counter() - t0) * 1e6)
-        rel = float(jnp.linalg.norm(op.matvec(u) - f) / jnp.linalg.norm(f))
+                precond="condensed" if condensed else spec.precond_name)
+            rel = float(jnp.linalg.norm(op.matvec(u) - f)
+                        / jnp.linalg.norm(f))
         res = _SolveResult(u, int(info.iters), rel, bool(info.converged))
         return (res, info) if return_info else res
 
@@ -163,9 +163,13 @@ class PoissonProblem(_ProblemBase):
         self.bc = DirichletCondenser(self.asm, self.space.boundary_dofs())
 
     def assemble(self, rho=None, f=1.0):
-        k = self.asm.assemble(wf.diffusion(rho))
-        load = self.asm.assemble_rhs(wf.source(f))
-        return self.bc.apply(k, load)
+        with telemetry.span("tg.assemble"):
+            with telemetry.span("tg.assemble.matrix"):
+                k = self.asm.assemble(wf.diffusion(rho))
+            with telemetry.span("tg.assemble.vector"):
+                load = self.asm.assemble_rhs(wf.source(f))
+            with telemetry.span("tg.condense"):
+                return self.bc.apply(k, load)
 
     def solve(self, rho=None, f=1.0, spec: SolverSpec | None = None,
               tol=None, maxiter=None, backend=None, return_info=False,
@@ -181,17 +185,18 @@ class PoissonProblem(_ProblemBase):
         ``return_info=True`` appends the raw
         :class:`~repro.core.solvers.SolveInfo`."""
         spec = self._spec(spec, tol, maxiter, "solve")
-        if backend in ("matfree", "matfree_sharded"):
-            load = self.asm.assemble_rhs(wf.source(f))
-            return self._solve_matfree(wf.diffusion(rho), load, spec,
-                                       return_info=return_info,
-                                       sharded=backend == "matfree_sharded",
-                                       condensed=condensed)
-        if condensed:
+        if condensed and backend not in ("matfree", "matfree_sharded"):
             raise ValueError("condensed=True needs a matfree backend")
-        k, load = self.assemble(rho, f)
-        return self._solve_system(k, load, spec, backend=backend,
-                                  return_info=return_info)
+        with telemetry.span("tg.solve"):
+            if backend in ("matfree", "matfree_sharded"):
+                load = self.asm.assemble_rhs(wf.source(f))
+                return self._solve_matfree(
+                    wf.diffusion(rho), load, spec, return_info=return_info,
+                    sharded=backend == "matfree_sharded",
+                    condensed=condensed)
+            k, load = self.assemble(rho, f)
+            return self._solve_system(k, load, spec, backend=backend,
+                                      return_info=return_info)
 
     # -- many-query batched data generation (SM B.1.4) ------------------------
     def solve_batch(self, f_batch: jnp.ndarray, rho=None, tol=1e-10, maxiter=2000):

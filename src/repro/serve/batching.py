@@ -146,8 +146,9 @@ class SolveResponse:
     @property
     def span_segments_us(self) -> dict:
         """Top-level segment walls (µs) of the carried span tree — e.g.
-        ``{"queue_wait": ..., "dispatch": ..., "solve": ..., "slice": ...}``
-        summing to the end-to-end latency.  Empty without telemetry."""
+        ``{"queue_wait": ..., "dispatch": ..., "solve": ..., "fetch": ...,
+        "slice": ...}`` summing to the end-to-end latency.  Empty without
+        telemetry."""
         if not self.trace:
             return {}
         return {

@@ -35,10 +35,15 @@ its own:
   every drain (separates overload from a slow executable),
 * **span trees** — every request gets a root span at :meth:`submit`
   (trace-ID minted there) with ``queue_wait`` / ``dispatch`` / ``solve`` /
-  ``slice`` children summing exactly to its end-to-end latency; the tree
-  rides back on ``SolveResponse.trace`` and every completed request is
-  recorded in the :mod:`~repro.telemetry.spans` flight recorder, which
-  auto-dumps on shed / expiry / non-convergence / failure.
+  ``fetch`` / ``slice`` children summing exactly to its end-to-end
+  latency; the tree rides back on ``SolveResponse.trace`` and every
+  completed request is recorded in the :mod:`~repro.telemetry.spans`
+  flight recorder, which auto-dumps on shed / expiry / non-convergence /
+  failure.  On the dispatch thread the same phases are live spans of a
+  ``serve.batch`` span (``cache_lookup``, ``pad``, ``solve`` — to
+  ``block_until_ready`` —, ``fetch`` — the device→host copy — and
+  ``slice`` — per-request slicing, re-upload and responses), so a profile
+  shows them on its own clock.
 
 Non-converged solves follow the PR-5 policy
 (``telemetry.nonconverged_policy()``): ``"warn"`` answers ``"ok"`` with a
@@ -262,123 +267,145 @@ class SolveService:
         template = pendings[0].request
         b = len(pendings)
         padded = min(pad_bucket(b), self.max_batch)
-        t_dispatch_ns = time.monotonic_ns()
-        t_dispatch = t_dispatch_ns / 1e9
         roots = [p.span for p in pendings]
-        # segment 1: queue_wait — submit (the root's start) → dispatch
-        for t, root in zip(submits, roots):
-            telemetry.histogram_observe(
-                "serve_queue_wait_us", 1e6 * (t_dispatch - t),
-                backend=template.backend)
-            root.child("queue_wait",
-                       start_ns=root.start_ns).finish(end_ns=t_dispatch_ns)
-        telemetry.histogram_observe("serve_batch_size", b,
-                                    backend=template.backend)
-        try:
-            fn, cache_hit = self.cache.get(key, padded, template)
-            t_lookup_ns = time.monotonic_ns()
-            leaves = tuple(
-                _stack_padded([p.request.leaves[j] for p in pendings], padded)
-                for j in range(len(template.leaves))
-            )
-            rhs = _stack_padded([p.request.rhs for p in pendings], padded)
-            t_solve_ns = time.monotonic_ns()
-            # segment 2: dispatch — cache lookup + pad/stack to the bucket
-            # (the batch-level walls are duplicated into every member's
-            # tree: each response carries its complete timeline)
-            for root in roots:
+        # the batch's phases are live spans of this thread; every member's
+        # tree repeats their boundaries as contiguous segments queue_wait →
+        # dispatch → solve → fetch → slice, summing exactly to its
+        # end-to-end latency (t_done - t_submit)
+        with telemetry.span("serve.batch", batch=b, padded=padded,
+                            backend=template.backend):
+            t_dispatch_ns = time.monotonic_ns()
+            t_dispatch = t_dispatch_ns / 1e9
+            for t, root in zip(submits, roots):
+                telemetry.histogram_observe(
+                    "serve_queue_wait_us", 1e6 * (t_dispatch - t),
+                    backend=template.backend)
+                root.child("queue_wait",
+                           start_ns=root.start_ns).finish(end_ns=t_dispatch_ns)
+            telemetry.histogram_observe("serve_batch_size", b,
+                                        backend=template.backend)
+            try:
+                with telemetry.span("cache_lookup"):
+                    fn, cache_hit = self.cache.get(key, padded, template)
+                t_lookup_ns = time.monotonic_ns()
+                with telemetry.span("pad"):
+                    leaves = tuple(
+                        _stack_padded([p.request.leaves[j] for p in pendings],
+                                      padded)
+                        for j in range(len(template.leaves))
+                    )
+                    rhs = _stack_padded([p.request.rhs for p in pendings],
+                                        padded)
+                t_padded_ns = time.monotonic_ns()
+                # the vmapped device solve (compiled on a cache miss)
+                with telemetry.span("solve", compiled=not cache_hit):
+                    x_pad, info_pad = fn(template.plan, leaves, rhs)
+                    jax.block_until_ready(x_pad)
+                t_solved_ns = time.monotonic_ns()
+                # the device→host copy of the answers and their info
+                with telemetry.span("fetch"):
+                    x = np.asarray(x_pad)[:b]
+                    info_b = jax.tree_util.tree_map(
+                        lambda leaf: np.asarray(leaf)[:b], info_pad)
+                t_fetched_ns = time.monotonic_ns()
+            except Exception as err:  # compile/solve failure → fail the batch
+                self._fail_group(key, members, err, padded, t_dispatch)
+                return
+            # per-request slicing, re-upload and responses
+            with telemetry.span("slice"):
+                responses = self._responses(pendings, submits, x, info_b,
+                                            cache_hit, t_dispatch)
+            t_done_ns = time.monotonic_ns()
+            t_done = t_done_ns / 1e9
+            telemetry.record_solve(
+                "serve.dispatch", info_b, method=template.spec.method,
+                precond=template.spec.precond_name,
+                backend=template.backend,
+                wall_us=1e-3 * (t_done_ns - t_dispatch_ns),
+                batch=b, padded=padded, cache_hit=cache_hit)
+            for (p, t), root, resp in zip(members, roots, responses):
+                # the batch-level walls are duplicated into every member's
+                # tree: each response carries its complete timeline
                 d = root.child("dispatch", start_ns=t_dispatch_ns,
                                batch=b, padded=padded, cache_hit=cache_hit)
                 d.child("cache_lookup",
                         start_ns=t_dispatch_ns).finish(end_ns=t_lookup_ns)
                 d.child("pad",
-                        start_ns=t_lookup_ns).finish(end_ns=t_solve_ns)
-                d.finish(end_ns=t_solve_ns)
-            x_pad, info_pad = fn(template.plan, leaves, rhs)
-            x = np.asarray(x_pad)[:b]
-            converged = np.asarray(info_pad.converged)[:b]
-            iters = np.asarray(info_pad.iters)[:b]
-            residual = np.asarray(info_pad.residual)[:b]
-            # segment 3: solve — the vmapped device solve incl. the host
-            # transfer that synchronizes on it (compiled on a cache miss)
-            t_solved_ns = time.monotonic_ns()
-            for root in roots:
-                root.child("solve", start_ns=t_solve_ns,
+                        start_ns=t_lookup_ns).finish(end_ns=t_padded_ns)
+                d.finish(end_ns=t_padded_ns)
+                root.child("solve", start_ns=t_padded_ns,
                            compiled=not cache_hit).finish(end_ns=t_solved_ns)
-        except Exception as err:  # compile/solve failure → fail the batch
-            t_done_ns = time.monotonic_ns()
-            t_done = t_done_ns / 1e9
-            telemetry.counter_inc("serve_requests", value=b, outcome="failed")
-            for (p, t), root in zip(members, roots):
-                root.finish(end_ns=t_done_ns, outcome="failed",
-                            error=type(err).__name__)
+                root.child("fetch",
+                           start_ns=t_solved_ns).finish(end_ns=t_fetched_ns)
+                root.child("slice",
+                           start_ns=t_fetched_ns).finish(end_ns=t_done_ns)
+                resp.t_done = t_done
+                telemetry.histogram_observe(
+                    "serve_e2e_us", 1e6 * (t_done - t),
+                    backend=template.backend)
+                converged = bool(resp.info.converged)
+                iters = int(resp.info.iters)
+                root.finish(end_ns=t_done_ns, outcome=resp.status,
+                            converged=converged, iters=iters)
+                resp.trace = root.to_dict()
                 telemetry.flight_record(
-                    root, outcome="failed", request_id=p.request.request_id,
-                    admission=_key_tag(key), bucket=padded, batch=b,
-                    error=repr(err))
-                p._resolve(SolveResponse(
-                    status="failed", error=err, batch_size=b,
-                    t_submit=t, t_dispatch=t_dispatch, t_done=t_done,
-                    trace=root.to_dict()))
-            telemetry.flight_autodump("failed")
-            return
-        info_b = jax.tree_util.tree_map(
-            lambda leaf: np.asarray(leaf)[:b], info_pad)
-        t_done_ns = time.monotonic_ns()
-        t_done = t_done_ns / 1e9
-        telemetry.record_solve(
-            "serve.dispatch", info_b, method=template.spec.method,
-            precond=template.spec.precond_name,
-            backend=template.backend,
-            wall_us=1e-3 * (t_done_ns - t_dispatch_ns),
-            batch=b, padded=padded, cache_hit=cache_hit)
+                    root, outcome=resp.status,
+                    request_id=p.request.request_id, admission=_key_tag(key),
+                    bucket=padded, batch=b, backend=template.backend,
+                    cache_hit=cache_hit, iterations=iters,
+                    final_residual=float(resp.info.residual),
+                    converged=converged)
+                p._resolve(resp)
+        if any(r.status == "nonconverged" for r in responses):
+            telemetry.flight_autodump("nonconverged")
+
+    def _responses(self, pendings, submits, x, info_b, cache_hit,
+                   t_dispatch) -> list:
+        """One response per request of a solved batch, its status set by
+        the non-convergence policy (``t_done`` and ``trace`` are left to
+        the caller)."""
         policy = telemetry.nonconverged_policy()
-        any_nonconverged = False
-        for i, (p, t) in enumerate(members):
-            root = roots[i]
-            # segment 4: slice — per-request extraction from the padded
-            # batch; ends at t_done, so the four segments sum exactly to
-            # the response's end-to-end latency (t_done - t_submit)
-            root.child("slice", start_ns=t_solved_ns).finish(end_ns=t_done_ns)
+        out = []
+        for i, (p, t) in enumerate(zip(pendings, submits)):
+            info = jax.tree_util.tree_map(lambda leaf: leaf[i], info_b)
             resp = SolveResponse(
-                status="ok", u=jnp.asarray(x[i]),
-                info=jax.tree_util.tree_map(lambda leaf: leaf[i], info_b),
-                batch_size=b, cache_hit=cache_hit,
-                t_submit=t, t_dispatch=t_dispatch, t_done=t_done,
+                status="ok", u=jnp.asarray(x[i]), info=info,
+                batch_size=len(pendings), cache_hit=cache_hit,
+                t_submit=t, t_dispatch=t_dispatch,
             )
-            if not converged[i]:
+            outcome = "ok"
+            if not info.converged:
                 msg = (f"request {p.request.request_id}: solve not converged "
-                       f"after {int(iters[i])} iterations "
-                       f"(residual {float(residual[i]):.3e})")
+                       f"after {int(info.iters)} iterations "
+                       f"(residual {float(info.residual):.3e})")
                 if policy == "raise":
-                    resp.status = "nonconverged"
+                    resp.status = outcome = "nonconverged"
                     resp.error = NonConverged(msg)
                     resp.u = None
-                    telemetry.counter_inc("serve_requests",
-                                          outcome="nonconverged")
-                    any_nonconverged = True
-                else:
-                    if policy == "warn":
-                        warnings.warn(msg, ConvergenceWarning, stacklevel=2)
-                    telemetry.counter_inc("serve_requests", outcome="ok")
-            else:
-                telemetry.counter_inc("serve_requests", outcome="ok")
-            telemetry.histogram_observe(
-                "serve_e2e_us", 1e6 * (t_done - t),
-                backend=template.backend)
-            root.finish(end_ns=t_done_ns, outcome=resp.status,
-                        converged=bool(converged[i]), iters=int(iters[i]))
-            resp.trace = root.to_dict()
+                elif policy == "warn":
+                    warnings.warn(msg, ConvergenceWarning, stacklevel=3)
+            telemetry.counter_inc("serve_requests", outcome=outcome)
+            out.append(resp)
+        return out
+
+    def _fail_group(self, key, members, err, padded, t_dispatch) -> None:
+        t_done_ns = time.monotonic_ns()
+        t_done = t_done_ns / 1e9
+        b = len(members)
+        telemetry.counter_inc("serve_requests", value=b, outcome="failed")
+        for p, t in members:
+            root = p.span
+            root.finish(end_ns=t_done_ns, outcome="failed",
+                        error=type(err).__name__)
             telemetry.flight_record(
-                root, outcome=resp.status,
-                request_id=p.request.request_id, admission=_key_tag(key),
-                bucket=padded, batch=b, backend=template.backend,
-                cache_hit=cache_hit, iterations=int(iters[i]),
-                final_residual=float(residual[i]),
-                converged=bool(converged[i]))
-            p._resolve(resp)
-        if any_nonconverged:
-            telemetry.flight_autodump("nonconverged")
+                root, outcome="failed", request_id=p.request.request_id,
+                admission=_key_tag(key), bucket=padded, batch=b,
+                error=repr(err))
+            p._resolve(SolveResponse(
+                status="failed", error=err, batch_size=b,
+                t_submit=t, t_dispatch=t_dispatch, t_done=t_done,
+                trace=root.to_dict()))
+        telemetry.flight_autodump("failed")
 
     # -- warmup ------------------------------------------------------------
     def warmup(self, request: SolveRequest, batch_sizes=(1,),

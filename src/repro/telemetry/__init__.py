@@ -27,6 +27,12 @@ The paper's claim is *efficiency*; this package is how the repo sees it:
   nonconverged/expired/shed; :func:`define_slo` tracks latency SLO
   attainment and burn rate against any histogram, surfaced in
   :func:`snapshot` and ``report --slo``.
+* **Span log** — every finished span also lands in a bounded in-memory log
+  (:func:`span_log`); a span opened with ``with span(...)`` holds a
+  profiler ``TraceAnnotation`` of its name, so a profile shows the
+  program's own phases (``tg.solve``, ``tg.assemble``, ``serve.batch``,
+  ...) on the profiler's clock, and while enabled every garbage-collector
+  pass is logged as a ``host.gc`` span.
 
 Disabled by default and zero-cost when off: recording entry points return
 after one boolean check, annotations are trace-time-only, nothing telemetry
@@ -84,6 +90,7 @@ from .spans import (  # noqa: F401
     flight_record,
     flight_records,
     span,
+    span_log,
     span_root,
 )
 from .trace import annotate, capture  # noqa: F401
@@ -99,7 +106,7 @@ __all__ = [
     "count_cache", "jit_trace_total", "snapshot", "export_jsonl",
     "metric_rows",
     # spans / flight recorder
-    "Span", "NULL_SPAN", "span", "span_root", "current_span",
+    "Span", "NULL_SPAN", "span", "span_root", "current_span", "span_log",
     "configure_flight", "flight_record", "flight_records", "flight_dump",
     "flight_autodump", "clear_flight",
     # SLOs

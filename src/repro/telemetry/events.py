@@ -203,19 +203,15 @@ def record_solve(name: str, info, *, method: str | None = None,
 
 def record_assembly(name: str, *, num_dofs: int | None = None,
                     nnz: int | None = None, num_cells: int | None = None,
-                    form: str | None = None, wall_us: float | None = None,
-                    **extra):
+                    form: str | None = None, **extra):
     """Record one assembly event (an eager ``assemble``/``assemble_rhs``
-    producing a global operator or load vector)."""
+    producing a global operator or load vector).  It carries no wall time:
+    the dispatch is asynchronous, so the host's wall is the ``tg.assemble``
+    span's and the device's is in a profile."""
     if not metrics.is_enabled():
         return None
     metrics.counter_inc("assemblies", 1, form=form or "?")
-    if wall_us is not None:
-        w = metrics.concrete_or_none(wall_us)
-        if w is not None:
-            metrics.histogram_observe("assembly_wall_us", float(w),
-                                      form=form or "?")
     return record_event(
-        "assembly", name, wall_us=wall_us, num_dofs=num_dofs, nnz=nnz,
+        "assembly", name, num_dofs=num_dofs, nnz=nnz,
         num_cells=num_cells, form=form, **extra,
     )

@@ -57,6 +57,8 @@ __all__ = [
     "append_jsonl_row",
     "register_snapshot_section",
     "register_row_provider",
+    "register_toggle_hook",
+    "register_reset_hook",
 ]
 
 # one observation cap per histogram key: summaries stay exact for any run
@@ -87,6 +89,8 @@ _IO_LOCK = threading.Lock()
 # being imported, keeping this module dependency-free within the package
 _SNAPSHOT_SECTIONS: dict[str, Any] = {}
 _ROW_PROVIDERS: list = []
+_TOGGLE_HOOKS: list = []
+_RESET_HOOKS: list = []
 
 
 def register_snapshot_section(name: str, fn) -> None:
@@ -99,6 +103,24 @@ def register_row_provider(fn) -> None:
     """Add a ``BENCH_JSON``-row source to :func:`metric_rows` — ``fn()``
     returning a list of row dicts."""
     _ROW_PROVIDERS.append(fn)
+
+
+def register_toggle_hook(fn) -> None:
+    """Call ``fn(enabled)`` now and on every :func:`enable` /
+    :func:`disable` (spans installs its garbage-collector hook here)."""
+    _TOGGLE_HOOKS.append(fn)
+    fn(_STATE.enabled)
+
+
+def register_reset_hook(fn) -> None:
+    """Call ``fn()`` on every :func:`reset` (spans clears its log here)."""
+    _RESET_HOOKS.append(fn)
+
+
+def _set_enabled(flag: bool) -> None:
+    _STATE.enabled = flag
+    for fn in _TOGGLE_HOOKS:
+        fn(flag)
 
 
 def enable(jsonl: str | None = None, on_nonconverged: str | None = None) -> None:
@@ -118,13 +140,13 @@ def enable(jsonl: str | None = None, on_nonconverged: str | None = None) -> None
         _STATE.on_nonconverged = on_nonconverged
     if jsonl is not None:
         _STATE.jsonl = jsonl
-    _STATE.enabled = True
+    _set_enabled(True)
 
 
 def disable() -> None:
     """Turn telemetry recording off (the registry contents are kept —
     call :func:`reset` to drop them)."""
-    _STATE.enabled = False
+    _set_enabled(False)
 
 
 def is_enabled() -> bool:
@@ -141,7 +163,7 @@ def enabled(jsonl: str | None = None, on_nonconverged: str | None = None):
     try:
         yield
     finally:
-        _STATE.enabled = prev_enabled
+        _set_enabled(prev_enabled)
         _STATE.jsonl = prev_jsonl
         _STATE.on_nonconverged = prev_policy
 
@@ -345,11 +367,14 @@ def snapshot() -> dict:
 
 
 def reset() -> None:
-    """Drop every recorded metric (the enabled flag is untouched)."""
+    """Drop every recorded metric and the span log (the enabled flag is
+    untouched)."""
     with _STATE.lock:
         _STATE.counters.clear()
         _STATE.gauges.clear()
         _STATE.hists.clear()
+    for fn in _RESET_HOOKS:
+        fn()
 
 
 def metric_rows() -> list[dict]:

@@ -25,6 +25,16 @@ On :meth:`Span.finish` a span folds into the existing registry — one
 ``span_us{span=<name>}`` histogram observation — and, when a JSONL stream
 is configured, appends one ``BENCH_JSON``-format row
 (``{"name": "span/<name>", "us_per_call", "derived", "trace_id", ...}``).
+Every finished span is also appended to a bounded in-memory **span log**
+(:func:`span_log`, cleared by :func:`~repro.telemetry.reset`).
+
+A span opened with ``with span(...)`` is *live*: for the block it also
+holds a ``jax.profiler.TraceAnnotation`` of its name, so a profile taken
+meanwhile shows it on the profiler's own clock, beside the device's
+operations.  A segment built afterwards with an explicit ``start_ns`` goes
+only to the log.  While telemetry is enabled, a ``gc.callbacks`` hook logs
+each collection of Python's garbage collector as a ``host.gc`` span tagged
+with its ``generation``.
 
 The **flight recorder** is a bounded ring buffer of the last K completed
 span trees plus caller context (admission key, bucket, ``SolveInfo``
@@ -35,12 +45,14 @@ expiry, shedding); :func:`flight_dump` dumps it on demand.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import threading
 import time
 from collections import deque
 
+import jax
 import numpy as np
 
 from . import metrics
@@ -53,6 +65,7 @@ __all__ = [
     "current_span",
     "push_span",
     "pop_span",
+    "span_log",
     "configure_flight",
     "flight_record",
     "flight_records",
@@ -64,6 +77,12 @@ __all__ = [
 _TRACE_IDS = itertools.count(1)
 _SPAN_IDS = itertools.count(1)
 _TLS = threading.local()  # per-thread stack of open spans
+
+# the event log's bound (events._EVENT_LIMIT); a deque appends without a
+# lock, which the garbage-collector hook needs: a collection can start while
+# the interrupted thread holds any lock that Python code takes
+_SPAN_LOG_LIMIT = 65536
+_SPAN_LOG: deque = deque(maxlen=_SPAN_LOG_LIMIT)
 
 
 def _clean_tag(v):
@@ -83,7 +102,7 @@ class Span:
     timestamps, so span walls and ``t_done - t_submit`` agree."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "tags",
-                 "start_ns", "end_ns", "children")
+                 "start_ns", "end_ns", "children", "_annotation")
 
     def __init__(self, name: str, *, trace_id: int | None = None,
                  parent: "Span | None" = None, start_ns: int | None = None,
@@ -97,6 +116,7 @@ class Span:
                          else int(start_ns))
         self.end_ns: int | None = None
         self.children: list[Span] = []
+        self._annotation = None
         if tags:
             self.tag(**tags)
 
@@ -144,6 +164,7 @@ class Span:
         for c in self.children:
             if c.end_ns is None:
                 c.finish(end_ns=self.end_ns)
+        _log(self)
         metrics.histogram_observe("span_us", self.wall_us, span=self.name)
         path = metrics.jsonl_path()
         if path:
@@ -184,12 +205,16 @@ class Span:
             **self.tags,
         }
 
-    # -- context-manager protocol (pushes onto the thread-local stack) -----
+    # -- context-manager protocol (pushes onto the thread-local stack and
+    # holds a profiler annotation of the span's name for the block) -------
     def __enter__(self) -> "Span":
         push_span(self)
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
+        self._annotation.__exit__(*exc)
         pop_span(self)
         self.finish()
 
@@ -283,6 +308,58 @@ def pop_span(sp) -> None:
         stack.pop()
     elif stack and sp in stack:
         stack.remove(sp)
+
+
+# ---------------------------------------------------------------------------
+# Span log and garbage-collector pauses
+# ---------------------------------------------------------------------------
+
+def _log(sp: Span) -> None:
+    _SPAN_LOG.append({
+        "name": sp.name, "trace_id": sp.trace_id, "span_id": sp.span_id,
+        "parent_id": sp.parent_id, "start_ns": sp.start_ns,
+        "end_ns": sp.end_ns, "tags": dict(sp.tags),
+    })
+
+
+def span_log() -> list[dict]:
+    """The finished spans, oldest first: ``name``, ``trace_id``,
+    ``span_id``, ``parent_id``, ``start_ns``, ``end_ns`` (on
+    ``time.monotonic_ns``) and ``tags``.  Bounded: past 65,536 spans the
+    oldest are dropped."""
+    return list(_SPAN_LOG)
+
+
+_GC_START_NS = 0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one ``host.gc`` span per collection.  It only
+    appends to the log: the histogram and the JSONL stream take locks that
+    the interrupted code may hold."""
+    global _GC_START_NS
+    if phase == "start":
+        _GC_START_NS = time.monotonic_ns()
+        return
+    sp = Span("host.gc", start_ns=_GC_START_NS,
+              generation=info.get("generation"))
+    sp.end_ns = time.monotonic_ns()
+    _log(sp)
+
+
+def _toggle_gc_hook(enabled: bool) -> None:
+    if enabled and _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    elif not enabled and _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+def _clear_log() -> None:
+    _SPAN_LOG.clear()
+
+
+metrics.register_toggle_hook(_toggle_gc_hook)
+metrics.register_reset_hook(_clear_log)
 
 
 # ---------------------------------------------------------------------------

@@ -262,7 +262,8 @@ def test_response_span_tree_segments_sum_to_e2e():
             assert tree["tags"]["outcome"] == "ok"
             assert tree["tags"]["request_id"] == p.request.request_id
             seg = resp.span_segments_us
-            assert list(seg) == ["queue_wait", "dispatch", "solve", "slice"]
+            assert list(seg) == ["queue_wait", "dispatch", "solve", "fetch",
+                                 "slice"]
             # the acceptance criterion: segments cover the full lifetime
             e2e_us = 1e6 * resp.e2e_s
             assert sum(seg.values()) == pytest.approx(e2e_us, rel=0.05)
@@ -274,6 +275,59 @@ def test_response_span_tree_segments_sum_to_e2e():
         # distinct requests get distinct trace ids
         tids = {p.response().trace["trace_id"] for p in pend}
         assert len(tids) == 4
+
+
+def test_batch_phases_are_live_spans_and_segments_are_contiguous():
+    svc = SolveService(window=0.0)
+    svc.warmup(_wave(1)[0], batch_sizes=(4,))
+    with telemetry.enabled():
+        telemetry.reset()
+        pend = [svc.submit(r) for r in _wave(3, seed=5)]
+        svc.drain()
+        log = telemetry.span_log()
+    telemetry.reset()
+    (batch,) = [s for s in log if s["name"] == "serve.batch"]
+    assert batch["tags"]["batch"] == 3 and batch["tags"]["padded"] == 4
+    phases = [s for s in log if s["parent_id"] == batch["span_id"]]
+    assert [s["name"] for s in phases] == [
+        "cache_lookup", "pad", "solve", "fetch", "slice"]
+    for a, b in zip(phases, phases[1:]):
+        assert batch["start_ns"] <= a["end_ns"] <= b["start_ns"] \
+            <= batch["end_ns"]
+    for p in pend:
+        tree = p.response().trace
+        segs = tree["children"]
+        assert [c["name"] for c in segs] == [
+            "queue_wait", "dispatch", "solve", "fetch", "slice"]
+        assert segs[0]["start_ns"] == tree["start_ns"]
+        for a, b in zip(segs, segs[1:]):
+            assert a["end_ns"] == b["start_ns"]
+        assert segs[-1]["end_ns"] == tree["end_ns"]
+        # each segment lies beside the live phase of the same name
+        live = {s["name"]: s for s in phases}
+        for c in segs[2:]:
+            assert c["start_ns"] <= live[c["name"]]["start_ns"]
+            assert c["end_ns"] >= live[c["name"]]["end_ns"]
+
+
+def test_warmed_batch_traces_no_krylov_loop():
+    svc = SolveService(window=0.0)
+    with telemetry.enabled():
+        telemetry.reset()
+        # a fresh service compiles its bucket executables: the CG loop of
+        # each is traced while warming up, and never again
+        svc.warmup(_wave(1)[0], batch_sizes=(2, 4))
+        warm = telemetry.snapshot()["counters"]
+        assert warm["krylov_traces{method=cg}"] >= 2
+        telemetry.reset()
+        for seed in (7, 8):
+            pend = [svc.submit(r) for r in _wave(3, seed=seed)]
+            svc.drain()
+            assert all(p.response().ok for p in pend)
+        counters = telemetry.snapshot()["counters"]
+    telemetry.reset()
+    assert not any(k.startswith("krylov_traces") for k in counters)
+    assert counters["serve_requests{outcome=ok}"] == 6
 
 
 def test_disabled_responses_carry_no_trace():

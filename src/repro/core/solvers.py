@@ -247,6 +247,14 @@ def _as_matvec(a) -> Callable:
     return a if callable(a) else a.matvec
 
 
+def _dot(a, b):
+    """``jnp.vdot`` as a product and a sum reduce.  XLA:TPU emulates a
+    float64 ``dot_general`` by splitting its operands into slices inside
+    while loops, which at 177k DOFs took most of a CG iteration on a v5e;
+    a float64 reduce needs no such loops."""
+    return jnp.sum(jnp.conj(a) * b)
+
+
 # ---------------------------------------------------------------------------
 # Conjugate gradients (SPD systems: Poisson, elasticity)
 # ---------------------------------------------------------------------------
@@ -282,7 +290,7 @@ def cg(matvec, b, x0=None, *, tol=1e-10, atol=1e-10, maxiter=10000, m=_identity)
     z0 = m(r0)
     rnorm0 = jnp.linalg.norm(r0)
     fold0 = jnp.array(False)
-    state = (x0, jnp.zeros_like(b), r0, z0, jnp.vdot(r0, z0), rnorm0,
+    state = (x0, jnp.zeros_like(b), r0, z0, _dot(r0, z0), rnorm0,
              jnp.maximum(target, _CG_REPLACE * rnorm0), fold0, jnp.array(0))
 
     def cond(s):
@@ -301,10 +309,10 @@ def cg(matvec, b, x0=None, *, tol=1e-10, atol=1e-10, maxiter=10000, m=_identity)
         r_true = b - av
         keep = jnp.linalg.norm(r_true - r) <= _CG_AGREE * rnorm
         # step
-        alpha = rz / jnp.vdot(p, av)
+        alpha = rz / _dot(p, av)
         r_new = jnp.where(fold, r_true, r - alpha * av)
         z = m(r_new)
-        rz_new = jnp.vdot(r_new, z)
+        rz_new = _dot(r_new, z)
         p_new = jnp.where(fold, jnp.where(keep, p, z), z + (rz_new / rz) * p)
         rnorm_new = jnp.linalg.norm(r_new)
         stop = jnp.where(fold, jnp.maximum(target, _CG_REPLACE * rnorm_new),
@@ -346,20 +354,20 @@ def bicgstab(matvec, b, x0=None, *, tol=1e-10, atol=1e-10, maxiter=10000, m=_ide
     def body(s):
         metrics.counter_inc(KRYLOV_TRACES, method="bicgstab")
         x, r, rho, alpha, omega, v, p, it = s
-        rho_new = jnp.vdot(rhat, r)
+        rho_new = _dot(rhat, r)
         beta = (rho_new / jnp.where(rho == 0, 1e-30, rho)) * (
             alpha / jnp.where(omega == 0, 1e-30, omega)
         )
         p = r + beta * (p - omega * v)
         phat = m(p)
         v = matvec(phat)
-        denom = jnp.vdot(rhat, v)
+        denom = _dot(rhat, v)
         alpha = rho_new / jnp.where(denom == 0, 1e-30, denom)
         s_vec = r - alpha * v
         shat = m(s_vec)
         t = matvec(shat)
-        tt = jnp.vdot(t, t)
-        omega = jnp.vdot(t, s_vec) / jnp.where(tt == 0, 1e-30, tt)
+        tt = _dot(t, t)
+        omega = _dot(t, s_vec) / jnp.where(tt == 0, 1e-30, tt)
         x = x + alpha * phat + omega * shat
         r = s_vec - omega * t
         return (x, r, rho_new, alpha, omega, v, p, it + 1)

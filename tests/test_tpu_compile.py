@@ -89,25 +89,29 @@ def _small_plan():
     return mesh, space, plan
 
 
+def _cg_solve(offsets):
+    """CG + Jacobi, float64, on ``CSR.matvec``'s row-layout arithmetic: on
+    the diagonals ``offsets`` of a banded pattern, or (``None``) on a
+    column table.  Values and layout tables are arguments, as they are
+    operands of the eager solve."""
+
+    def solve(vals, table, cols, diag, b):
+        rl = (RowLayout(table, cols=cols) if offsets is None
+              else RowLayout(table, offsets=offsets))
+        return cg(lambda x: row_layout_matvec(vals, x, rl), b,
+                  m=lambda r: r / diag, tol=1e-10, atol=0.0, maxiter=10000)
+
+    return solve
+
+
 @pytest.mark.parametrize("layout", ["diagonals", "columns"])
 def test_csr_cg_solve_compiles_at_a_million_dofs(one_chip, layout):
     """The solve of chip_smoke.py: CG + Jacobi with the ``csr`` matvec
     (``CSR.matvec``'s row-layout arithmetic, on the diagonals that the
     structured mesh has and on the column table of a general pattern),
-    float64, 1,002,001 DOFs.  Values and layout tables are arguments, as
-    they are operands of the eager solve."""
+    float64, 1,002,001 DOFs."""
     n, nnz, width = MILLION_DOFS, MILLION_NNZ, len(MILLION_OFFSETS)
-
-    def solve(vals, table, cols, diag, b):
-        rl = (RowLayout(table, offsets=MILLION_OFFSETS)
-              if layout == "diagonals" else RowLayout(table, cols=cols))
-
-        def matvec(x):
-            return row_layout_matvec(vals, x, rl)
-
-        return cg(matvec, b, m=lambda r: r / diag, tol=1e-10, atol=0.0,
-                  maxiter=10000)
-
+    solve = _cg_solve(MILLION_OFFSETS if layout == "diagonals" else None)
     args = [((nnz,), jnp.float64), ((width, n), jnp.int32),
             ((width, n), jnp.int32), ((n,), jnp.float64), ((n,), jnp.float64)]
     compiled = jax.jit(solve).lower(
@@ -115,6 +119,27 @@ def test_csr_cg_solve_compiles_at_a_million_dofs(one_chip, layout):
     ).compile()
     assert compiled.memory_analysis().argument_size_in_bytes >= nnz * 8
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("batch", [None, SERVE_BATCH])
+def test_cg_program_holds_one_while_loop(one_chip, batch):
+    """CG with the 9-offset ``csr`` matvec, float64, at SMALL_RESOLUTION,
+    alone and vmapped over a batch of operators as the service solves: the
+    compiled program holds its Krylov loop and no other.  A float64
+    ``dot_general`` would add five while loops over operand slices."""
+    r = SMALL_RESOLUTION
+    n, width = (r + 1) ** 2, len(MILLION_OFFSETS)
+    solve = _cg_solve((-(r + 2), -(r + 1), -r, -1, 0, 1, r, r + 1, r + 2))
+    lead = () if batch is None else (batch,)
+    args = [(lead + (width * n,), jnp.float64), ((width, n), jnp.int32),
+            ((width, n), jnp.int32), (lead + (n,), jnp.float64),
+            (lead + (n,), jnp.float64)]
+    if batch is not None:
+        solve = jax.vmap(solve, in_axes=(0, None, None, 0, 0))
+    compiled = jax.jit(solve).lower(
+        *(jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args)
+    ).compile()
+    assert compiled.as_text().count(" while(") == 1
 
 
 def test_diffusion_assembly_compiles_and_fits(one_chip):

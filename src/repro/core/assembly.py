@@ -49,8 +49,10 @@ from . import forms, weakform
 from .elements import get_element
 from .mesh import FunctionSpace
 from .routing import (
+    BlockRouting,
     MatrixRouting,
     VectorRouting,
+    build_block_routing,
     build_matrix_routing,
     build_vector_routing,
     scatter_free,
@@ -146,15 +148,21 @@ def geometry_context(
     coords: (E, nv_geo, d); geo_phi/geo_grad: geometric element tables
     (Q, nv_geo[, d]); phi/gradhat: field element tables (Q, k[, d]).
     Fully differentiable w.r.t. ``coords`` (shape optimization).
+
+    The contractions are broadcast products and sums, not einsums: XLA:TPU
+    emulates a float64 ``dot_general`` with while loops over operand
+    slices, where products and sums fuse into the element loop.
     """
     # J_eqij = Σ_a X_eai ĝeo_qaj     (Alg. 1 line 1)
-    j = jnp.einsum("eai,qaj->eqij", coords, geo_grad)
+    j = jnp.sum(coords[:, None, :, :, None] * geo_grad[None, :, :, None, :],
+                axis=2)
     det = _det(j)
     jinv = _inv(j, det)
     detj = jnp.abs(det)
     # push-forward 𝒢_eqai = Σ_j (J⁻¹)_ji ĝ_qaj   (Alg. 1 line 2)
-    grad = jnp.einsum("eqji,qaj->eqai", jinv, gradhat)
-    xq = jnp.einsum("qa,eai->eqi", geo_phi, coords)
+    grad = jnp.sum(jnp.swapaxes(jinv, -1, -2)[:, :, None, :, :]
+                   * gradhat[None, :, :, None, :], axis=-1)
+    xq = jnp.sum(geo_phi[None, :, :, None] * coords[:, None, :, :], axis=2)
     return forms.FormContext(
         w=w, phi=phi, detj=detj, grad=grad, xq=xq,
         scalar_cell_dofs=scalar_cell_dofs,
@@ -182,9 +190,15 @@ def facet_context(
 # Stage II reduce
 # ---------------------------------------------------------------------------
 
-def reduce_matrix(k_local: jnp.ndarray, routing: MatrixRouting, mode: str = "sorted"):
+def reduce_matrix(k_local: jnp.ndarray, routing, mode: str = "sorted",
+                  operands: tuple | None = None):
     """``S_mat · vec(K_local)`` as a deterministic segment reduction, in one
-    of the modes of :mod:`repro.core.routing`."""
+    of the modes of :mod:`repro.core.routing`.  A :class:`BlockRouting`
+    reduces over its vertex pattern, reading ``operands`` (default: its
+    own :meth:`~repro.core.routing.BlockRouting.operands`)."""
+    if isinstance(routing, BlockRouting):
+        return _reduce_blocks(k_local, routing, mode,
+                              operands or routing.operands(mode))
     v = k_local.reshape(-1)
     if mode == "sorted":
         vals = jax.ops.segment_sum(
@@ -202,14 +216,50 @@ def reduce_matrix(k_local: jnp.ndarray, routing: MatrixRouting, mode: str = "sor
     return vals
 
 
-def reduce_vector(f_local: jnp.ndarray, routing: VectorRouting, mode: str = "sorted"):
+def _reduce_blocks(k_local, routing: BlockRouting, mode: str, operands):
+    """The vertex-pattern Reduce of interleaved ``(E, nv·b, nv·b)`` local
+    matrices: the sums of each vertex pair's ``b × b`` block, then each DoF
+    entry taken from its block's sum."""
+    b, nv = routing.block, routing.cell_vertices
+    n = routing.vertex.nnz
+    if mode == "table":
+        # the cells along the minor axis: a TPU pads a minor axis of b or nv
+        # entries to 128 lanes, and a reshape that merges the cells with
+        # another axis copies the whole array
+        *tables, order = operands
+        e = k_local.shape[0]
+        v = k_local.reshape(e, nv, b, nv, b).transpose(2, 4, 1, 3, 0)
+        v = v.reshape(b, b, nv * nv, e)
+        sums = []
+        for t in tables:
+            pair, cell = jnp.divmod(t, e)
+            g = v[:, :, jnp.minimum(pair, nv * nv - 1), cell]
+            sums.append(jnp.sum(jnp.where(pair < nv * nv, g, 0.0), axis=2))
+        sums = jnp.concatenate(sums, -1)                   # (b, b, n)
+    else:
+        v = k_local.reshape(-1, nv, b, nv, b).transpose(0, 1, 3, 2, 4)
+        v = v.reshape(-1, b * b)
+        if mode == "sorted":
+            perm, seg, order = operands
+            sums = jax.ops.segment_sum(v[perm], seg, num_segments=n,
+                                       indices_are_sorted=True)
+        else:
+            seg, order = operands
+            sums = jax.ops.segment_sum(v, seg, num_segments=n)  # (n, b²)
+    return jnp.take(sums.reshape(-1), order)
+
+
+def reduce_vector(f_local: jnp.ndarray, routing: VectorRouting,
+                  mode: str = "sorted", operands: tuple | None = None):
     """``S_vec · vec(F_local)`` — reduce to touched dofs, then spread them to
-    all dofs: by one scatter, or in ``"table"`` mode by a gather."""
+    all dofs: by one scatter, or in ``"table"`` mode by a gather, through
+    ``operands`` (default: the routing's ``table_dev`` and
+    ``spread_dev``)."""
     v = f_local.reshape(-1)
     if mode == "table":
-        packed = table_segment_sum(v, routing.table_dev)
-        return jnp.take(packed, routing.spread_dev, axis=-1, mode="fill",
-                        fill_value=0)
+        table, spread = operands or (routing.table_dev, routing.spread_dev)
+        packed = table_segment_sum(v, table)
+        return jnp.take(packed, spread, axis=-1, mode="fill", fill_value=0)
     if mode == "sorted":
         packed = jax.ops.segment_sum(
             v[routing.perm_dev],
@@ -252,7 +302,7 @@ class PlanStatic:
     geo_phi: jnp.ndarray                 # (Q, nv_geo) geometry basis
     geo_grad: jnp.ndarray                # (Q, nv_geo, d) geometry gradients
     scalar_cell_dofs: jnp.ndarray | None  # (E, k_scalar) for nodal coeffs
-    mat_routing: MatrixRouting
+    mat_routing: MatrixRouting | BlockRouting
     vec_routing: VectorRouting
     num_dofs: int
     value_size: int
@@ -319,6 +369,7 @@ class AssemblyPlan:
             row_of_nnz=r.row_of_nnz,
             shape=(r.num_dofs, r.num_dofs),
             diag_pos=r.diag_pos,
+            block=self.static.value_size,
         )
 
     def batched_csr(self, vals: jnp.ndarray) -> BatchedCSR:
@@ -330,6 +381,7 @@ class AssemblyPlan:
             row_of_nnz=r.row_of_nnz,
             shape=(r.num_dofs, r.num_dofs),
             diag_pos=r.diag_pos,
+            block=self.static.value_size,
         )
 
 
@@ -355,10 +407,13 @@ def build_plan(space: FunctionSpace, quad_order: int | None = None,
 
     if space.value_size == 1:
         scalar_cell_dofs = jnp.asarray(space.cell_dofs)
+        mat_routing = build_matrix_routing(space.cell_dofs, None,
+                                           space.num_dofs)
     else:
-        scalar_cell_dofs = jnp.asarray(
-            space.cell_dofs[:, :: space.value_size] // space.value_size
-        )
+        scalar_dofs = space.cell_dofs[:, :: space.value_size] // space.value_size
+        scalar_cell_dofs = jnp.asarray(scalar_dofs)
+        mat_routing = build_block_routing(scalar_dofs, space.scalar_dofs,
+                                          space.value_size)
 
     static = PlanStatic(
         w=jnp.asarray(w),
@@ -367,7 +422,7 @@ def build_plan(space: FunctionSpace, quad_order: int | None = None,
         geo_phi=jnp.asarray(geo.tabulate(pts)),
         geo_grad=jnp.asarray(geo.tabulate_grad(pts)),
         scalar_cell_dofs=scalar_cell_dofs,
-        mat_routing=build_matrix_routing(space.cell_dofs, None, space.num_dofs),
+        mat_routing=mat_routing,
         vec_routing=build_vector_routing(space.cell_dofs, space.num_dofs),
         num_dofs=space.num_dofs,
         value_size=space.value_size,
@@ -445,10 +500,12 @@ def _zero_fallback_dtype(coords, facet_sums):
     return jnp.result_type(*dts) if dts else coords.dtype
 
 
-def _eval_form(static: PlanStatic, coords, spec, leaves, arity: str):
+def _eval_form(static: PlanStatic, coords, spec, leaves, arity: str,
+               operands=None):
     """Pure fused Map + Reduce over one lowered form.  All closure-free:
     ``static`` carries the tables, ``coords``/``leaves`` are the traced
-    inputs, ``spec`` is the static signature."""
+    inputs, ``spec`` is the static signature; ``operands`` are the tables
+    of a vector-valued space's Reduce (:func:`_reduce_operands`)."""
     _N_CORE_TRACES[0] += 1
     telemetry.count_trace("assembly", static, spec)
     with annotate("tg.map"):
@@ -463,7 +520,7 @@ def _eval_form(static: PlanStatic, coords, spec, leaves, arity: str):
     if arity == weakform.MATRIX:
         with annotate("tg.reduce"):
             out = (
-                reduce_matrix(local_sum, static.mat_routing, mode)
+                reduce_matrix(local_sum, static.mat_routing, mode, operands)
                 if local_sum is not None
                 else jnp.zeros(
                     (static.mat_routing.nnz,),
@@ -480,7 +537,7 @@ def _eval_form(static: PlanStatic, coords, spec, leaves, arity: str):
 
     with annotate("tg.reduce"):
         out = (
-            reduce_vector(local_sum, static.vec_routing, mode)
+            reduce_vector(local_sum, static.vec_routing, mode, operands)
             if local_sum is not None
             else jnp.zeros(
                 (static.num_dofs,),
@@ -528,12 +585,28 @@ def _cached_form_fn(key, build):
     return fn
 
 
+def _reduce_operands(static: PlanStatic, arity: str):
+    """The tables that the Reduce of a vector-valued space reads, passed to
+    the assembly's program as operands: at a million DoFs they are hundreds
+    of MB, and an executable with them compiled in as constants is too
+    large for the persistent compilation cache.  ``None`` for a scalar
+    space, whose tables are compiled in."""
+    r = static.mat_routing
+    if not isinstance(r, BlockRouting):
+        return None
+    if arity == weakform.MATRIX:
+        return r.operands(static.reduce_mode)
+    if static.reduce_mode == "table":
+        return (static.vec_routing.table_dev, static.vec_routing.spread_dev)
+    return None
+
+
 def _assemble_flat(coords, leaves, *, static, spec, arity):
     fn = _cached_form_fn(
         ("single", static, spec, arity),
-        lambda: lambda c, lv: _eval_form(static, c, spec, lv, arity),
+        lambda: lambda c, lv, ops: _eval_form(static, c, spec, lv, arity, ops),
     )
-    out = fn(coords, leaves)
+    out = fn(coords, leaves, _reduce_operands(static, arity))
     if telemetry.is_enabled():
         is_mat = arity == weakform.MATRIX
         telemetry.record_assembly(
@@ -581,13 +654,13 @@ def _assemble_batched_flat(coords, leaves, *, static, spec, arity, axes):
     coords_ax, leaf_axes = axes
 
     def build():
-        return lambda c, lv: jax.vmap(
-            lambda ci, lvi: _eval_form(static, ci, spec, lvi, arity),
+        return lambda c, lv, ops: jax.vmap(
+            lambda ci, lvi: _eval_form(static, ci, spec, lvi, arity, ops),
             in_axes=(coords_ax, leaf_axes),
         )(c, lv)
 
     fn = _cached_form_fn(("batched", static, spec, arity, axes), build)
-    out = fn(coords, leaves)
+    out = fn(coords, leaves, _reduce_operands(static, arity))
     if telemetry.is_enabled():
         is_mat = arity == weakform.MATRIX
         telemetry.record_assembly(

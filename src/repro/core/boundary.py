@@ -79,7 +79,10 @@ class DirichletCondenser:
         the *uncondensed* matrix (the lift needs the constrained columns).
         """
         u_d = self.boundary_field(values, dtype=f.dtype)
-        f_lift = (f - k.matvec(u_d)) * self.free_mask
+        # homogeneous data: K u_D = 0, and the lift is the masking alone
+        # (an apply of the whole operator saved)
+        homogeneous = isinstance(values, (int, float)) and values == 0.0
+        f_lift = (f if homogeneous else f - k.matvec(u_d)) * self.free_mask
         bc = self._bc_dofs_dev
         return f_lift.at[bc].set(u_d[bc])
 

@@ -172,17 +172,20 @@ def elasticity(ctx: FormContext, lam: float, mu: float, scale=None) -> jnp.ndarr
     local matrix is over interleaved vector dofs (a·d + i), matching
     FunctionSpace ordering.  ``scale`` is an optional per-element factor —
     the SIMP stiffness interpolation E(ρ) enters here (TensorOpt).
+
+    With ``T_aibj = Σ_q ŵ|detJ| 𝒢_ai 𝒢_bj``, the block of vertices (a, b)
+    is ``λ T_aibj + μ (T_ajbi + δ_ij Σ_k T_akbk)``, written as broadcast
+    products and sums for the reason :func:`diffusion` gives.
     """
     g = ctx.grad
-    e, q, nv, d = g.shape
-    s_q = eval_coefficient(scale, ctx)
-    w = ctx.wdet * s_q
-    t_lam = jnp.einsum("eq,eqai,eqbj->eaibj", w, g, g, optimize=True)
-    t_mu1 = jnp.einsum("eq,eqaj,eqbi->eaibj", w, g, g, optimize=True)
-    gdotg = jnp.einsum("eq,eqak,eqbk->eab", w, g, g, optimize=True)
-    eye = jnp.eye(d)
-    t_mu2 = jnp.einsum("eab,ij->eaibj", gdotg, eye)
-    k_local = lam * t_lam + mu * (t_mu1 + t_mu2)
+    e, _, nv, d = g.shape
+    w = (ctx.wdet * eval_coefficient(scale, ctx))[..., None, None]
+    t = jnp.sum((w * g)[:, :, :, :, None, None] * g[:, :, None, None, :, :],
+                axis=1)                                     # (E, a, i, b, j)
+    eye = jnp.eye(d, dtype=t.dtype)
+    gdotg = jnp.sum(t * eye[:, None, :], axis=(2, 4))        # (E, a, b)
+    k_local = lam * t + mu * (jnp.swapaxes(t, 2, 4)
+                              + gdotg[:, :, None, :, None] * eye[:, None, :])
     return k_local.reshape(e, nv * d, nv * d)
 
 
@@ -200,8 +203,10 @@ def vector_load(ctx: FormContext, f, d: int) -> jnp.ndarray:
     """∫ f · v for vector-valued v; ``f`` is a constant (d,) vector, a
     callable returning (E, Q, d), or an (E, Q, d) array."""
     f_q = eval_coefficient(f, ctx, vector_size=d)     # (E, Q, d)
-    e, q, nv = ctx.detj.shape[0], ctx.detj.shape[1], ctx.phi.shape[1]
-    out = jnp.einsum("eq,eqi,qa->eai", ctx.wdet, f_q, ctx.phi)
+    e, nv = ctx.detj.shape[0], ctx.phi.shape[1]
+    # products and sums, as in :func:`elasticity`
+    out = jnp.sum((ctx.wdet[..., None] * f_q)[:, :, None, :]
+                  * ctx.phi[None, :, :, None], axis=1)    # (E, a, i)
     return out.reshape(e, nv * d)
 
 

@@ -102,7 +102,9 @@ def _refuse_on_tpu(backend: str) -> None:
 
 
 def _csr_matvec(op) -> Callable:
-    return op.matvec  # CSR / BatchedCSR / LinearOperator all expose matvec
+    # CSR / BatchedCSR / LinearOperator all expose matvec; a CSR lays out
+    # what its applies share once
+    return op.loop_matvec() if isinstance(op, CSR) else op.matvec
 
 
 def _ell_matvec(op) -> Callable:

@@ -19,6 +19,9 @@ static padded table (:func:`segment_table`) and sums them densely, with no
 scatter-add.  XLA:TPU has no native float64 scatter-add (it emulates one
 with a serial two-word scatter), while a float64 gather is two native f32
 gathers, so "table" is the default on a TPU (:func:`scatter_free`).
+
+A vector-valued space routes its matrix Reduce through the vertex pattern
+(:class:`BlockRouting`), with each vertex pair's block as a trailing axis.
 """
 
 from __future__ import annotations
@@ -31,8 +34,10 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
+    "BlockRouting",
     "MatrixRouting",
     "VectorRouting",
+    "build_block_routing",
     "build_matrix_routing",
     "build_vector_routing",
     "scatter_free",
@@ -50,18 +55,20 @@ def scatter_free() -> bool:
 
 
 def segment_table(perm: np.ndarray, seg_ids: np.ndarray,
-                  num_segments: int) -> np.ndarray:
+                  num_segments: int, size: int | None = None) -> np.ndarray:
     """``(L, num_segments)`` int32 positions of each segment's entries, from
     the sort permutation ``perm`` and the sorted ``seg_ids`` it produces;
-    ``L`` is the largest segment and padding points past the end (gathered
-    as zero by :func:`table_segment_sum`).  Segments run along the minor
+    ``L`` is the largest segment and padding points past the end of the
+    ``size`` gathered entries (default: as many as ``perm`` has), which
+    :func:`table_segment_sum` gathers as zero.  Segments run along the minor
     axis: a TPU lays out a tiny minor axis of size L padded to 128 lanes,
     which made the summed gather take minutes to compile at 1M rows."""
     seg_ids = np.asarray(seg_ids)
     counts = np.bincount(seg_ids, minlength=num_segments)
     width = max(int(counts.max()) if counts.size else 0, 1)
     slot = np.arange(seg_ids.size) - (np.cumsum(counts) - counts)[seg_ids]
-    table = np.full((width, num_segments), seg_ids.size, dtype=np.int32)
+    table = np.full((width, num_segments),
+                    seg_ids.size if size is None else size, dtype=np.int32)
     table[slot, seg_ids] = perm
     return table
 
@@ -99,12 +106,19 @@ class MatrixRouting:
     row_of_nnz: np.ndarray   # (nnz,) row index of each stored entry
     diag_pos: np.ndarray     # (num_dofs,) position of (i,i) in vals, -1 if absent
 
-    def __post_init__(self):
-        object.__setattr__(self, "perm_dev", jnp.asarray(self.perm))
-        object.__setattr__(self, "seg_ids_dev", jnp.asarray(self.seg_ids))
-        object.__setattr__(
-            self, "seg_ids_unsorted_dev", jnp.asarray(self.seg_ids_unsorted)
-        )
+    # device mirrors, staged on first use: a Reduce reads the ones of its
+    # mode only
+    @cached_property
+    def perm_dev(self) -> jnp.ndarray:
+        return _staged(self.perm)
+
+    @cached_property
+    def seg_ids_dev(self) -> jnp.ndarray:
+        return _staged(self.seg_ids)
+
+    @cached_property
+    def seg_ids_unsorted_dev(self) -> jnp.ndarray:
+        return _staged(self.seg_ids_unsorted)
 
     @cached_property
     def table_dev(self) -> jnp.ndarray:
@@ -145,6 +159,133 @@ class VectorRouting:
         device."""
         return _staged(
             segment_table(self.perm, self.seg_ids, self.touched.shape[0]))
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class BlockRouting:
+    """Sparse-Reduce of a vector-valued space's local matrices through its
+    vertex pattern.
+
+    A space with ``block`` components per vertex numbers its DoFs
+    ``vertex·block + component``, so each pair of vertices of a cell owns a
+    dense ``block × block`` block of the local matrix.  The Reduce sums
+    those blocks over the vertex pairs (``vertex``, a :class:`MatrixRouting`
+    of the cells' vertices) with the block as a trailing axis — tables
+    ``block²`` times smaller than a routing of each scalar entry's — and
+    then places each summed entry at its position in the DoF pattern.
+    ``num_dofs``, ``nnz``, ``indptr``, ``indices``, ``row_of_nnz`` and
+    ``diag_pos`` are that DoF pattern, the one :func:`build_matrix_routing`
+    gives for the DoFs: condensers and CSR matrices read them as they read
+    a :class:`MatrixRouting`'s.
+    """
+
+    vertex: MatrixRouting
+    block: int
+    num_dofs: int
+    nnz: int
+    indptr: np.ndarray
+    indices: np.ndarray
+    row_of_nnz: np.ndarray
+    diag_pos: np.ndarray
+    position: np.ndarray     # (block, block, vertex nnz): DoF entry of each block entry
+    cell_vertices: int       # vertices per cell
+
+    def operands(self, mode: str) -> tuple:
+        """The device arrays that the Reduce of ``mode`` reads (the
+        assembly passes them to its program as operands)."""
+        if mode == "table":
+            return (*self._tables_dev, self._table_order_dev)
+        if mode == "sorted":
+            return (self.vertex.perm_dev, self.vertex.seg_ids_dev,
+                    self._order_dev)
+        return (self.vertex.seg_ids_unsorted_dev, self._order_dev)
+
+    @cached_property
+    def _tables_dev(self) -> tuple:
+        """:func:`segment_table` of the diagonal blocks and of the others,
+        each entry ``pair · E + cell`` of a contribution's cell and local
+        vertex pair (``pair = a · nv + b``).  A diagonal block gathers the
+        cells of a vertex's whole star, an off-diagonal one the few around
+        an edge (on the hollow cube 24 and 6): one table would pad every
+        block to the star's count."""
+        v, nv = self.vertex, self.cell_vertices
+        cells = v.perm.size // (nv * nv)
+        cell, pair = np.divmod(v.perm, nv * nv)
+        diag = v.row_of_nnz == v.indices
+        tables = []
+        for part in (diag, ~diag):
+            keep = part[v.seg_ids]
+            seg = (np.cumsum(part) - 1)[v.seg_ids[keep]]
+            tables.append(_staged(segment_table(
+                (pair * cells + cell)[keep], seg, int(part.sum()),
+                size=v.perm.size)))
+        return tuple(tables)
+
+    @cached_property
+    def _table_order_dev(self) -> jnp.ndarray:
+        """The table Reduce's sums are ``(b², vertex nnz)``, the diagonal
+        blocks first."""
+        v, b = self.vertex, self.block
+        diag = v.row_of_nnz == v.indices
+        column = np.empty(v.nnz, np.int64)
+        column[diag] = np.arange(int(diag.sum()))
+        column[~diag] = int(diag.sum()) + np.arange(v.nnz - int(diag.sum()))
+        return self._order(np.arange(b * b).reshape(b, b, 1) * v.nnz + column)
+
+    @cached_property
+    def _order_dev(self) -> jnp.ndarray:
+        """The segment Reduces' sums are ``(vertex nnz, b²)``."""
+        b = self.block
+        return self._order(np.arange(self.vertex.nnz) * b * b
+                           + np.arange(b * b).reshape(b, b, 1))
+
+    def _order(self, entry: np.ndarray) -> jnp.ndarray:
+        """(nnz,) position in the flattened block sums of each entry of the
+        DoF pattern, from ``entry`` ``(b, b, vertex nnz)``: the position of
+        each block entry."""
+        order = np.empty(self.nnz, np.int32)
+        order[self.position] = entry
+        return _staged(order)
+
+    @cached_property
+    def seg_ids_unsorted(self) -> np.ndarray:
+        """(E·k²,) DoF-pattern entry of each slot of the interleaved local
+        matrices, as :class:`MatrixRouting` has them (the sharded
+        Reduce's scatter ids)."""
+        b, nv = self.block, self.cell_vertices
+        p = self.vertex.seg_ids_unsorted.reshape(-1, nv, 1, nv, 1)
+        a = np.arange(b)
+        return self.position[a.reshape(1, 1, b, 1, 1),
+                             a.reshape(1, 1, 1, 1, b), p].ravel()
+
+
+def build_block_routing(vertex_dofs: np.ndarray, num_vertices: int,
+                        block: int) -> BlockRouting:
+    """:class:`BlockRouting` of cells with vertices ``vertex_dofs: (E, nv)``
+    and ``block`` components per vertex (DoF ``vertex·block + component``)."""
+    vertex = build_matrix_routing(vertex_dofs, None, num_vertices)
+    b = block
+    rows = vertex.row_of_nnz
+    # DoF row b·i + a holds b entries per block of vertex row i, in order
+    indptr = np.zeros(num_vertices * b + 1, np.int64)
+    indptr[1:] = np.cumsum(np.repeat(b * np.diff(vertex.indptr), b))
+    rank = np.arange(vertex.nnz) - vertex.indptr[rows]
+    a = np.arange(b).reshape(b, 1, 1)
+    c = np.arange(b).reshape(1, b, 1)
+    position = indptr[b * rows + a] + b * rank + c      # (b, b, vertex nnz)
+    nnz = b * b * vertex.nnz
+    indices = np.empty(nnz, np.int64)
+    indices[position] = b * vertex.indices + c
+    row_of_nnz = np.empty(nnz, np.int64)
+    row_of_nnz[position] = b * rows + a
+    diag_v = vertex.diag_pos
+    diag_pos = np.where(diag_v[:, None] >= 0,
+                        position[np.arange(b), np.arange(b)][:, diag_v].T, -1)
+    return BlockRouting(
+        vertex=vertex, block=b, num_dofs=num_vertices * b, nnz=nnz,
+        indptr=indptr, indices=indices, row_of_nnz=row_of_nnz,
+        diag_pos=diag_pos.ravel(), position=position,
+        cell_vertices=int(np.asarray(vertex_dofs).shape[1]))
 
 
 def build_matrix_routing(

@@ -424,7 +424,7 @@ def _solve_bwd(spec, return_info, res, g):
                         precond=spec.precond_name, phase="adjoint")
     # ∂L/∂vals = −λ_r · x_c at each stored (r, c) — never densified
     dvals = -lam[_dev(a.row_of_nnz)] * x[_dev(a.indices)]
-    da = CSR(dvals, a.indptr, a.indices, a.row_of_nnz, a.shape, a.diag_pos)
+    da = dataclasses.replace(a, vals=dvals)
     return (da, lam)
 
 

@@ -11,6 +11,7 @@ one shared static pattern, ``(B, nnz)`` traced values — the container behind
 from __future__ import annotations
 
 import dataclasses
+import weakref
 from functools import partial
 from typing import NamedTuple
 
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..telemetry import annotate
 from .routing import _staged, scatter_free, segment_table
 
@@ -69,14 +71,38 @@ class RowLayout(NamedTuple):
     offsets: tuple | None = None
 
 
-def _row_layout(csr) -> RowLayout:
-    """The pattern's :class:`RowLayout`: diagonals when a square pattern has
-    at most four times as many distinct column offsets as its widest row
-    has entries, a column table otherwise."""
+class BlockLayout(NamedTuple):
+    """How :meth:`CSR.matvec` reaches a vector-valued operator's entries:
+    as the ``b × b`` blocks of its vertex pairs.  ``table`` ``(b, b, K,
+    n_v)`` holds the position in ``vals`` of entry (a, c) of vertex row
+    i's k-th block (padding past the end, read as zero); ``cols`` ``(K,
+    n_v)`` holds that block's vertex column (padding: i itself).  ``x`` is
+    gathered once per vertex, not once per DoF entry."""
+
+    table: jnp.ndarray
+    cols: jnp.ndarray
+
+
+def _row_layout(csr) -> RowLayout | BlockLayout:
+    """The pattern's layout: vertex blocks for a vector-valued operator
+    (``csr.block > 1``); for a scalar one, diagonals when a square pattern
+    has at most four times as many distinct column offsets as its widest
+    row has entries, a column table otherwise.  Sets the gauge
+    ``matvec_gathers{kind}``: the layout's table entries, K × rows."""
     key = ("row_layout", id(csr.row_of_nnz))
     hit = _DEVICE_MIRRORS.get(key)
-    if hit is not None:
-        return hit[1]
+    layout, kind, gathers = (
+        hit[1] if hit is not None
+        else _remember(key, csr.row_of_nnz, _build_row_layout(csr)))
+    telemetry.gauge_set("matvec_gathers", gathers, kind=kind)
+    return layout
+
+
+def _build_row_layout(csr):
+    """``(layout, kind, K × rows)`` of :func:`_row_layout`."""
+    if csr.block > 1:
+        layout = _block_layout(csr)
+        return layout, "vertex_blocks", int(layout.cols.size)
     rows = np.asarray(csr.row_of_nnz)
     indices = np.asarray(csr.indices)
     offsets = np.unique(indices - rows)
@@ -89,15 +115,40 @@ def _row_layout(csr) -> RowLayout:
             np.arange(rows.size)
         layout = RowLayout(_staged(diag_table),
                            offsets=tuple(int(o) for o in offsets))
-    else:
-        # padding reads column i of a square operator (x_i − x_i = 0 in the
-        # difference form), column 0 of any other
-        pad = table >= rows.size
-        pad_col = np.arange(csr.shape[0]) if square else np.zeros(
-            csr.shape[0], np.int64)
-        cols = np.where(pad, pad_col[None, :], indices[np.where(pad, 0, table)])
-        layout = RowLayout(_staged(table), cols=_staged(cols.astype(np.int32)))
-    return _remember(key, csr.row_of_nnz, layout)
+        return layout, "diagonals", int(diag_table.size)
+    # padding reads column i of a square operator (x_i − x_i = 0 in the
+    # difference form), column 0 of any other
+    pad = table >= rows.size
+    pad_col = np.arange(csr.shape[0]) if square else np.zeros(
+        csr.shape[0], np.int64)
+    cols = np.where(pad, pad_col[None, :], indices[np.where(pad, 0, table)])
+    layout = RowLayout(_staged(table), cols=_staged(cols.astype(np.int32)))
+    return layout, "columns", int(table.size)
+
+
+def _block_layout(csr) -> BlockLayout:
+    """:class:`BlockLayout` of a pattern of full ``b × b`` blocks, whose
+    DoF ``b·i + a`` is component a of vertex i: the b rows of vertex i hold
+    the same blocks, each b consecutive entries of a row."""
+    b = csr.block
+    n_v = csr.shape[0] // b
+    indptr = np.asarray(csr.indptr)
+    indices = np.asarray(csr.indices)
+    start = indptr[:-1:b]                      # first entry of row b·i
+    width = indptr[1::b] - start               # entries of each of its rows
+    if not (csr.shape[0] == csr.shape[1] == n_v * b
+            and np.array_equal(np.diff(indptr), np.repeat(width, b))
+            and np.all(width % b == 0)):
+        raise ValueError(f"not a pattern of {b} x {b} vertex blocks")
+    k = np.arange(int(width.max()) // b)[:, None]
+    valid = b * k < width                      # (K, n_v)
+    first = np.where(valid, start + b * k, 0)
+    cols = np.where(valid, indices[first] // b, np.arange(n_v))
+    a = np.arange(b).reshape(b, 1, 1, 1)
+    c = np.arange(b).reshape(1, b, 1, 1)
+    table = np.where(valid, start + a * width + b * k + c, indices.size)
+    return BlockLayout(_staged(table.astype(np.int32)),
+                       _staged(cols.astype(np.int32)))
 
 
 def _shifted(x: jnp.ndarray, offsets: tuple) -> jnp.ndarray:
@@ -123,6 +174,34 @@ def row_layout_matvec(vals, x, layout: RowLayout, *, square: bool = True):
     return jnp.sum(a * (xc - x[..., None, :]), axis=-2) + jnp.sum(a, axis=-2) * x
 
 
+def block_values(vals, layout: BlockLayout) -> jnp.ndarray:
+    """``(..., b, b, K, n_v)``: the blocks of ``vals`` in ``layout``'s
+    order, zero where a row has fewer than K blocks."""
+    return jnp.take(vals, layout.table, axis=-1, mode="fill", fill_value=0)
+
+
+@annotate("tg.spmv")
+def block_layout_matvec(blocks, x, cols):
+    """``A x`` from the operator's :func:`block_values` and its vertex
+    columns ``cols`` (:class:`BlockLayout`), in the difference form of
+    :meth:`CSR.matvec`: ``y_i = Σ_k A_ik (x_(c_k) − x_i) + (Σ_k A_ik)
+    x_i`` over 3-vectors (b-vectors) ``x_i`` and blocks ``A_ik``."""
+    b, n_v = blocks.shape[-4], blocks.shape[-1]
+    xv = jnp.swapaxes(x.reshape(x.shape[:-1] + (n_v, b)), -1, -2)
+    d = jnp.take(xv, cols, axis=-1) - xv[..., None, :]      # (..., c, K, i)
+    y = (jnp.sum(blocks * d[..., None, :, :, :], axis=(-3, -2))
+         + jnp.sum(jnp.sum(blocks, axis=-2) * xv[..., None, :, :], axis=-2))
+    return jnp.swapaxes(y, -1, -2).reshape(x.shape)
+
+
+_block_values = jax.jit(block_values)
+
+
+@jax.jit
+def _block_matvec(vals, x, layout: BlockLayout):
+    return block_layout_matvec(block_values(vals, layout), x, layout.cols)
+
+
 def clear_device_mirrors():
     """Release every cached (host, device) pattern-array pair, ELL layout and
     operator diagonal — part of the ``repro.core.clear_assembly_caches``
@@ -137,8 +216,9 @@ def clear_device_mirrors():
 # diagonal is a fixed gather of ``vals`` and for a matrix-free operator a
 # diagonal-only assembly — both pure functions of the anchor array's values.
 # Keyed on the *value anchor* (``vals`` for CSR, the operator object for
-# matrix-free), with a strong reference so ids cannot be recycled while
-# cached; same FIFO bound rationale as the device mirrors above.
+# matrix-free), held by a weak reference whose death drops the entry, so
+# ids cannot be recycled while cached (a strong reference where the anchor
+# takes none); same FIFO bound rationale as the device mirrors above.
 _DIAGONALS: dict[tuple[int, str], tuple[object, jnp.ndarray]] = {}
 _DIAGONALS_LIMIT = 256
 
@@ -173,6 +253,13 @@ def cached_diagonal(op) -> jnp.ndarray:
         return d
     while len(_DIAGONALS) >= _DIAGONALS_LIMIT:
         _DIAGONALS.pop(next(iter(_DIAGONALS)))
+    try:
+        # the entry lives as long as its anchor: a solve loop that
+        # assembles new values each step would otherwise keep every step's
+        # values on the device (at a million DoFs, 362 MB a step)
+        anchor = weakref.ref(anchor, lambda _, k=key: _DIAGONALS.pop(k, None))
+    except TypeError:
+        pass
     _DIAGONALS[key] = (anchor, d)
     return d
 
@@ -186,10 +273,12 @@ class CSR:
     row_of_nnz: np.ndarray       # static, (nnz,)
     shape: tuple[int, int]       # static
     diag_pos: np.ndarray | None = None  # static
+    block: int = 1               # static: components per vertex (value_size)
 
     # -- pytree ----------------------------------------------------------
     def tree_flatten(self):
-        aux = (self.indptr, self.indices, self.row_of_nnz, self.shape, self.diag_pos)
+        aux = (self.indptr, self.indices, self.row_of_nnz, self.shape,
+               self.diag_pos, self.block)
         return (self.vals,), aux
 
     @classmethod
@@ -208,7 +297,13 @@ class CSR:
         (Σ_j a_ij) x_i``: a stiffness row's O(1) terms would otherwise
         cancel down to its O(h²) result, which costs the digits that
         XLA:TPU's float64 emulation does not carry (there the error of
-        ``K u`` drops from 2.0e-10 to 1.2e-11 of ``‖f‖`` at 1M DOFs)."""
+        ``K u`` drops from 2.0e-10 to 1.2e-11 of ``‖f‖`` at 1M DOFs).
+
+        A vector-valued operator (``block > 1``) is applied by vertex
+        blocks (:class:`BlockLayout`) on every platform, in the same
+        difference form."""
+        if self.block > 1:
+            return _block_matvec(self.vals, x, _row_layout(self))
         if scatter_free():
             return row_layout_matvec(self.vals, x, _row_layout(self),
                                      square=self.shape[0] == self.shape[1])
@@ -237,6 +332,17 @@ class CSR:
             num_segments=self.shape[0],
             indices_are_sorted=True,
         )
+
+    def loop_matvec(self):
+        """``x ↦ A x`` for a loop that applies A many times (the ``csr``
+        backend of :func:`repro.core.make_matvec`): a vector-valued
+        operator's blocks are gathered from ``vals`` once, here, rather
+        than in every apply."""
+        if self.block == 1:
+            return self.matvec
+        layout = _row_layout(self)
+        blocks = _block_values(self.vals, layout)
+        return lambda x: block_layout_matvec(blocks, x, layout.cols)
 
     def diagonal(self) -> jnp.ndarray:
         assert self.diag_pos is not None, "diagonal positions not precomputed"
@@ -280,10 +386,12 @@ class BatchedCSR:
     row_of_nnz: np.ndarray       # static, (nnz,)
     shape: tuple[int, int]       # static, per-instance shape
     diag_pos: np.ndarray | None = None  # static
+    block: int = 1               # static, as in :class:`CSR`
 
     # -- pytree ----------------------------------------------------------
     def tree_flatten(self):
-        aux = (self.indptr, self.indices, self.row_of_nnz, self.shape, self.diag_pos)
+        aux = (self.indptr, self.indices, self.row_of_nnz, self.shape,
+               self.diag_pos, self.block)
         return (self.vals,), aux
 
     @classmethod
@@ -322,24 +430,22 @@ class BatchedCSR:
             row_of_nnz=first.row_of_nnz,
             shape=first.shape,
             diag_pos=first.diag_pos,
+            block=first.block,
         )
 
     def as_csr(self) -> CSR:
         """Reinterpret as a single :class:`CSR` sharing this pattern — valid
         when ``vals`` is one instance's ``(nnz,)`` slice (e.g. inside a
         ``vmap`` over the batch axis)."""
-        return CSR(self.vals, self.indptr, self.indices, self.row_of_nnz,
-                   self.shape, self.diag_pos)
+        return self._one(self.vals)
 
     def __getitem__(self, b):
         """Integer index → one instance as a :class:`CSR`; slice → the
         sub-family as a :class:`BatchedCSR`."""
         if isinstance(b, (int, np.integer)):
-            return CSR(self.vals[b], self.indptr, self.indices,
-                       self.row_of_nnz, self.shape, self.diag_pos)
+            return self._one(self.vals[b])
         if isinstance(b, slice):
-            return BatchedCSR(self.vals[b], self.indptr, self.indices,
-                              self.row_of_nnz, self.shape, self.diag_pos)
+            return dataclasses.replace(self, vals=self.vals[b])
         raise TypeError(
             f"BatchedCSR indices must be int or slice, got {type(b).__name__}"
         )
@@ -363,7 +469,7 @@ class BatchedCSR:
 
     def _one(self, vals) -> CSR:
         return CSR(vals, self.indptr, self.indices, self.row_of_nnz,
-                   self.shape, self.diag_pos)
+                   self.shape, self.diag_pos, self.block)
 
     def diagonal(self) -> jnp.ndarray:
         assert self.diag_pos is not None, "diagonal positions not precomputed"

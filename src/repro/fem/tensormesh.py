@@ -88,15 +88,16 @@ class _ProblemBase:
         # krylov: build the matvec and preconditioner, trace, compile and
         # dispatch the loop; wait: the host blocks on the loop's result
         with telemetry.span("tg.solve.krylov"):
+            matvec = make_matvec(k, be)
             u, info = _method(spec.method)(
-                make_matvec(k, be), f, m=make_preconditioner(k, spec.precond),
+                matvec, f, m=make_preconditioner(k, spec.precond),
                 tol=spec.tol, atol=spec.atol, maxiter=spec.maxiter)
         where = f"{type(self).__name__}.solve"
         with telemetry.span("tg.solve.wait"):
             events.check_convergence(info, where=where)
             events.record_solve(where, info, method=spec.method, backend=be,
                                 precond=spec.precond_name)
-            rel = float(jnp.linalg.norm(k.matvec(u) - f) / jnp.linalg.norm(f))
+            rel = float(jnp.linalg.norm(matvec(u) - f) / jnp.linalg.norm(f))
         res = _SolveResult(u, int(info.iters), rel, bool(info.converged))
         return (res, info) if return_info else res
 
@@ -276,9 +277,8 @@ class AdvectionDiffusionProblem(_ProblemBase):
 
 
 class ElasticityProblem(_ProblemBase):
-    """Isotropic linear elasticity, constant body force (paper Benchmark II)."""
-
-    method = "bicgstab"
+    """Isotropic linear elasticity, constant body force (paper Benchmark II).
+    The form is symmetric positive definite, so CG is the default."""
 
     def __init__(self, mesh: Mesh, e_mod=1.0, nu=0.3):
         d = mesh.dim
@@ -292,25 +292,32 @@ class ElasticityProblem(_ProblemBase):
     def assemble(self, body_force=None, scale=None):
         d = self.mesh.dim
         bf = jnp.ones(d) if body_force is None else jnp.asarray(body_force)
-        k = self.asm.assemble(wf.elasticity(self.lam, self.mu, scale=scale))
-        f = self.asm.assemble_rhs(wf.source(bf))
-        return self.bc.apply(k, f)
+        with telemetry.span("tg.assemble"):
+            with telemetry.span("tg.assemble.matrix"):
+                k = self.asm.assemble(
+                    wf.elasticity(self.lam, self.mu, scale=scale))
+            with telemetry.span("tg.assemble.vector"):
+                f = self.asm.assemble_rhs(wf.source(bf))
+            with telemetry.span("tg.condense"):
+                return self.bc.apply(k, f)
 
     def solve(self, body_force=None, spec: SolverSpec | None = None,
               tol=None, maxiter=None, backend=None, return_info=False):
         spec = self._spec(spec, tol, maxiter, "solve")
-        if backend in ("matfree", "matfree_sharded"):
-            d = self.mesh.dim
-            bf = jnp.ones(d) if body_force is None else jnp.asarray(body_force)
-            load = self.asm.assemble_rhs(wf.source(bf))
-            return self._solve_matfree(
-                wf.elasticity(self.lam, self.mu), load, spec,
-                return_info=return_info,
-                sharded=backend == "matfree_sharded",
-            )
-        k, f = self.assemble(body_force)
-        return self._solve_system(k, f, spec, backend=backend,
-                                  return_info=return_info)
+        with telemetry.span("tg.solve"):
+            if backend in ("matfree", "matfree_sharded"):
+                d = self.mesh.dim
+                bf = (jnp.ones(d) if body_force is None
+                      else jnp.asarray(body_force))
+                load = self.asm.assemble_rhs(wf.source(bf))
+                return self._solve_matfree(
+                    wf.elasticity(self.lam, self.mu), load, spec,
+                    return_info=return_info,
+                    sharded=backend == "matfree_sharded",
+                )
+            k, f = self.assemble(body_force)
+            return self._solve_system(k, f, spec, backend=backend,
+                                      return_info=return_info)
 
 
 class MixedBCPoisson(_ProblemBase):

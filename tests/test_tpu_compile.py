@@ -20,6 +20,7 @@ from repro.core import (
     FunctionSpace,
     assemble,
     build_plan,
+    hollow_cube_tet,
     matfree_operator,
     unit_square_tri,
     weakform as wf,
@@ -34,6 +35,7 @@ MILLION_NNZ = 7_006_001
 # column offsets of its stiffness pattern (lexicographic vertex numbering)
 MILLION_OFFSETS = (-1002, -1001, -1000, -1, 0, 1, 1000, 1001, 1002)
 MILLION_CELLS = 2_000_000
+ELASTIC_CELLS = 1_959_552        # hollow_cube_tet(72): 1,038,426 DOFs
 # Map + Reduce and the matrix-free apply compile their routing tables in as
 # constants, so compile time grows with the mesh: resolution 64 (8,192
 # triangles) compiles in a few seconds, and the memory check below scales
@@ -153,6 +155,56 @@ def test_diffusion_assembly_compiles_and_fits(one_chip):
     ).lower(_shapes(plan, one_chip), _shapes(rho, one_chip)).compile()
     scale = MILLION_CELLS / mesh.num_cells
     assert _device_bytes(compiled, scale) < HBM_BYTES
+
+
+def _elastic_plan():
+    mesh = hollow_cube_tet(8)
+    plan = build_plan(FunctionSpace(mesh, element_for_mesh(mesh),
+                                    value_size=3))
+    assert plan.static.reduce_mode == "table"
+    return plan
+
+
+def test_elasticity_assembly_holds_no_dot_general(one_chip):
+    """Map (geometry + elasticity blocks) + the vertex-pattern Reduce of a
+    vector-valued space, float64, on the hollow cube at 8 cells a side: no
+    float64 ``dot_general``, which XLA:TPU emulates with while loops over
+    operand slices."""
+    lowered = jax.jit(
+        lambda p: assemble(p, wf.elasticity(0.6, 0.4)).vals
+    ).lower(_shapes(_elastic_plan(), one_chip))
+    assert "dot_general" not in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert " dot(" not in text and "convolution(" not in text
+
+
+def test_elasticity_map_reduce_fits_at_a_million_dofs(one_chip):
+    """The same Map + Reduce at the sizes of 72 cells a side (1,959,552
+    tetrahedra, 5,035,286 vertex pairs, 45,317,574 stored entries), its
+    tables as operands as the assembly passes them: it fits one chip (a
+    reshape that merged the cells with a tiny axis once needed 36 GB)."""
+    import types
+
+    from repro.core import forms
+    from repro.core.assembly import _reduce_blocks, geometry_context
+
+    st = _elastic_plan().static
+    cells, verts, pairs, nnz = ELASTIC_CELLS, 346_142, 5_035_286, 45_317_574
+    routing = types.SimpleNamespace(block=3, cell_vertices=4,
+                                    vertex=types.SimpleNamespace(nnz=pairs))
+
+    def map_reduce(coords, diag, off, order):
+        ctx = geometry_context(coords, st.geo_phi, st.geo_grad, st.phi,
+                               st.gradhat, st.w)
+        return _reduce_blocks(forms.elasticity(ctx, 0.6, 0.4), routing,
+                              "table", (diag, off, order))
+
+    args = [((cells, 4, 3), jnp.float64), ((24, verts), jnp.int32),
+            ((6, pairs - verts), jnp.int32), ((nnz,), jnp.int32)]
+    compiled = jax.jit(map_reduce).lower(
+        *(jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args)
+    ).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
 
 
 def test_matfree_apply_compiles_and_fits(one_chip):

@@ -83,12 +83,52 @@ class BlockLayout(NamedTuple):
     cols: jnp.ndarray
 
 
-def _row_layout(csr) -> RowLayout | BlockLayout:
-    """The pattern's layout: vertex blocks for a vector-valued operator
-    (``csr.block > 1``); for a scalar one, diagonals when a square pattern
-    has at most four times as many distinct column offsets as its widest
-    row has entries, a column table otherwise.  Sets the gauge
-    ``matvec_gathers{kind}``: the layout's table entries, K × rows."""
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class BandLayout:
+    """The banded form of :class:`BlockLayout`, for a vertex pattern whose
+    pairs lie nearly all on a few vertex offsets: slot s of vertex row i is
+    block ``(i, i + offsets[s])`` (a static tuple), zero where the pair is
+    absent, so ``x`` is read as shifted slices.  The blocks are gathered
+    through ``table`` as in :class:`BlockLayout` and put in their slots by
+    ``slots`` ``(K, n_v)`` (-1: off the band).  The pairs off the band sit
+    on the vertex rows ``rows`` alone: ``rem_table`` ``(b, b, K_r, n_r)``
+    holds their positions in ``vals`` and ``rem_cols`` ``(K_r, n_r)`` their
+    vertex columns (padding as in :class:`BlockLayout`); all three are
+    ``None`` where every pair is on the band."""
+
+    table: jnp.ndarray
+    slots: jnp.ndarray
+    rem_table: jnp.ndarray | None
+    rem_cols: jnp.ndarray | None
+    rows: jnp.ndarray | None
+    offsets: tuple
+
+    def tree_flatten(self):
+        return ((self.table, self.slots, self.rem_table, self.rem_cols,
+                 self.rows), self.offsets)
+
+    @classmethod
+    def tree_unflatten(cls, offsets, children):
+        return cls(*children, offsets)
+
+
+# a vertex offset takes a band slot where at least this share of the vertex
+# rows holds it; a pattern is banded where its slots stay within this many
+# times the column table's and the pairs off them within this share
+_BAND_ROW_SHARE = 0.01
+_BAND_MAX_SLOTS = 2.5
+_BAND_MAX_REMAINDER = 0.05
+
+
+def _row_layout(csr) -> RowLayout | BlockLayout | BandLayout:
+    """The pattern's layout: for a vector-valued operator (``csr.block >
+    1``) vertex bands where the vertex pattern is banded, vertex blocks
+    otherwise; for a scalar one, diagonals when a square pattern has at
+    most four times as many distinct column offsets as its widest row has
+    entries, a column table otherwise.  Sets the gauge
+    ``matvec_gathers{kind}``: the entries of the table that the layout
+    gathers through, K × rows (vertex bands: the remainder's)."""
     key = ("row_layout", id(csr.row_of_nnz))
     hit = _DEVICE_MIRRORS.get(key)
     layout, kind, gathers = (
@@ -102,7 +142,10 @@ def _build_row_layout(csr):
     """``(layout, kind, K × rows)`` of :func:`_row_layout`."""
     if csr.block > 1:
         layout = _block_layout(csr)
-        return layout, "vertex_blocks", int(layout.cols.size)
+        if isinstance(layout, BlockLayout):
+            return layout, "vertex_blocks", int(layout.cols.size)
+        rem = 0 if layout.rows is None else int(layout.rem_cols.size)
+        return layout, "vertex_bands", rem
     rows = np.asarray(csr.row_of_nnz)
     indices = np.asarray(csr.indices)
     offsets = np.unique(indices - rows)
@@ -126,10 +169,11 @@ def _build_row_layout(csr):
     return layout, "columns", int(table.size)
 
 
-def _block_layout(csr) -> BlockLayout:
-    """:class:`BlockLayout` of a pattern of full ``b × b`` blocks, whose
-    DoF ``b·i + a`` is component a of vertex i: the b rows of vertex i hold
-    the same blocks, each b consecutive entries of a row."""
+def _block_layout(csr) -> BlockLayout | BandLayout:
+    """Layout of a pattern of full ``b × b`` blocks, whose DoF ``b·i + a``
+    is component a of vertex i: the b rows of vertex i hold the same
+    blocks, each b consecutive entries of a row.  A :class:`BandLayout`
+    where :func:`_vertex_bands` finds the pattern banded."""
     b = csr.block
     n_v = csr.shape[0] // b
     indptr = np.asarray(csr.indptr)
@@ -147,8 +191,47 @@ def _block_layout(csr) -> BlockLayout:
     a = np.arange(b).reshape(b, 1, 1, 1)
     c = np.arange(b).reshape(1, b, 1, 1)
     table = np.where(valid, start + a * width + b * k + c, indices.size)
+    bands = _vertex_bands(table, cols, valid, indices.size)
+    if bands is not None:
+        return bands
     return BlockLayout(_staged(table.astype(np.int32)),
                        _staged(cols.astype(np.int32)))
+
+
+def _vertex_bands(table, cols, valid, nnz) -> BandLayout | None:
+    """The :class:`BandLayout` of a vertex-block pattern of ``nnz`` stored
+    entries, given as a :class:`BlockLayout`'s numpy ``table`` and ``cols``
+    and the ``(K, n_v)`` mask of its blocks, or ``None`` where the pattern
+    is not banded: its band slots would exceed ``_BAND_MAX_SLOTS`` × K, or
+    the pairs off them ``_BAND_MAX_REMAINDER`` of all pairs (as on a mesh
+    numbered at random)."""
+    n_k, n_v = cols.shape
+    off = cols - np.arange(n_v)
+    held, rows_holding = np.unique(off[valid], return_counts=True)
+    offsets = held[rows_holding >= _BAND_ROW_SHARE * n_v]
+    band = valid & np.isin(off, offsets)
+    rem = valid & ~band
+    if (offsets.size > _BAND_MAX_SLOTS * n_k
+            or rem.sum() > _BAND_MAX_REMAINDER * valid.sum()):
+        return None
+    slots = np.where(band, np.searchsorted(offsets, off), -1)
+    rows = np.flatnonzero(rem.any(axis=0))
+    rem_table = rem_cols = None
+    if rows.size:
+        # each remainder row's blocks off the band first, in column order
+        k = np.argsort(~rem[:, rows], axis=0, kind="stable")[
+            :int(rem.sum(axis=0).max())]
+        on = rem[k, rows]
+        rem_table = _staged(np.where(on, table[:, :, k, rows], nnz)
+                            .astype(np.int32))
+        rem_cols = _staged(np.where(on, cols[k, rows], rows)
+                           .astype(np.int32))
+        rows = _staged(rows.astype(np.int32))
+    else:
+        rows = None
+    return BandLayout(_staged(table.astype(np.int32)),
+                      _staged(slots.astype(np.int32)), rem_table, rem_cols,
+                      rows, tuple(int(o) for o in offsets))
 
 
 def _shifted(x: jnp.ndarray, offsets: tuple) -> jnp.ndarray:
@@ -174,23 +257,61 @@ def row_layout_matvec(vals, x, layout: RowLayout, *, square: bool = True):
     return jnp.sum(a * (xc - x[..., None, :]), axis=-2) + jnp.sum(a, axis=-2) * x
 
 
-def block_values(vals, layout: BlockLayout) -> jnp.ndarray:
-    """``(..., b, b, K, n_v)``: the blocks of ``vals`` in ``layout``'s
-    order, zero where a row has fewer than K blocks."""
-    return jnp.take(vals, layout.table, axis=-1, mode="fill", fill_value=0)
+def block_values(vals, layout: BlockLayout | BandLayout) -> tuple:
+    """What every vertex-block apply of ``vals`` reads, gathered once: the
+    blocks in ``layout``'s order (``(..., b, b, K, n_v)``, zero where a
+    row has fewer than K blocks; on a :class:`BandLayout` ``(..., b, b,
+    S, n_v)`` by band slot), their row sums ``Σ_k A_ik`` ``(..., b, b,
+    n_v)`` and, on a :class:`BandLayout` with pairs off the band, the
+    remainder's blocks ``(..., b, b, K_r, n_r)``."""
+    blocks = jnp.take(vals, layout.table, axis=-1, mode="fill", fill_value=0)
+    sums = jnp.sum(blocks, axis=-2)
+    if isinstance(layout, BlockLayout):
+        return blocks, sums
+    # each block into its slot by selects: a second gather of vals, at the
+    # band's 2-3x the entries, would cost more than the first
+    slot = jnp.arange(len(layout.offsets))[:, None]
+    band = jnp.zeros(blocks.shape[:-2] + slot.shape[:1] + blocks.shape[-1:],
+                     blocks.dtype)
+    for k in range(blocks.shape[-2]):
+        band = jnp.where(layout.slots[k] == slot, blocks[..., k:k + 1, :],
+                         band)
+    if layout.rows is None:
+        return band, sums
+    rem = jnp.take(vals, layout.rem_table, axis=-1, mode="fill",
+                   fill_value=0)
+    return band, sums, rem
+
+
+def _block_rows(blocks, xc, xi):
+    """``Σ_k A_ik (x_(c_k) − x_i)`` over b-vectors: ``blocks`` ``(..., b,
+    b, K, m)``, ``xc`` ``(..., b, K, m)``, ``xi`` ``(..., b, m)``."""
+    d = xc - xi[..., None, :]
+    return jnp.sum(blocks * d[..., None, :, :, :], axis=(-3, -2))
 
 
 @annotate("tg.spmv")
-def block_layout_matvec(blocks, x, cols):
-    """``A x`` from the operator's :func:`block_values` and its vertex
-    columns ``cols`` (:class:`BlockLayout`), in the difference form of
-    :meth:`CSR.matvec`: ``y_i = Σ_k A_ik (x_(c_k) − x_i) + (Σ_k A_ik)
-    x_i`` over 3-vectors (b-vectors) ``x_i`` and blocks ``A_ik``."""
+def block_layout_matvec(values, x, layout: BlockLayout | BandLayout):
+    """``A x`` from the operator's :func:`block_values` in the difference
+    form of :meth:`CSR.matvec`: ``y_i = Σ_k A_ik (x_(c_k) − x_i) + (Σ_k
+    A_ik) x_i`` over 3-vectors (b-vectors) ``x_i`` and blocks ``A_ik``.
+    ``x_(c_k)`` is gathered through a :class:`BlockLayout`'s columns; on a
+    :class:`BandLayout` it is a shifted slice of ``x``, gathered only for
+    the remainder's pairs, whose rows are then added in."""
+    blocks, sums = values[:2]
     b, n_v = blocks.shape[-4], blocks.shape[-1]
     xv = jnp.swapaxes(x.reshape(x.shape[:-1] + (n_v, b)), -1, -2)
-    d = jnp.take(xv, cols, axis=-1) - xv[..., None, :]      # (..., c, K, i)
-    y = (jnp.sum(blocks * d[..., None, :, :, :], axis=(-3, -2))
-         + jnp.sum(jnp.sum(blocks, axis=-2) * xv[..., None, :, :], axis=-2))
+    if isinstance(layout, BlockLayout):
+        xc = jnp.take(xv, layout.cols, axis=-1)         # (..., c, K, i)
+    else:
+        xc = _shifted(xv, layout.offsets)               # (..., c, S, i)
+    y = (_block_rows(blocks, xc, xv)
+         + jnp.sum(sums * xv[..., None, :, :], axis=-2))
+    if isinstance(layout, BandLayout) and layout.rows is not None:
+        rem = _block_rows(values[2], jnp.take(xv, layout.rem_cols, axis=-1),
+                          jnp.take(xv, layout.rows, axis=-1))
+        y = y.at[..., layout.rows].add(rem, indices_are_sorted=True,
+                                       unique_indices=True)
     return jnp.swapaxes(y, -1, -2).reshape(x.shape)
 
 
@@ -198,8 +319,8 @@ _block_values = jax.jit(block_values)
 
 
 @jax.jit
-def _block_matvec(vals, x, layout: BlockLayout):
-    return block_layout_matvec(block_values(vals, layout), x, layout.cols)
+def _block_matvec(vals, x, layout: BlockLayout | BandLayout):
+    return block_layout_matvec(block_values(vals, layout), x, layout)
 
 
 def clear_device_mirrors():
@@ -300,8 +421,10 @@ class CSR:
         ``K u`` drops from 2.0e-10 to 1.2e-11 of ``‖f‖`` at 1M DOFs).
 
         A vector-valued operator (``block > 1``) is applied by vertex
-        blocks (:class:`BlockLayout`) on every platform, in the same
-        difference form."""
+        blocks on every platform, in the same difference form: on a banded
+        vertex pattern as shifted slices of ``x`` with a gather for the
+        few pairs off the band (:class:`BandLayout`), else through a
+        gather of ``x`` by vertex column (:class:`BlockLayout`)."""
         if self.block > 1:
             return _block_matvec(self.vals, x, _row_layout(self))
         if scatter_free():
@@ -336,13 +459,13 @@ class CSR:
     def loop_matvec(self):
         """``x ↦ A x`` for a loop that applies A many times (the ``csr``
         backend of :func:`repro.core.make_matvec`): a vector-valued
-        operator's blocks are gathered from ``vals`` once, here, rather
-        than in every apply."""
+        operator's blocks and their row sums are laid out from ``vals``
+        once, here, rather than in every apply."""
         if self.block == 1:
             return self.matvec
         layout = _row_layout(self)
-        blocks = _block_values(self.vals, layout)
-        return lambda x: block_layout_matvec(blocks, x, layout.cols)
+        values = _block_values(self.vals, layout)
+        return lambda x: block_layout_matvec(values, x, layout)
 
     def diagonal(self) -> jnp.ndarray:
         assert self.diag_pos is not None, "diagonal positions not precomputed"

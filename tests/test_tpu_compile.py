@@ -9,6 +9,7 @@ never at import — and every test is skipped where it cannot be described.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,12 @@ from repro.core import (
 )
 from repro.core.mesh import element_for_mesh
 from repro.core.solvers import cg
-from repro.core.sparse import RowLayout, row_layout_matvec
+from repro.core.sparse import (
+    BandLayout,
+    RowLayout,
+    block_layout_matvec,
+    row_layout_matvec,
+)
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
 MILLION_DOFS = 1_002_001        # unit_square_tri(1000)
@@ -36,6 +42,14 @@ MILLION_NNZ = 7_006_001
 MILLION_OFFSETS = (-1002, -1001, -1000, -1, 0, 1, 1000, 1001, 1002)
 MILLION_CELLS = 2_000_000
 ELASTIC_CELLS = 1_959_552        # hollow_cube_tet(72): 1,038,426 DOFs
+ELASTIC_VERTICES = 346_142
+# its vertex pattern's band slots (the vertex offsets that at least 1% of
+# the vertex rows hold) and the pairs off them: 5,244 rows, at most 4 a row
+ELASTIC_OFFSETS = (-5403, -5402, -5330, -5329, -4178, -4177, -4143, -4142,
+                   -4105, -4104, -74, -73, -39, -38, -1, 0, 1, 38, 39, 73,
+                   74, 4104, 4105, 4142, 4143, 4177, 4178, 5329, 5330, 5402,
+                   5403)
+ELASTIC_REMAINDER = (4, 5_244)
 # Map + Reduce and the matrix-free apply compile their routing tables in as
 # constants, so compile time grows with the mesh: resolution 64 (8,192
 # triangles) compiles in a few seconds, and the memory check below scales
@@ -205,6 +219,40 @@ def test_elasticity_map_reduce_fits_at_a_million_dofs(one_chip):
         *(jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args)
     ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def _gather_sizes(hlo: str) -> list[int]:
+    """Elements of the output of each gather in a compiled program."""
+    return [int(np.prod([int(d) for d in dims.split(",") if d]))
+            for dims in re.findall(r"= [a-z0-9]+\[([0-9,]*)\]\S* gather\(",
+                                   hlo)]
+
+
+def test_elasticity_cg_compiles_on_the_vertex_bands(one_chip):
+    """The elasticity solve's Krylov loop at 72 cells a side: CG + Jacobi
+    on the vertex-band matvec (``CSR.loop_matvec``), float64, 1,038,426
+    DOFs, the laid-out blocks and the remainder's tables as arguments.  It
+    fits one chip, holds one ``while``, and reads ``x`` through no gather
+    as large as the (15, n_v) column table's."""
+    n_v, (k_r, n_r) = ELASTIC_VERTICES, ELASTIC_REMAINDER
+    n, slots = 3 * n_v, len(ELASTIC_OFFSETS)
+
+    def solve(band, sums, rem, rem_cols, rows, diag, b):
+        layout = BandLayout(None, None, None, rem_cols, rows, ELASTIC_OFFSETS)
+        return cg(lambda x: block_layout_matvec((band, sums, rem), x, layout),
+                  b, m=lambda r: r / diag, tol=1e-10, atol=0.0, maxiter=5000)
+
+    args = [((3, 3, slots, n_v), jnp.float64), ((3, 3, n_v), jnp.float64),
+            ((3, 3, k_r, n_r), jnp.float64), ((k_r, n_r), jnp.int32),
+            ((n_r,), jnp.int32), ((n,), jnp.float64), ((n,), jnp.float64)]
+    compiled = jax.jit(solve).lower(
+        *(jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args)
+    ).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+    text = compiled.as_text()
+    assert text.count(" while(") == 1
+    gathers = _gather_sizes(text)
+    assert gathers and max(gathers) < 15 * n_v
 
 
 def test_matfree_apply_compiles_and_fits(one_chip):

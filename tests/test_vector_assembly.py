@@ -6,8 +6,9 @@ Acceptance-critical properties:
 
 * the block Reduce gives the DoF pattern of a routing of each scalar entry,
   with values equal to a relative 1e-14, in every Reduce mode;
-* the vertex-block matvec equals the column-table matvec of the same
-  values;
+* the vertex-block matvec, banded (shifted slices of ``x`` and a gathered
+  remainder) where the vertex pattern is and through its column table
+  where it is not, equals the column-table matvec of the same values;
 * ``ElasticityProblem`` assembles the reference's K and F and solves them
   on the hollow cube; a 2D space (value_size 2) matches a NumPy assembly;
 * scalar spaces keep their routing and row layouts.
@@ -35,6 +36,7 @@ from repro.core import (
     hollow_cube_tet,
     rectangle_tri,
     sparse_solve,
+    unit_cube_tet,
     unit_square_tri,
     weakform as wf,
 )
@@ -155,27 +157,76 @@ def test_elasticity_2d_matches_numpy_assembly():
                                atol=1e-14 * np.abs(load_ref).max())
 
 
-def test_vertex_block_matvec_matches_column_table(monkeypatch):
+# vertex patterns by the layout they take: banded with pairs off the band
+# (the hollow cube's numbering skips the cavity), banded with none, and not
+# banded (the column table)
+BLOCK_PATTERNS = {
+    "hollow12": (hollow_cube_tet(12), "remainder"),
+    "cube6": (unit_cube_tet(6), "band"),
+    "renumbered": (_renumbered(hollow_cube_tet(6)), "columns"),
+}
+
+
+@pytest.mark.parametrize("which", sorted(BLOCK_PATTERNS))
+def test_vertex_block_matvec_matches_column_table(monkeypatch, which):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    space = _space(_renumbered(hollow_cube_tet(6)), 3)
+    mesh, form = BLOCK_PATTERNS[which]
+    space = _space(mesh, 3)
     plan = build_plan(space)
     vals = jnp.asarray(np.random.default_rng(2).standard_normal(plan.nnz))
     k = plan.csr(vals)
+    x = jnp.asarray(np.random.default_rng(3).standard_normal(k.shape[0]))
+    layout = sparse._row_layout(k)
+    if form == "columns":
+        assert isinstance(layout, sparse.BlockLayout)
+    else:
+        assert isinstance(layout, sparse.BandLayout)
+        assert (layout.rows is not None) == (form == "remainder")
+    # the vertex-block column table of the same pattern, and the scalar
+    # operator's own row layout
+    with monkeypatch.context() as m:
+        m.setattr(sparse, "_vertex_bands", lambda *_: None)
+        columns = sparse._block_layout(k)
+    assert isinstance(columns, sparse.BlockLayout)
+    want = np.asarray(sparse._block_matvec(vals, x, columns))
     scalar = dataclasses.replace(k, block=1,
                                  row_of_nnz=np.array(k.row_of_nnz))
-    layout = sparse._row_layout(scalar)
-    assert layout.cols is not None and layout.offsets is None
-    x = jnp.asarray(np.random.default_rng(3).standard_normal(k.shape[0]))
-    want = np.asarray(sparse.row_layout_matvec(vals, x, layout))
-    assert isinstance(sparse._row_layout(k), sparse.BlockLayout)
+    scalar_layout = sparse._row_layout(scalar)
+    if form == "columns":
+        assert scalar_layout.cols is not None
+        assert scalar_layout.offsets is None
     scale = np.abs(want).max()
     for got in (k.matvec(x), k.loop_matvec()(x),
                 jax.jit(lambda v, y: dataclasses.replace(k, vals=v)
-                        .matvec(y))(vals, x)):
+                        .matvec(y))(vals, x),
+                sparse.row_layout_matvec(vals, x, scalar_layout)):
         np.testing.assert_allclose(np.asarray(got), want, rtol=0,
                                    atol=1e-13 * scale)
     np.testing.assert_allclose(want, k.to_scipy() @ np.asarray(x), rtol=0,
                                atol=1e-13 * scale)
+
+
+def test_vertex_bands_hold_every_block_once():
+    """Each vertex pair of the hollow cube's pattern sits in the band slot
+    of its offset or in the remainder, not both; the band's offsets are
+    those that at least 1% of the vertex rows hold."""
+    plan = build_plan(_space(hollow_cube_tet(12), 3))
+    k = plan.csr(jnp.zeros(plan.nnz))
+    layout = sparse._row_layout(k)
+    n_v = k.shape[0] // 3
+    dof_rows, dof_cols = np.asarray(k.row_of_nnz), np.asarray(k.indices)
+    first = (dof_rows % 3 == 0) & (dof_cols % 3 == 0)
+    rows, cols = dof_rows[first] // 3, dof_cols[first] // 3
+    held, count = np.unique(cols - rows, return_counts=True)
+    assert layout.offsets == tuple(held[count >= 0.01 * n_v])
+    band = {(i, c) for i, c in zip(rows.tolist(), cols.tolist())
+            if c - i in layout.offsets}
+    rem = [(i, c) for i, col in zip(np.asarray(layout.rows).tolist(),
+                                    np.asarray(layout.rem_cols).T.tolist())
+           for c in col if c != i]
+    assert len(rem) == len(set(rem)) and band.isdisjoint(rem)
+    assert band.union(rem) == set(zip(rows.tolist(), cols.tolist()))
+    assert int(np.sum(np.asarray(layout.slots) >= 0)) == len(band)
 
 
 @pytest.mark.parametrize("mesh,kind,width", [
@@ -210,8 +261,10 @@ def test_scalar_plan_keeps_its_routing_and_layout(monkeypatch, mesh, kind,
         assert len(layout.offsets) == width
 
 
-def test_vertex_blocks_gauge_counts_vertex_rows():
-    prob = ElasticityProblem(hollow_cube_tet(6))
+@pytest.mark.parametrize("which", ["hollow12", "renumbered"])
+def test_vertex_blocks_gauge_counts_vertex_rows(which):
+    mesh, form = BLOCK_PATTERNS[which]
+    prob = ElasticityProblem(mesh)
     k, _ = prob.assemble()
     telemetry.enable()
     try:
@@ -220,9 +273,15 @@ def test_vertex_blocks_gauge_counts_vertex_rows():
     finally:
         telemetry.disable()
         telemetry.reset()
-    # a vertex of the Kuhn cube mesh shares cells with at most 14 others
-    assert gauges["matvec_gathers{kind=vertex_blocks}"] == \
-        15 * prob.mesh.num_vertices
+    if form == "columns":
+        # a vertex of the Kuhn cube mesh shares cells with at most 14 others
+        assert gauges["matvec_gathers{kind=vertex_blocks}"] == \
+            15 * prob.mesh.num_vertices
+    else:
+        # 448 pairs off the 31 band slots, on 144 vertex rows, at most 4 a
+        # row: the remainder's (4, 144) table
+        assert gauges["matvec_gathers{kind=vertex_bands}"] == 4 * 144
+        assert "matvec_gathers{kind=vertex_blocks}" not in gauges
 
 
 def test_elasticity_problem_solves_with_cg_and_spans():

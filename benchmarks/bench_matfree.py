@@ -87,27 +87,6 @@ def _apply_case(mesh, tag: str):
         matfree_state_bytes=op_coords.state_bytes(), csr_bytes=csr_b,
     )
 
-    # streaming SpMV (HBM-resident x): VMEM footprint independent of N —
-    # the row carries the footprint formula's value next to the CSR bytes
-    from repro.core import csr_to_ell
-    from repro.kernels import ell_matvec_stream
-    from repro.kernels.spmv_ell import BLOCK_N, N_BUFFERS, stream_vmem_bytes
-
-    ell = csr_to_ell(k)
-    stream_mv = lambda v: ell_matvec_stream(ell, v)  # noqa: E731
-    np.testing.assert_allclose(
-        np.asarray(stream_mv(x)), np.asarray(k.matvec(x)), atol=1e-12
-    )
-    t_stream = time_fn(stream_mv, x, warmup=3, iters=25)
-    vmem_b = stream_vmem_bytes(*ell.vals.shape, block_n=BLOCK_N,
-                               nbuf=N_BUFFERS)
-    emit_json(
-        f"ell_stream_matvec_{tag}", t_stream,
-        f"vs_csr={t_stream / t_csr:.2f}x;vmem_bytes={vmem_b}",
-        dofs=space.num_dofs, ratio_vs_csr=round(t_stream / t_csr, 2),
-        stream_vmem_bytes=vmem_b, csr_bytes=csr_b,
-    )
-
     # sharded matrix-free apply (1 device locally; CI runs the 8-device leg)
     import jax as _jax
 

@@ -3,42 +3,16 @@
 Derived: DoFs, solver iterations, relative residual (must be < 1e-10 to
 match the paper's tolerance).
 
-Streaming/sharded rows (this file's perf-gate additions): ``ell_stream``
-runs the whole CG on the HBM-resident streaming SpMV — full mode solves an
-N ≥ 1e6-DOF 2D Poisson end-to-end (the million-DOF claim), quick mode the
-same path at CI scale; ``matfree_sharded`` spans a single matrix-free CG
-over every local device."""
+The sharded row (``matfree_sharded``) spans a single matrix-free CG over
+every local device."""
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.core import hollow_cube_tet, unit_cube_tet, unit_square_tri
+from repro.core import hollow_cube_tet, unit_cube_tet
 from repro.fem import ElasticityProblem, PoissonProblem
 
 from .common import emit, emit_json, is_quick, time_fn
-
-
-def _stream_case(quick: bool):
-    # quick: the reduced-N CI proof of the streaming solve path; full: the
-    # million-DOF row — unit_square_tri(1000) has 1_002_001 DoFs, and the
-    # streaming kernel's VMEM footprint is independent of N
-    n = 32 if quick else 1000
-    prob = PoissonProblem(unit_square_tri(n))
-    res, info = prob.solve(backend="ell_stream", tol=1e-10, return_info=True)
-    assert res.converged, "streaming-SpMV CG did not converge"
-    dofs = prob.space.num_dofs
-    if not quick:
-        assert dofs >= 1_000_000, f"full-mode streaming row must be ≥1e6 DoFs, got {dofs}"
-    t = time_fn(lambda: prob.solve(backend="ell_stream", tol=1e-10).u,
-                warmup=0, iters=2 if quick else 1)
-    emit_json(
-        f"poisson2d_stream_solve_n{dofs}", t,
-        f"dofs={dofs};iters={res.iters};relres={res.residual:.1e}",
-        dofs=dofs, iterations=int(info.iters),
-        final_residual=float(info.residual),
-        converged=bool(info.converged), relres=res.residual,
-    )
 
 
 def _sharded_case(quick: bool):
@@ -79,7 +53,6 @@ def main():
         t_sp = time_fn(lambda: spla.spsolve(ks, np.asarray(f)), warmup=0, iters=2)
         emit(f"poisson3d_scipy_n{prob.space.num_dofs}", t_sp, "baseline=scipy_spsolve")
 
-    _stream_case(quick)
     _sharded_case(quick)
 
     for n in (3,) if quick else (4, 8):

@@ -2,8 +2,7 @@
 
 Steps/sec and end-to-end wall-clock for heat (θ-method) and wave
 (Newmark-β) rollouts on the assembled operators, plus the inner
-residual/matvec CSR vs ELL (jnp) vs ELL (Pallas kernel, interpret on CPU)
-comparison — the matrix-free fast-path trade the subsystem exposes.
+residual ``K·u − f`` on the CSR.
 
 Emits JSON-lines alongside the CSV rows when ``BENCH_JSON`` is set
 (see :mod:`benchmarks.common`).
@@ -17,7 +16,6 @@ from repro.core import (
     DirichletCondenser,
     FunctionSpace,
     GalerkinAssembler,
-    csr_to_ell,
     unit_square_tri,
 )
 from repro.core.mesh import element_for_mesh
@@ -31,7 +29,6 @@ N_STEPS = 50
 
 
 def main() -> None:
-    from repro.kernels import ell_residual
     from repro.transient import (
         CRANK_NICOLSON,
         NewmarkIntegrator,
@@ -58,9 +55,6 @@ def main() -> None:
         ("transient/heat_cn_csr",
          ThetaIntegrator(mass, stiff, dt=1e-3, theta=CRANK_NICOLSON, bc=bc,
                          tol=1e-10)),
-        ("transient/heat_be_ell",
-         ThetaIntegrator(mass, stiff, dt=1e-3, theta=1.0, bc=bc, tol=1e-10,
-                         backend="ell")),
         ("transient/wave_newmark_csr",
          NewmarkIntegrator(mass, stiff, dt=1e-3, bc=bc, tol=1e-10)),
     ]
@@ -81,18 +75,11 @@ def main() -> None:
               f"traj_steps_per_sec={total / (us * 1e-6):.0f}",
               n_dofs=n, n_steps=N_STEPS, batch=8)
 
-    # -- inner residual: CSR vs ELL(jnp) vs ELL(Pallas) ------------------------
+    # -- inner residual ------------------------------------------------------
     lhs = integ.lhs
-    ell = csr_to_ell(lhs)
     f = mass.matvec(u0)
     r_csr = jax.jit(lambda u: lhs.matvec(u) - f)
-    r_ell = jax.jit(lambda u: ell.matvec(u) - f)
     emit_json("transient/residual_csr", time_fn(r_csr, u0), n_dofs=n)
-    emit_json("transient/residual_ell_jnp", time_fn(r_ell, u0), n_dofs=n)
-    emit_json("transient/residual_ell_pallas",
-              time_fn(lambda u: ell_residual(ell, u, f), u0, iters=3),
-              "interpret_mode" if jax.default_backend() != "tpu" else "",
-              n_dofs=n)
 
 
 if __name__ == "__main__":

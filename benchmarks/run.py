@@ -10,7 +10,7 @@ Prints ``name,us_per_call,derived`` CSV rows.  Module → paper artifact map:
   bench_operator_learning  — Table 2 (wave operator learning, ID/OOD)
   bench_topo_opt           — Table 3 (cantilever SIMP)
   bench_kernels            — Pallas kernel microbench (interpret mode off-TPU)
-  bench_transient          — repro.transient rollouts (heat/wave, CSR vs ELL)
+  bench_transient          — repro.transient rollouts (heat/wave on the CSR)
   bench_weakform           — fused multi-term WeakForm assemble vs separate+add
   bench_batched_assembly   — vmap-batched multi-instance assembly vs B singles
   bench_matfree            — matrix-free apply/solve vs assembled CSR

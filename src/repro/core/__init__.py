@@ -25,13 +25,6 @@ from .assembly import (  # noqa: E402,F401
 )
 from .boundary import DirichletCondenser, FacetAssembler  # noqa: E402,F401
 from .elements import ReferenceElement, get_element  # noqa: E402,F401
-from .matvec import (  # noqa: E402,F401
-    MATVEC_BACKENDS,
-    make_matvec,
-    make_residual,
-    matvec_backends,
-    register_matvec_backend,
-)
 from .mesh import (  # noqa: E402,F401
     FunctionSpace,
     Mesh,
@@ -84,11 +77,8 @@ from .elemalg import (  # noqa: E402,F401
 )
 from .sparse import (  # noqa: E402,F401
     CSR,
-    ELL,
     BatchedCSR,
     cached_diagonal,
-    csr_to_ell,
-    ell_layout,
 )
 from . import weakform  # noqa: E402,F401
 from .weakform import WeakForm  # noqa: E402,F401

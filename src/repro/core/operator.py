@@ -1,7 +1,7 @@
 """Matrix-free Galerkin operators: ``y = A(form) @ x`` without CSR values.
 
 The assembled path materializes the global value vector (one float per nnz,
-plus pattern arrays and optional ELL mirrors) before the Krylov loop ever
+plus pattern arrays) before the Krylov loop ever
 runs.  This module applies the operator **directly from the weak form**:
 
     gather   x_e = x[cell_dofs]                 (element-local Map input)

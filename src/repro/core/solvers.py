@@ -14,11 +14,11 @@
   Because a spec is hashable it is also the jit/custom-vjp static argument
   and the ``repro.serve`` admission-key component.
 * preconditioner registry — :func:`register_preconditioner` maps a name to
-  a ``factory(op) -> m(x)`` (mirroring :mod:`repro.core.matvec`'s backend
-  registry).  Built-ins: ``identity``/``none``, ``jacobi``; the element
-  tensor-algebra layer (:mod:`repro.core.elemalg`) registers ``ebe`` and
-  ``chebyshev`` on import (resolved lazily here, so ``SolverSpec(precond=
-  "chebyshev")`` works without importing elemalg first).
+  a ``factory(op) -> m(x)``.  Built-ins: ``identity``/``none``,
+  ``jacobi``; the element tensor-algebra layer (:mod:`repro.core.elemalg`)
+  registers ``ebe`` and ``chebyshev`` on import (resolved lazily here, so
+  ``SolverSpec(precond="chebyshev")`` works without importing elemalg
+  first).
 * :func:`sparse_solve` — ``jax.custom_vjp``: the backward pass solves the
   adjoint system ``Kᵀλ = ḡ`` with the *same* solver and emits the **sparse**
   cotangent ``∂/∂vals = −λ[rows]·U[cols]`` (only at stored nnz positions) and
@@ -178,7 +178,7 @@ _MATFREE_DEFAULT = SolverSpec(method="cg")
 
 
 # ---------------------------------------------------------------------------
-# Preconditioner registry (mirrors repro.core.matvec's backend registry)
+# Preconditioner registry
 # ---------------------------------------------------------------------------
 
 def jacobi_preconditioner(a) -> Callable:
@@ -206,7 +206,7 @@ def register_preconditioner(name: str, factory: Callable, *,
 
     ``op`` is whatever reaches the solve (CSR, MatFreeOperator, ...);
     ``m(x)`` must be trace-compatible (it runs inside the Krylov
-    ``while_loop``).  Mirrors :func:`repro.core.matvec.register_matvec_backend`.
+    ``while_loop``).
     """
     if name in _PRECONDITIONERS and not overwrite:
         raise ValueError(
@@ -243,7 +243,7 @@ def make_preconditioner(op, precond="jacobi") -> Callable:
 
 def _as_matvec(a) -> Callable:
     """Normalize an operator argument: a callable is used as-is, anything
-    else must expose ``.matvec`` (CSR, MatFreeOperator, ELL)."""
+    else must expose ``.matvec`` (CSR, MatFreeOperator)."""
     return a if callable(a) else a.matvec
 
 

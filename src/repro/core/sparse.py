@@ -1,4 +1,4 @@
-"""Sparse containers (CSR / ELL / BatchedCSR) as jax pytrees, with SpMV/SpMM.
+"""Sparse containers (CSR / BatchedCSR) as jax pytrees, with SpMV/SpMM.
 
 The CSR *pattern* (indptr/indices/row ids) is static numpy baked at setup —
 only ``vals`` is traced, preserving the paper's O(1)-graph property: the
@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import dataclasses
 import weakref
-from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -23,14 +22,14 @@ from .. import telemetry
 from ..telemetry import annotate
 from .routing import _staged, scatter_free, segment_table
 
-__all__ = ["CSR", "ELL", "BatchedCSR", "csr_to_ell", "ell_layout"]
+__all__ = ["CSR", "BatchedCSR"]
 
 
 # device mirrors of static numpy pattern arrays, keyed by id: staged to the
 # device once instead of per traced call.  The numpy key array is kept alive
 # by the strong reference so ids cannot be recycled while cached; the cache
 # is FIFO-bounded because some callers mint fresh pattern arrays per call
-# (e.g. csr_to_ell cols in a solve loop) — eviction just costs a re-stage.
+# — eviction just costs a re-stage.
 # The CSR row layouts derived from a pattern array live here too, under a
 # ("row_layout", id) key.
 _DEVICE_MIRRORS: dict[object, tuple[np.ndarray, object]] = {}
@@ -324,11 +323,10 @@ def _block_matvec(vals, x, layout: BlockLayout | BandLayout):
 
 
 def clear_device_mirrors():
-    """Release every cached (host, device) pattern-array pair, ELL layout and
-    operator diagonal — part of the ``repro.core.clear_assembly_caches``
+    """Release every cached (host, device) pattern-array pair, row layout
+    and operator diagonal — part of the ``repro.core.clear_assembly_caches``
     memory-release path."""
     _DEVICE_MIRRORS.clear()
-    _ELL_LAYOUTS.clear()
     _DIAGONALS.clear()
 
 
@@ -347,8 +345,8 @@ _DIAGONALS_LIMIT = 256
 def cached_diagonal(op) -> jnp.ndarray:
     """``op.diagonal()`` memoized per (operator identity, dtype).
 
-    The cache key anchors on ``op.vals`` when present (a :class:`CSR` /
-    :class:`ELL` rebuilt around the same value buffer shares the diagonal)
+    The cache key anchors on ``op.vals`` when present (a :class:`CSR`
+    rebuilt around the same value buffer shares the diagonal)
     and on the operator object otherwise.  Tracers are never cached: inside
     a trace the diagonal is part of the jaxpr and caching by ``id`` would
     leak abstract values across traces.
@@ -457,10 +455,9 @@ class CSR:
         )
 
     def loop_matvec(self):
-        """``x ↦ A x`` for a loop that applies A many times (the ``csr``
-        backend of :func:`repro.core.make_matvec`): a vector-valued
-        operator's blocks and their row sums are laid out from ``vals``
-        once, here, rather than in every apply."""
+        """``x ↦ A x`` for a loop that applies A many times (a Krylov
+        solve): a vector-valued operator's blocks and their row sums are
+        laid out from ``vals`` once, here, rather than in every apply."""
         if self.block == 1:
             return self.matvec
         layout = _row_layout(self)
@@ -603,66 +600,3 @@ class BatchedCSR:
         out = jnp.zeros((self.batch,) + self.shape, dtype=self.vals.dtype)
         return out.at[:, _dev(self.row_of_nnz), _dev(self.indices)].set(self.vals)
 
-
-@jax.tree_util.register_pytree_node_class
-@dataclasses.dataclass
-class ELL:
-    """ELLPACK: fixed nnz-per-row padded format — the TPU-friendly layout
-    consumed by the Pallas SpMV kernel (bounded valence of FEM meshes)."""
-
-    vals: jnp.ndarray        # (n, L) traced, zero-padded
-    cols: np.ndarray         # (n, L) static, padded with row index (self-loop)
-    shape: tuple[int, int]
-
-    def tree_flatten(self):
-        return (self.vals,), (self.cols, self.shape)
-
-    @classmethod
-    def tree_unflatten(cls, aux, children):
-        (vals,) = children
-        return cls(vals, *aux)
-
-    def matvec(self, x: jnp.ndarray) -> jnp.ndarray:
-        return jnp.sum(self.vals * x[_dev(self.cols)], axis=1)
-
-
-# static ELL layouts keyed by pattern identity: the padded column table and
-# nnz→slot map depend only on (indptr, indices), so deriving them per call
-# (as the old per-call-site conversions did) redid an O(nnz) numpy sort-free
-# pass on every solve.  Strong references to the key arrays keep ids stable;
-# FIFO-bounded like the device mirrors.
-_ELL_LAYOUTS: dict[int, tuple] = {}
-_ELL_LAYOUTS_LIMIT = 128
-
-
-def ell_layout(csr: CSR) -> tuple[np.ndarray, np.ndarray, int]:
-    """Static ELL layout of a CSR pattern: ``(cols, flat_pos, L)`` — cached
-    per pattern identity so repeated conversions only pay the runtime value
-    scatter."""
-    hit = _ELL_LAYOUTS.get(id(csr.indices))
-    if hit is not None:
-        return hit[1]
-    n = csr.shape[0]
-    counts = np.diff(csr.indptr)
-    L = int(counts.max()) if counts.size else 1
-    # int32 at staging time: the Pallas kernels index with int32, and casting
-    # here (once per pattern) removes the per-matvec convert from solve loops
-    cols = np.repeat(np.arange(n, dtype=np.int32)[:, None], L, axis=1)
-    slot = np.concatenate([np.arange(c) for c in counts]) if counts.size else np.array([], np.int64)
-    rows_of = np.asarray(csr.row_of_nnz)
-    cols[rows_of, slot] = np.asarray(csr.indices)
-    flat_pos = rows_of * L + slot
-    layout = (cols, flat_pos, L)
-    if isinstance(csr.indices, np.ndarray):
-        while len(_ELL_LAYOUTS) >= _ELL_LAYOUTS_LIMIT:
-            _ELL_LAYOUTS.pop(next(iter(_ELL_LAYOUTS)))
-        _ELL_LAYOUTS[id(csr.indices)] = (csr.indices, layout)
-    return layout
-
-
-def csr_to_ell(csr: CSR) -> ELL:
-    cols, flat_pos, L = ell_layout(csr)
-    n = csr.shape[0]
-    # runtime scatter of vals into the padded layout (static slot map)
-    vals = jnp.zeros((n * L,), dtype=csr.vals.dtype).at[_dev(flat_pos)].set(csr.vals)
-    return ELL(vals.reshape(n, L), cols, csr.shape)

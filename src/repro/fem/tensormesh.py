@@ -10,7 +10,6 @@ Problem classes own (mesh → space → assembler → condenser) and expose:
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +23,6 @@ from ..core import (
     FunctionSpace,
     GalerkinAssembler,
     SolverSpec,
-    make_matvec,
     make_preconditioner,
     matfree_operator,
     resolve_solver_spec,
@@ -50,10 +48,20 @@ class _SolveResult:
     converged: bool = True
 
 
+_BACKENDS = (None, "csr", "matfree", "matfree_sharded")
+
+
+def _check_backend(backend):
+    """``backend`` picks between the assembled CSR (``None`` or
+    ``"csr"``) and the matrix-free operator (``"matfree"``, or
+    ``"matfree_sharded"`` over every local device)."""
+    if backend not in _BACKENDS:
+        raise ValueError(f"unknown backend {backend!r}: expected one of "
+                         f"{_BACKENDS}")
+
+
 class _ProblemBase:
     method = "cg"
-    use_ell = True  # ELL matvec in the Krylov loop: 2.1× end-to-end (§Perf-FEM)
-    backend = None  # default matvec backend (None → "ell" per use_ell flag)
 
     def _spec(self, spec, tol, maxiter, where) -> SolverSpec:
         """One :class:`~repro.core.SolverSpec` per solve: ``spec=`` wins,
@@ -70,33 +78,27 @@ class _ProblemBase:
         ``assemble_batched`` / ``assemble_sharded`` entry points."""
         return self.asm.plan
 
-    def _default_backend(self) -> str:
-        if self.backend is not None:
-            return self.backend
-        return "ell" if self.use_ell else "csr"
-
-    def _solve_system(self, k, f, spec: SolverSpec, backend=None,
-                      return_info=False):
-        """Krylov solve on an assembled operator with the inner matvec from
-        the unified registry (:mod:`repro.core.matvec`) and the
-        preconditioner resolved from ``spec.precond`` via the registry.  A
-        ``maxiter`` exit is reported through
-        :func:`repro.telemetry.check_convergence` (warn/raise per policy)
-        and the ``converged`` flag on the result; ``return_info=True``
-        appends the raw :class:`~repro.core.solvers.SolveInfo`."""
-        be = backend or self._default_backend()
+    def _solve_system(self, k, f, spec: SolverSpec, return_info=False):
+        """Krylov solve on an assembled operator, applied by
+        :meth:`~repro.core.sparse.CSR.loop_matvec` (the CSR picks its row
+        layout from the platform and the pattern), with the preconditioner
+        resolved from ``spec.precond`` via the registry.  A ``maxiter``
+        exit is reported through :func:`repro.telemetry.check_convergence`
+        (warn/raise per policy) and the ``converged`` flag on the result;
+        ``return_info=True`` appends the raw
+        :class:`~repro.core.solvers.SolveInfo`."""
         # krylov: build the matvec and preconditioner, trace, compile and
         # dispatch the loop; wait: the host blocks on the loop's result
         with telemetry.span("tg.solve.krylov"):
-            matvec = make_matvec(k, be)
+            matvec = k.loop_matvec()
             u, info = _method(spec.method)(
                 matvec, f, m=make_preconditioner(k, spec.precond),
                 tol=spec.tol, atol=spec.atol, maxiter=spec.maxiter)
         where = f"{type(self).__name__}.solve"
         with telemetry.span("tg.solve.wait"):
             events.check_convergence(info, where=where)
-            events.record_solve(where, info, method=spec.method, backend=be,
-                                precond=spec.precond_name)
+            events.record_solve(where, info, method=spec.method,
+                                backend="csr", precond=spec.precond_name)
             rel = float(jnp.linalg.norm(matvec(u) - f) / jnp.linalg.norm(f))
         res = _SolveResult(u, int(info.iters), rel, bool(info.converged))
         return (res, info) if return_info else res
@@ -175,16 +177,17 @@ class PoissonProblem(_ProblemBase):
     def solve(self, rho=None, f=1.0, spec: SolverSpec | None = None,
               tol=None, maxiter=None, backend=None, return_info=False,
               condensed=False):
-        """Solve with a registry-selected matvec backend; ``"matfree"``
-        skips matrix assembly entirely (only the RHS vector is assembled)
-        and ``"matfree_sharded"`` additionally spans the solve over all
-        local devices.  Solver knobs come in as one
+        """Solve on the assembled CSR (``backend=None`` or ``"csr"``);
+        ``"matfree"`` skips matrix assembly entirely (only the RHS vector
+        is assembled) and ``"matfree_sharded"`` additionally spans the
+        solve over all local devices.  Solver knobs come in as one
         :class:`~repro.core.SolverSpec` (``spec=``; legacy ``tol=`` /
         ``maxiter=`` kwargs still work but are deprecated).
         ``condensed=True`` (matfree backends, degree ≥ 2) runs the Krylov
         iteration on the statically condensed interface system.
         ``return_info=True`` appends the raw
         :class:`~repro.core.solvers.SolveInfo`."""
+        _check_backend(backend)
         spec = self._spec(spec, tol, maxiter, "solve")
         if condensed and backend not in ("matfree", "matfree_sharded"):
             raise ValueError("condensed=True needs a matfree backend")
@@ -196,8 +199,7 @@ class PoissonProblem(_ProblemBase):
                     sharded=backend == "matfree_sharded",
                     condensed=condensed)
             k, load = self.assemble(rho, f)
-            return self._solve_system(k, load, spec, backend=backend,
-                                      return_info=return_info)
+            return self._solve_system(k, load, spec, return_info=return_info)
 
     # -- many-query batched data generation (SM B.1.4) ------------------------
     def solve_batch(self, f_batch: jnp.ndarray, rho=None, tol=1e-10, maxiter=2000):
@@ -263,6 +265,7 @@ class AdvectionDiffusionProblem(_ProblemBase):
     def solve(self, eps=1.0, beta=(1.0, 0.0), f=1.0, dirichlet_values=0.0,
               spec: SolverSpec | None = None, tol=None, maxiter=None,
               backend=None, return_info=False):
+        _check_backend(backend)
         spec = self._spec(spec, tol, maxiter, "solve")
         if backend in ("matfree", "matfree_sharded"):
             form = wf.diffusion(eps) + wf.advection(jnp.asarray(beta))
@@ -272,8 +275,7 @@ class AdvectionDiffusionProblem(_ProblemBase):
                                        return_info=return_info,
                                        sharded=backend == "matfree_sharded")
         k, load = self.assemble(eps, beta, f, dirichlet_values)
-        return self._solve_system(k, load, spec, backend=backend,
-                                  return_info=return_info)
+        return self._solve_system(k, load, spec, return_info=return_info)
 
 
 class ElasticityProblem(_ProblemBase):
@@ -303,6 +305,7 @@ class ElasticityProblem(_ProblemBase):
 
     def solve(self, body_force=None, spec: SolverSpec | None = None,
               tol=None, maxiter=None, backend=None, return_info=False):
+        _check_backend(backend)
         spec = self._spec(spec, tol, maxiter, "solve")
         with telemetry.span("tg.solve"):
             if backend in ("matfree", "matfree_sharded"):
@@ -316,8 +319,7 @@ class ElasticityProblem(_ProblemBase):
                     sharded=backend == "matfree_sharded",
                 )
             k, f = self.assemble(body_force)
-            return self._solve_system(k, f, spec, backend=backend,
-                                      return_info=return_info)
+            return self._solve_system(k, f, spec, return_info=return_info)
 
 
 class MixedBCPoisson(_ProblemBase):
@@ -367,12 +369,13 @@ class MixedBCPoisson(_ProblemBase):
               dirichlet_values=None, rho=None,
               spec: SolverSpec | None = None, tol=None, maxiter=None,
               backend=None, return_info=False):
+        _check_backend(backend)
         spec = self._spec(spec, tol, maxiter, "solve")
         if backend in ("matfree", "matfree_sharded"):
             raise NotImplementedError(
                 "MixedBCPoisson has Robin facet terms, which the matrix-free "
-                "apply does not support (volume terms only) — use an "
-                "assembled backend ('csr'/'ell'/'ell_pallas'/'ell_stream')"
+                "apply does not support (volume terms only) — use the "
+                "assembled backend ('csr')"
             )
         # mixed volume + boundary form → ONE CSR from one fused assembly
         # (Robin facet terms inject into the volume pattern), and one fused
@@ -402,5 +405,4 @@ class MixedBCPoisson(_ProblemBase):
             d_dofs = self.bc.bc_dofs
             bvals = jnp.asarray(dirichlet_values(self.space.dof_points[d_dofs]))
         kc, fc = self.bc.apply(k, load, bvals)
-        return self._solve_system(kc, fc, spec, backend=backend,
-                                  return_info=return_info)
+        return self._solve_system(kc, fc, spec, return_info=return_info)

@@ -1,8 +1,1 @@
-from .ops import (  # noqa: F401
-    autotune_ell_stream,
-    batch_map_stiffness,
-    ell_matvec,
-    ell_matvec_stream,
-    ell_residual,
-    ell_residual_stream,
-)
+from .ops import batch_map_stiffness  # noqa: F401

@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-__all__ = ["local_stiffness_p1_ref", "spmv_ell_ref", "galerkin_residual_ell_ref"]
+__all__ = ["local_stiffness_p1_ref"]
 
 
 def local_stiffness_p1_ref(coords: jnp.ndarray, rho: jnp.ndarray) -> jnp.ndarray:
@@ -24,12 +24,3 @@ def local_stiffness_p1_ref(coords: jnp.ndarray, rho: jnp.ndarray) -> jnp.ndarray
     scale = w * jnp.abs(det) * rho                       # (E,)
     return jnp.einsum("e,eai,ebi->eab", scale, g, g)
 
-
-def spmv_ell_ref(vals: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
-    """ELL SpMV: vals/cols (N, L), x (N,) → (N,)."""
-    return jnp.sum(vals * x[cols], axis=1)
-
-
-def galerkin_residual_ell_ref(vals, cols, u, f) -> jnp.ndarray:
-    """Fused TensorPILS residual r = K u − f on the ELL operator."""
-    return spmv_ell_ref(vals, cols, u) - f

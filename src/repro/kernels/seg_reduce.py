@@ -4,16 +4,16 @@ Variable-length segment reduction is TPU-hostile; FEM gives us a bound —
 each global nnz entry receives at most ``L`` local contributions (L =
 max element valence of an edge/vertex pair).  At routing build time the
 sorted segment layout is repacked into a padded ``(nnz, L)`` index table
-(pad slots point at a zeroed sentinel), turning the Reduce into the same
-lane-parallel gather+sum shape as the ELL SpMV kernel:
+(pad slots point at a zeroed sentinel), turning the Reduce into a
+lane-parallel gather+sum:
 
     vals[n] = Σ_l  vec(K_local ‖ 0)[ idx[n, l] ]
 
 Grid: (ceil(nnz / BN),); blocks (BN, L) indices + broadcast source vector.
 
 ``interpret`` resolves from the platform (:mod:`repro.kernels.interpret`).
-The kernel does not compile for a TPU today: its 1-D ``jnp.take`` gather is
-the one Mosaic rejects in the ELL SpMV (``Only 2D gather is supported``).
+The kernel does not compile for a TPU today: Mosaic rejects its 1-D
+``jnp.take`` gather (``Only 2D gather is supported``).
 """
 
 from __future__ import annotations

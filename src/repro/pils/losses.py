@@ -17,8 +17,6 @@ differ only in the objective:
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,7 +27,6 @@ from ..core import (
     assemble_batched,
     assemble_rhs,
     assemble_rhs_batched,
-    make_residual,
     matfree_family,
     matfree_operator,
     SolverSpec,
@@ -127,15 +124,17 @@ class GalerkinResidualLoss:
     The network may predict U directly (``coeffs_from(params)``) or via a
     pointwise backbone evaluated at DoF coordinates.
 
-    ``backend`` picks the residual inner op from the unified registry
-    (:mod:`repro.core.matvec`): ``"csr"`` (default), ``"ell"``,
-    ``"ell_pallas"`` (the fused ``r = K·u − f`` Pallas kernel — one pass, no
-    extra HBM round-trip), or ``"matfree"`` (K is never assembled; the
-    residual applies the weak form element-locally).
+    ``backend`` picks the operator: ``"csr"`` (default, the assembled K)
+    or ``"matfree"`` (K is never assembled; the residual applies the weak
+    form element-locally).
     """
 
     def __init__(self, asm: GalerkinAssembler, bc: DirichletCondenser,
                  rho=None, f=1.0, backend: str = "csr"):
+        if backend not in ("csr", "matfree"):
+            raise ValueError(
+                f"unknown backend {backend!r}: expected 'csr' or 'matfree'"
+            )
         load = asm.assemble_rhs(wf.source(f))
         if backend == "matfree":
             self.k = matfree_operator(asm.plan, wf.diffusion(rho)).condensed(bc)
@@ -144,13 +143,12 @@ class GalerkinResidualLoss:
         else:
             k = asm.assemble(wf.diffusion(rho))
             self.k, self.f = bc.apply(k, load)
-        self._residual = make_residual(self.k, backend)
         self.backend = backend
         self.bc = bc
         self.dof_points = jnp.asarray(asm.space.dof_points)
 
     def residual(self, u: jnp.ndarray) -> jnp.ndarray:
-        return self._residual(u, self.f)
+        return self.k.matvec(u) - self.f
 
     def __call__(self, u: jnp.ndarray) -> jnp.ndarray:
         r = self.residual(u)

@@ -4,9 +4,7 @@ operators.
 Module map
 ----------
 * :mod:`~repro.transient.stepping` — shared rollout machinery:
-  checkpoint-segmented ``lax.scan``, same-pattern CSR combination.  The
-  inner-matvec backend dispatch lives in the unified registry
-  :mod:`repro.core.matvec` (CSR / ELL / Pallas-ELL / matrix-free).
+  checkpoint-segmented ``lax.scan``, same-pattern CSR combination.
 * :mod:`~repro.transient.theta` — :class:`ThetaIntegrator`: the θ-method
   for parabolic problems (θ=1 backward Euler, θ=½ Crank–Nicolson), with
   per-step time-varying loads and Dirichlet data inside the scan.
@@ -39,7 +37,6 @@ from __future__ import annotations
 
 import jax
 
-from ..core.matvec import make_matvec  # unified registry (compat re-export)
 from .newmark import NewmarkIntegrator
 from .newton import NewtonKrylovIntegrator
 from .stepping import axpy_csr, segmented_scan
@@ -56,7 +53,6 @@ __all__ = [
     "batched_theta_rollout",
     "segmented_scan",
     "axpy_csr",
-    "make_matvec",
 ]
 
 

@@ -29,7 +29,6 @@ import dataclasses
 import jax.numpy as jnp
 
 from ..core.boundary import DirichletCondenser
-from ..core.matvec import make_matvec
 from ..core.solvers import SolverSpec, resolve_solver_spec, sparse_solve
 from ..core.sparse import CSR
 from ..telemetry import events
@@ -50,11 +49,6 @@ class NewmarkIntegrator:
     solver: str | None = None       # deprecated → spec.method
     tol: float | None = None        # deprecated → spec.tol (and atol)
     maxiter: int | None = None      # deprecated → spec.maxiter
-    # inner K·u matvec backend (unified registry, repro.core.matvec): the
-    # predictor RHS runs two stiffness applies per step — "ell"/"ell_pallas"
-    # switch them to the padded layout / Pallas kernel (the solve itself
-    # stays on the differentiable sparse_solve path)
-    backend: str = "csr"
 
     def __post_init__(self):
         # M + βΔt²K is SPD → CG default
@@ -68,7 +62,6 @@ class NewmarkIntegrator:
         self.lhs_full = axpy_csr(
             1.0, self.mass, self.beta * self.dt**2, self.stiff
         )
-        self._stiff_mv = make_matvec(self.stiff, self.backend)
         if self.bc is not None:
             self.lhs = self.bc.apply_matrix_only(self.lhs_full)
             self.mass_c = self.bc.apply_matrix_only(self.mass)
@@ -81,7 +74,7 @@ class NewmarkIntegrator:
 
     def initial_acceleration(self, u0, load0=None):
         """Consistent a₀ from M a₀ = F(0) − K u₀ (condensed)."""
-        r = -self._stiff_mv(u0)
+        r = -self.stiff.matvec(u0)
         if load0 is not None:
             r = r + load0
         return sparse_solve(self.mass_c, self._mask(r), self.spec)
@@ -90,7 +83,7 @@ class NewmarkIntegrator:
         dt, beta, gamma = self.dt, self.beta, self.gamma
         u_star = u + dt * v + 0.5 * dt**2 * (1 - 2 * beta) * a
         v_star = v + dt * (1 - gamma) * a
-        rhs = -self._stiff_mv(u_star)
+        rhs = -self.stiff.matvec(u_star)
         if load is not None:
             rhs = rhs + load
         out = sparse_solve(self.lhs, self._mask(rhs), self.spec,
@@ -144,7 +137,7 @@ class NewmarkIntegrator:
             u_traj, v_traj, info = ys
             events.check_convergence(info, where="newmark.rollout")
             events.record_solve("newmark.rollout", info,
-                                method=self.spec.method, backend=self.backend,
+                                method=self.spec.method, backend="csr",
                                 precond=self.spec.precond_name)
             out = (u_traj, v_traj) if return_velocity else u_traj
             return out, info

@@ -7,25 +7,17 @@
 * :func:`axpy_csr` — combine two same-pattern CSR operators into a third
   (``α·A + β·B``) without touching the static pattern; this is how the
   θ-method / Newmark effective operators are formed once, outside the loop.
-
-The inner-matvec backend dispatch that used to live here
-(``make_matvec`` / ``MATVEC_BACKENDS``) moved to the unified registry in
-:mod:`repro.core.matvec` — every solver, integrator and loss now consumes
-one dispatch point, and the ELL layout derivation is cached per sparsity
-pattern instead of re-derived per call site.  The old names still resolve
-from this module but emit a ``DeprecationWarning``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import warnings
 
 import jax
 
 from ..core.sparse import CSR
 
-__all__ = ["segmented_scan", "axpy_csr", "make_matvec", "MATVEC_BACKENDS"]
+__all__ = ["segmented_scan", "axpy_csr"]
 
 
 def segmented_scan(step, init, xs, length: int, checkpoint_every: int | None = None):
@@ -65,17 +57,3 @@ def axpy_csr(alpha, a: CSR, beta, b: CSR) -> CSR:
     assert a.indices.shape == b.indices.shape, "CSR patterns must match"
     return dataclasses.replace(a, vals=alpha * a.vals + beta * b.vals)
 
-
-def __getattr__(name):
-    # deprecated backend-dispatch names, forwarded to the unified registry
-    if name in ("make_matvec", "MATVEC_BACKENDS"):
-        from ..core import matvec as _registry
-
-        warnings.warn(
-            f"repro.transient.stepping.{name} is deprecated; use "
-            f"repro.core.matvec.{name} (the unified matvec-backend registry)",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        return getattr(_registry, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

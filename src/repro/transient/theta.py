@@ -25,15 +25,11 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 
 from ..core.boundary import DirichletCondenser
-from ..core.matvec import make_matvec
 from ..core.solvers import (
     SolverSpec,
-    _method,
-    make_preconditioner,
     matfree_solve,
     resolve_solver_spec,
     sparse_solve,
@@ -47,6 +43,8 @@ __all__ = ["ThetaIntegrator", "BACKWARD_EULER", "CRANK_NICOLSON"]
 BACKWARD_EULER = 1.0
 CRANK_NICOLSON = 0.5
 
+_BACKENDS = ("csr", "matfree", "matfree_sharded")
+
 
 @dataclasses.dataclass
 class ThetaIntegrator:
@@ -58,13 +56,10 @@ class ThetaIntegrator:
     (``assemble(mass(c) + θΔt·form)``) sharing one jit signature; the
     static sparsity pattern is reused across traces.
 
-    ``backend`` selects the inner-loop apply from the unified registry
-    (:mod:`repro.core.matvec`): ``"csr"`` (default) keeps the rollout
-    differentiable via ``sparse_solve``; ``"ell"`` / ``"ell_pallas"`` run
-    the inner matvecs on the ELLPACK layout with a plain CG loop — the fast
-    inference path (``lax.while_loop`` is forward-only); ``"matfree"``
-    (build via :meth:`from_form` with ``backend="matfree"``) steps on
-    matrix-free operators through the differentiable
+    ``backend`` picks the operators' kind: ``"csr"`` (default) steps on
+    the assembled operators through the differentiable ``sparse_solve``;
+    ``"matfree"`` (build via :meth:`from_form` with ``backend="matfree"``)
+    steps on matrix-free operators through the differentiable
     :func:`~repro.core.solvers.matfree_solve` — no CSR values are ever
     materialized for the rollout; ``"matfree_sharded"`` additionally
     partitions every apply over the local device mesh
@@ -88,6 +83,9 @@ class ThetaIntegrator:
     rhs_op: CSR | None = None
 
     def __post_init__(self):
+        if self.backend not in _BACKENDS:
+            raise ValueError(f"unknown backend {self.backend!r}: expected "
+                             f"one of {_BACKENDS}")
         # M + θΔtK is SPD for θ ≥ 0 → CG default; legacy solver/tol/maxiter
         # fields fold into the spec (DeprecationWarning) and stay readable
         # as mirrors afterwards
@@ -119,10 +117,6 @@ class ThetaIntegrator:
             self.lhs = self.bc.apply_matrix_only(self.lhs_full)
         else:  # matrix-free operator: condensation as an apply wrapper
             self.lhs = self.lhs_full.condensed(self.bc)
-        if self.backend not in ("csr", "matfree", "matfree_sharded"):
-            self._lhs_mv = make_matvec(self.lhs, self.backend)
-            self._rhs_mv = make_matvec(self.rhs_op, self.backend)
-            self._precond = make_preconditioner(self.lhs, self.spec.precond)
 
     @classmethod
     def from_form(cls, asm, form, dt, *, theta: float = BACKWARD_EULER,
@@ -176,10 +170,7 @@ class ThetaIntegrator:
         ``return_info=True`` additionally returns the step's
         :class:`~repro.core.solvers.SolveInfo` as a non-differentiated
         auxiliary output (stop-gradient leaves)."""
-        if self.backend in ("csr", "matfree", "matfree_sharded"):
-            b = self.rhs_op.matvec(u)
-        else:
-            b = self._rhs_mv(u)
+        b = self.rhs_op.matvec(u)
         if load is not None:
             b = b + self.dt * load
         if self.bc is None:
@@ -194,17 +185,9 @@ class ThetaIntegrator:
         if self.backend == "csr":
             return sparse_solve(self.lhs, b, self.spec,
                                 return_info=return_info)
-        if self.backend in ("matfree", "matfree_sharded"):
-            # differentiable adjoint solve on the matrix-free operator
-            # (sharded: the same solve with every apply spanning the mesh)
-            return matfree_solve(self.lhs, b, self.spec,
-                                 return_info=return_info)
-        u_new, info = _method(self.spec.method)(
-            self._lhs_mv, b, x0=u, tol=self.spec.tol, atol=self.spec.atol,
-            maxiter=self.spec.maxiter, m=self._precond)
-        if return_info:
-            return u_new, jax.lax.stop_gradient(info)
-        return u_new
+        # differentiable adjoint solve on the matrix-free operator
+        # (sharded: the same solve with every apply spanning the mesh)
+        return matfree_solve(self.lhs, b, self.spec, return_info=return_info)
 
     # -- rollout ---------------------------------------------------------------
     def rollout(self, u0, n_steps: int, *, loads=None, bc_values=None,

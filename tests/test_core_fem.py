@@ -17,19 +17,21 @@ from repro.core import (
     GalerkinAssembler,
     cg,
     bicgstab,
-    csr_to_ell,
     disk_tri,
     hollow_cube_tet,
     jacobi_preconditioner,
     l_shape_tri,
+    rectangle_quad,
     rectangle_tri,
     sparse_solve,
     unit_cube_tet,
     unit_square_tri,
 )
+from repro import telemetry
+from repro.core import sparse
 from repro.core import weakform as wf
 from repro.core.elements import get_element
-from repro.core.mesh import element_for_mesh
+from repro.core.mesh import Mesh, element_for_mesh
 from repro.core.quadrature import triangle_rule, tetrahedron_rule, quad_rule
 
 
@@ -161,25 +163,66 @@ def test_table_segment_sum_matches_segment_sum(batch):
     np.testing.assert_allclose(got.reshape(-1, n_seg), want, atol=1e-13)
 
 
-@pytest.mark.parametrize("mesh,diagonals", [
-    (lambda: unit_square_tri(9), True),    # banded: 9 column offsets
-    (lambda: disk_tri(4), False),          # unstructured numbering
-])
-def test_csr_matvec_row_layouts_match_dense(monkeypatch, mesh, diagonals):
-    """On a TPU CSR.matvec reads rows as shifted diagonals on a banded
-    pattern and through a column table otherwise; both equal the dense
-    product, for one vector and for a batch of operators."""
-    from repro.core.sparse import _row_layout
+def _renumbered(mesh, seed=0):
+    """The mesh with its vertices numbered at random."""
+    perm = np.random.default_rng(seed).permutation(mesh.num_vertices)
+    inv = np.empty_like(perm)
+    inv[perm] = np.arange(perm.size)
+    return Mesh(mesh.points[perm], inv[mesh.cells], mesh.cell_type)
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+# the row layout each pattern takes on a TPU: diagonals where a square
+# pattern has at most 4x as many distinct column offsets as its widest row
+# has entries, the column table otherwise
+ROW_LAYOUT_PATTERNS = {
+    "tri1": (lambda: unit_square_tri(1), 1, "diagonals"),       # 7 offsets, K 4
+    "tri2": (lambda: unit_square_tri(2), 1, "diagonals"),       # 9, K 9
+    "tri9": (lambda: unit_square_tri(9), 1, "diagonals"),       # 9, K 9
+    "tri4_p2": (lambda: unit_square_tri(4), 2, "columns"),      # 107, K 25
+    "disk4": (lambda: disk_tri(4), 1, "columns"),               # 51, K 8
+    "tri9_renumbered": (lambda: _renumbered(unit_square_tri(9)), 1,
+                        "columns"),                             # 151, K 9
+    "tet3": (lambda: unit_cube_tet(3), 1, "diagonals"),         # 15, K 15
+    "quad5x4": (lambda: rectangle_quad(5, 4, 1.0, 1.0), 1, "diagonals"),
+}
+
+
+def _matvec_kind(k, x):
+    """``k.matvec(x)`` and the row layout it read: the ``matvec_gathers``
+    gauge's kind, or ``"segment_sum"`` where it set none."""
+    telemetry.enable()
+    try:
+        y = k.matvec(x)
+        gauges = telemetry.snapshot()["gauges"]
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    kinds = [g[len("matvec_gathers{kind="):-1] for g in gauges
+             if g.startswith("matvec_gathers{")]
+    assert len(kinds) <= 1
+    return y, (kinds[0] if kinds else "segment_sum")
+
+
+@pytest.mark.parametrize("which", sorted(ROW_LAYOUT_PATTERNS))
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_csr_matvec_row_layouts_match_dense(monkeypatch, platform, which):
+    """On a TPU CSR.matvec reads rows as shifted diagonals on a banded
+    pattern and through a column table otherwise; elsewhere it is a
+    sorted segment-sum.  Each equals the dense product, for one vector
+    and for a batch of operators."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    mesh, degree, kind = ROW_LAYOUT_PATTERNS[which]
     m = mesh()
-    space = FunctionSpace(m, element_for_mesh(m))
+    space = FunctionSpace(m, element_for_mesh(m, degree))
     k = GalerkinAssembler(space).assemble(wf.diffusion() + 0.5 * wf.mass())
-    assert (_row_layout(k).offsets is not None) == diagonals
     rng = np.random.default_rng(5)
     x = rng.standard_normal(k.shape[0])
-    np.testing.assert_allclose(np.asarray(k.matvec(jnp.asarray(x))),
-                               np.asarray(k.to_dense()) @ x, atol=1e-12)
+    y, got_kind = _matvec_kind(k, jnp.asarray(x))
+    assert got_kind == (kind if platform == "tpu" else "segment_sum")
+    np.testing.assert_allclose(np.asarray(y), np.asarray(k.to_dense()) @ x,
+                               atol=1e-12)
+    np.testing.assert_array_equal(np.asarray(k.loop_matvec()(jnp.asarray(x))),
+                                  np.asarray(y))
     vals = jnp.stack([k.vals, 2.0 * k.vals])
     xb = jnp.asarray(rng.standard_normal((2, k.shape[0])))
     got = jax.vmap(lambda v, xi: dataclasses.replace(k, vals=v).matvec(xi))(
@@ -187,6 +230,134 @@ def test_csr_matvec_row_layouts_match_dense(monkeypatch, mesh, diagonals):
     want = np.stack([np.asarray(k.to_dense()) @ np.asarray(xb[0]),
                      2.0 * np.asarray(k.to_dense()) @ np.asarray(xb[1])])
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-12)
+
+
+def _csr_from_pairs(rows, cols, shape, block=1, seed=0):
+    """A :class:`CSR` with random values on the given (row, col) pattern;
+    with ``block`` b each pair is a vertex pair, stored as a full b x b
+    block of DoF entries (DoF ``b·i + a`` is component a of vertex i)."""
+    pattern = sp.csr_matrix((np.ones(len(rows)), (rows, cols)), shape=shape)
+    a = sp.kron(pattern, np.ones((block, block)), format="csr")
+    a.sort_indices()
+    a.data = np.random.default_rng(seed).standard_normal(a.nnz)
+    return CSR(jnp.asarray(a.data), a.indptr, a.indices,
+               np.repeat(np.arange(a.shape[0]), np.diff(a.indptr)), a.shape,
+               block=block)
+
+
+def _offset_rows(n, offsets):
+    """Row i holds its diagonal and, where it stays in range, column
+    ``i + offsets[i % len(offsets)]``."""
+    rows, cols = [], []
+    for i in range(n):
+        for o in (0, offsets[i % len(offsets)]):
+            if 0 <= i + o < n:
+                rows.append(i)
+                cols.append(i + o)
+    return rows, cols
+
+
+def _threshold_pattern(rule, side):
+    """``(csr, kind)``: a pattern built by hand on one side of one of the
+    row-layout rules, and the layout it takes on a TPU (``side`` "in":
+    at the rule's limit, "out": one step past it)."""
+    past = side == "out"
+    n = 200
+    if rule == "diagonals":
+        # the diagonal plus one entry a row: K = 2, so 8 distinct offsets
+        # (4K) take diagonals and 9 the column table
+        rows, cols = _offset_rows(40, list(range(1, 8 + past)))
+        return (_csr_from_pairs(rows, cols, (40, 40)),
+                "columns" if past else "diagonals")
+    if rule == "row_share":
+        # offset 50 on 2 of 200 vertex rows (1%) takes a band slot; on one
+        # row it is left to the remainder
+        rows, cols = _offset_rows(n, [-1, 1])
+        extra = [0] if past else [0, 1]
+        rows += extra
+        cols += [i + 50 for i in extra]
+        return _csr_from_pairs(rows, cols, (n, n), block=2), (
+            "vertex_bands_remainder" if past else "vertex_bands")
+    if rule == "slots":
+        # K = 2 blocks a row: 5 offsets fill the 2.5 K slots, 6 exceed them
+        rows, cols = _offset_rows(n, list(range(1, 5 + past)))
+        return _csr_from_pairs(rows, cols, (n, n), block=2), (
+            "vertex_blocks" if past else "vertex_bands")
+    # remainder: r pairs on offsets no other row holds, of 190 + r pairs;
+    # 10 are 5% of them, 11 more
+    n = 190
+    rows, cols = list(range(n)), list(range(n))
+    for j in range(10 + past):
+        rows.append(j)
+        cols.append(100 + 2 * j)
+    return _csr_from_pairs(rows, cols, (n, n), block=2), (
+        "vertex_blocks" if past else "vertex_bands_remainder")
+
+
+@pytest.mark.parametrize("side", ["in", "out"])
+@pytest.mark.parametrize("rule", ["diagonals", "row_share", "slots",
+                                  "remainder"])
+def test_row_layout_thresholds(monkeypatch, rule, side):
+    """Each rule that picks a row layout, on both sides of its limit: the
+    layout taken, and the dense product through it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    k, kind = _threshold_pattern(rule, side)
+    layout = sparse._row_layout(k)
+    if kind in ("diagonals", "columns"):
+        assert isinstance(layout, sparse.RowLayout)
+        assert (layout.offsets is not None) == (kind == "diagonals")
+    elif kind == "vertex_blocks":
+        assert isinstance(layout, sparse.BlockLayout)
+    else:
+        assert isinstance(layout, sparse.BandLayout)
+        assert (layout.rows is not None) == kind.endswith("_remainder")
+    if rule == "row_share":
+        assert (50 in layout.offsets) == (side == "in")
+    x = np.random.default_rng(1).standard_normal(k.shape[0])
+    want = np.asarray(k.to_dense()) @ x
+    for got in (k.matvec(jnp.asarray(x)), k.loop_matvec()(jnp.asarray(x))):
+        np.testing.assert_allclose(np.asarray(got), want, atol=1e-12)
+
+
+def _edge_pattern(which):
+    """``(rows, cols, shape)``: a tridiagonal 12 x 12 pattern whose row 5
+    is empty or holds one entry, off the diagonal; its first 7 columns
+    (rows of 0-3 entries, so the column table pads); or a 1 x 1 one."""
+    if which == "one_by_one":
+        return [0], [0], (1, 1)
+    pairs = [(i, j) for i in range(12) for j in (i - 1, i, i + 1)
+             if 0 <= j < 12]
+    shape = (12, 12)
+    if which == "rectangular":
+        pairs, shape = [(i, j) for i, j in pairs if j < 7], (12, 7)
+    else:
+        pairs = [(i, j) for i, j in pairs if i != 5]
+        if which == "one_entry_row":
+            pairs.append((5, 9))
+    rows, cols = zip(*pairs)
+    return list(rows), list(cols), shape
+
+
+@pytest.mark.parametrize("which", ["empty_row", "one_entry_row",
+                                   "rectangular", "one_by_one"])
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_csr_matvec_edge_patterns(monkeypatch, platform, which):
+    """A row with no entries, a row with one, a rectangular operator (its
+    column table's padding reads column 0) and a 1 x 1 operator: the
+    apply equals the dense product on both platforms."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    rows, cols, shape = _edge_pattern(which)
+    k = _csr_from_pairs(rows, cols, shape, seed=3)
+    x = np.random.default_rng(4).standard_normal(shape[1]) + 1.0
+    y, kind = _matvec_kind(k, jnp.asarray(x))
+    if platform == "cpu":
+        assert kind == "segment_sum"
+    else:
+        assert kind == ("columns" if which == "rectangular" else "diagonals")
+    want = np.asarray(k.to_dense()) @ x
+    np.testing.assert_allclose(np.asarray(y), want, atol=1e-12)
+    np.testing.assert_allclose(np.asarray(k.loop_matvec()(jnp.asarray(x))),
+                               want, atol=1e-12)
 
 
 def test_assembly_deterministic():
@@ -334,15 +505,6 @@ def test_cg_true_residual_meets_tolerance_in_float32(n):
                           - b) / np.linalg.norm(b)
     assert bool(info.converged)
     assert true <= 1e-4
-
-
-def test_ell_spmv_matches_csr():
-    k, f, _ = _poisson_system(7)
-    ell = csr_to_ell(k)
-    x = jnp.asarray(np.random.default_rng(0).normal(size=f.shape))
-    np.testing.assert_allclose(
-        np.asarray(ell.matvec(x)), np.asarray(k.matvec(x)), atol=1e-12
-    )
 
 
 def test_csr_matmat_batched_rhs():

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import (
+    CSR,
     DirichletCondenser,
     FunctionSpace,
     GalerkinAssembler,
@@ -14,7 +15,12 @@ from repro.core import (
     unit_square_tri,
 )
 from repro.core.mesh import element_for_mesh
-from repro.fem import ElasticityProblem, MixedBCPoisson, PoissonProblem
+from repro.fem import (
+    AdvectionDiffusionProblem,
+    ElasticityProblem,
+    MixedBCPoisson,
+    PoissonProblem,
+)
 from repro.core import hollow_cube_tet, unit_cube_tet
 from repro.pils import (
     GalerkinResidualLoss,
@@ -43,6 +49,41 @@ def test_elasticity3d_hollow_cube():
     res = ElasticityProblem(hollow_cube_tet(6)).solve()
     assert res.residual < 1e-8
     assert float(jnp.abs(res.u).max()) > 0
+
+
+PROBLEMS = {
+    "poisson": lambda: (PoissonProblem(unit_square_tri(16)), {}),
+    "advection_diffusion": lambda: (
+        AdvectionDiffusionProblem(unit_square_tri(8)), {}),
+    "elasticity": lambda: (ElasticityProblem(unit_square_tri(6)), {}),
+    "mixed_bc": lambda: (MixedBCPoisson(
+        unit_square_tri(8),
+        dirichlet_pred=lambda c: c[:, 0] < 0.5,
+        neumann_pred=lambda c: c[:, 1] < 0.5,
+        robin_pred=lambda c: c[:, 1] >= 0.5), {"f": 1.0}),
+}
+
+
+@pytest.mark.parametrize("which", sorted(PROBLEMS))
+def test_default_solve_is_the_csr_loop_apply(monkeypatch, which):
+    """``.solve()`` with no backend applies the assembled operator through
+    ``CSR.loop_matvec``, once a solve, and returns what ``backend="csr"``
+    returns, bit for bit."""
+    calls = []
+    loop_matvec = CSR.loop_matvec
+
+    def spy(self):
+        calls.append(self.shape)
+        return loop_matvec(self)
+
+    monkeypatch.setattr(CSR, "loop_matvec", spy)
+    prob, kw = PROBLEMS[which]()
+    default = prob.solve(**kw)
+    assert len(calls) == 1
+    csr = prob.solve(backend="csr", **kw)
+    assert len(calls) == 2
+    assert default.converged and default.residual < 1e-9
+    np.testing.assert_array_equal(np.asarray(default.u), np.asarray(csr.u))
 
 
 def test_batched_rhs_matches_individual():

@@ -1,14 +1,12 @@
-"""Matrix-free operator subsystem + unified matvec-backend registry.
+"""Matrix-free operator subsystem, and the assembled and matrix-free
+applies its consumers choose between.
 
-Covers the PR-4 acceptance criteria: apply parity vs the assembled CSR
-matvec (≤1e-12) across ALL element types, grad-vs-FD and grad-vs-adjoint
-through matrix-free solves, the zero-retrace property on coefficient value
-updates, the condensed (Dirichlet) apply, the registry dispatch incl. the
-fused Pallas residual, and the deprecation shims of the old
-``transient.stepping`` dispatch names.
+Covers apply parity vs the assembled CSR matvec (≤1e-12) across ALL
+element types, grad-vs-FD and grad-vs-adjoint through matrix-free solves,
+the zero-retrace property on coefficient value updates, the condensed
+(Dirichlet) apply, and the ``backend`` choice of the problems, integrators
+and residual losses.
 """
-
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -20,12 +18,9 @@ from repro.core import (
     DirichletCondenser,
     FunctionSpace,
     GalerkinAssembler,
-    MATVEC_BACKENDS,
     assemble,
     assemble_rhs,
     build_plan,
-    make_matvec,
-    make_residual,
     matfree_operator,
     SolverSpec,
     matfree_solve,
@@ -234,7 +229,7 @@ def test_poisson_problem_matfree_backend():
 
 
 # ---------------------------------------------------------------------------
-# the unified backend registry
+# the assembled and the matrix-free apply
 # ---------------------------------------------------------------------------
 
 def _small_system():
@@ -246,75 +241,56 @@ def _small_system():
 
 
 def test_registry_backends_agree():
+    """The CSR's apply, its loop apply and the matrix-free apply agree."""
     plan, k, x = _small_system()
-    assert set(MATVEC_BACKENDS) >= {"csr", "ell", "ell_pallas", "matfree"}
-    y_ref = np.asarray(k.matvec(x))
-    for backend in ("csr", "ell", "ell_pallas"):
-        mv = make_matvec(k, backend)
-        np.testing.assert_allclose(np.asarray(mv(x)), y_ref, atol=1e-12)
+    y_ref = np.asarray(k.to_dense()) @ np.asarray(x)
     op = matfree_operator(plan, wf.diffusion(1.5))
-    np.testing.assert_allclose(
-        np.asarray(make_matvec(op, "matfree")(x)), y_ref, atol=1e-12
-    )
+    for mv in (k.matvec, k.loop_matvec(), op.matvec):
+        np.testing.assert_allclose(np.asarray(mv(x)), y_ref, atol=1e-12)
 
 
 def test_registry_residuals_agree():
-    plan, k, x = _small_system()
-    f = jnp.asarray(RNG.standard_normal(x.shape[0]))
-    r_ref = np.asarray(k.matvec(x) - f)
-    for backend in ("csr", "ell", "ell_pallas"):
-        r = make_residual(k, backend)(x, f)
+    """The residual loss's ``K u − f`` on the assembled and on the
+    matrix-free operator, against the dense condensed system."""
+    from repro.pils.losses import GalerkinResidualLoss
+
+    space = CASES["P1_tri"]()
+    asm = GalerkinAssembler(space)
+    bc = DirichletCondenser(asm, space.boundary_dofs())
+    u = jnp.asarray(RNG.standard_normal(space.num_dofs))
+    kc, fc = bc.apply(asm.assemble(wf.diffusion()),
+                      asm.assemble_rhs(wf.source(1.0)))
+    r_ref = np.asarray(kc.to_dense()) @ np.asarray(u) - np.asarray(fc)
+    for backend in ("csr", "matfree"):
+        r = GalerkinResidualLoss(asm, bc, backend=backend).residual(u)
         np.testing.assert_allclose(np.asarray(r), r_ref, atol=1e-12)
-    op = matfree_operator(plan, wf.diffusion(1.5))
-    np.testing.assert_allclose(
-        np.asarray(make_residual(op, "matfree")(x, f)), r_ref, atol=1e-12
-    )
 
 
-def test_registry_errors():
-    plan, k, x = _small_system()
-    op = matfree_operator(plan, wf.diffusion(1.5))
-    with pytest.raises(ValueError, match="unknown matvec backend"):
-        make_matvec(k, "nope")
-    with pytest.raises(TypeError, match="matrix-free operator"):
-        make_matvec(k, "matfree")
-    with pytest.raises(TypeError, match="assembled CSR"):
-        make_matvec(op, "ell")
+@pytest.mark.parametrize("backend", ["nope", "ell", "ell_pallas",
+                                     "ell_stream"])
+def test_registry_errors(backend):
+    """A backend name outside the accepted ones is refused, naming them,
+    by the problems, the θ-integrator and the residual loss."""
+    from repro.fem.tensormesh import PoissonProblem
+    from repro.pils.losses import GalerkinResidualLoss
+    from repro.transient import ThetaIntegrator
 
-
-def test_register_custom_backend():
-    from repro.core.matvec import _BACKENDS, matvec_backends, register_matvec_backend
-
-    _, k, x = _small_system()
-    register_matvec_backend(
-        "dense_test", lambda op: op.to_dense().__matmul__, overwrite=True
-    )
-    try:
-        np.testing.assert_allclose(
-            np.asarray(make_matvec(k, "dense_test")(x)),
-            np.asarray(k.matvec(x)), atol=1e-12,
-        )
-        # the live set sees the registration; the built-in constant does not
-        assert "dense_test" in matvec_backends()
-        assert "dense_test" not in MATVEC_BACKENDS
-        with pytest.raises(ValueError, match="already registered"):
-            register_matvec_backend("dense_test", lambda op: op.matvec)
-    finally:
-        _BACKENDS.pop("dense_test", None)
-
-
-def test_ell_layout_cached_per_pattern():
-    from repro.core.sparse import _ELL_LAYOUTS, csr_to_ell
-
-    _, k, x = _small_system()
-    ell1 = csr_to_ell(k)
-    assert id(k.indices) in _ELL_LAYOUTS
-    ell2 = csr_to_ell(k)
-    assert ell1.cols is ell2.cols  # layout derived once, not per call site
+    space = CASES["P1_tri"]()
+    asm = GalerkinAssembler(space)
+    bc = DirichletCondenser(asm, space.boundary_dofs())
+    mass = asm.assemble(wf.mass())
+    stiff = asm.assemble(wf.diffusion())
+    named = f"unknown backend '{backend}'.*'csr'.*'matfree'"
+    with pytest.raises(ValueError, match=named):
+        PoissonProblem(unit_square_tri(4)).solve(backend=backend)
+    with pytest.raises(ValueError, match=named):
+        ThetaIntegrator(mass, stiff, 0.01, bc=bc, backend=backend)
+    with pytest.raises(ValueError, match=named):
+        GalerkinResidualLoss(asm, bc, backend=backend)
 
 
 # ---------------------------------------------------------------------------
-# consumers: losses, transient, deprecation shims
+# consumers: losses, transient
 # ---------------------------------------------------------------------------
 
 def test_galerkin_residual_loss_backends():
@@ -324,10 +300,9 @@ def test_galerkin_residual_loss_backends():
     asm = GalerkinAssembler(space)
     bc = DirichletCondenser(asm, space.boundary_dofs())
     u = jnp.asarray(RNG.standard_normal(space.num_dofs))
-    ref = float(GalerkinResidualLoss(asm, bc)(u))
-    for backend in ("ell", "ell_pallas", "matfree"):
-        val = float(GalerkinResidualLoss(asm, bc, backend=backend)(u))
-        assert abs(val - ref) < 1e-9 * max(1.0, abs(ref))
+    ref = float(GalerkinResidualLoss(asm, bc, backend="csr")(u))
+    val = float(GalerkinResidualLoss(asm, bc, backend="matfree")(u))
+    assert abs(val - ref) < 1e-9 * max(1.0, abs(ref))
 
 
 def test_theta_matfree_rollout_matches_csr():
@@ -358,6 +333,8 @@ def test_theta_matfree_rollout_matches_csr():
 
 
 def test_newmark_backend_dispatch():
+    """The Newmark rollout, whose predictor applies the stiffness CSR,
+    against Newmark steps on the dense free-DoF system in NumPy."""
     from repro.transient import NewmarkIntegrator
 
     space = CASES["P1_tri"]()
@@ -366,26 +343,27 @@ def test_newmark_backend_dispatch():
     mass = asm.assemble(wf.mass())
     stiff = asm.assemble(wf.diffusion())
     u0 = jnp.asarray(RNG.standard_normal(space.num_dofs)) * jnp.asarray(bc.free_mask)
-    t_csr = NewmarkIntegrator(mass, stiff, 0.01, bc=bc).rollout(u0, 3)
-    t_ell = NewmarkIntegrator(mass, stiff, 0.01, bc=bc, backend="ell").rollout(u0, 3)
-    np.testing.assert_allclose(np.asarray(t_ell), np.asarray(t_csr), atol=1e-10)
+    dt, beta, gamma, n_steps = 0.01, 0.25, 0.5, 3
+    traj = np.asarray(NewmarkIntegrator(mass, stiff, dt, bc=bc).rollout(u0, n_steps))
 
-
-def test_stepping_names_deprecated_but_working():
-    from repro.transient import stepping
-
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        backends = stepping.MATVEC_BACKENDS
-        mv_factory = stepping.make_matvec
-    assert {w.category for w in caught} == {DeprecationWarning}
-    assert "matfree" in backends
-    _, k, x = _small_system()
-    np.testing.assert_allclose(
-        np.asarray(mv_factory(k, "ell")(x)), np.asarray(k.matvec(x)), atol=1e-12
-    )
-    with pytest.raises(AttributeError):
-        stepping.not_a_name  # noqa: B018
+    free = np.asarray(bc.free_mask).astype(bool)
+    m_ff = np.asarray(mass.to_dense())[np.ix_(free, free)]
+    k_ff = np.asarray(stiff.to_dense())[np.ix_(free, free)]
+    u = np.asarray(u0)[free]
+    v = np.zeros_like(u)
+    a = np.linalg.solve(m_ff, -k_ff @ u)
+    want = []
+    for _ in range(n_steps):
+        u_star = u + dt * v + 0.5 * dt**2 * (1 - 2 * beta) * a
+        v_star = v + dt * (1 - gamma) * a
+        a = np.linalg.solve(m_ff + beta * dt**2 * k_ff, -k_ff @ u_star)
+        u = u_star + beta * dt**2 * a
+        v = v_star + gamma * dt * a
+        want.append(u)
+    scale = np.abs(traj).max()
+    np.testing.assert_allclose(traj[:, free], np.stack(want), rtol=0,
+                               atol=1e-8 * scale)
+    np.testing.assert_array_equal(traj[:, ~free], 0.0)
 
 
 def test_matfree_rejects_facet_terms_and_vector_arity():

@@ -1,4 +1,4 @@
-"""Multi-device sharded matrix-free Krylov + streaming-SpMV solve paths.
+"""Multi-device sharded matrix-free Krylov solve paths.
 
 ``ShardedMatFreeOperator`` partitions the gather → per-element action →
 scatter apply over the named FEM mesh axis (per-device partial touched-DoF
@@ -19,12 +19,9 @@ from repro.core import (
     DirichletCondenser,
     FunctionSpace,
     GalerkinAssembler,
-    MATVEC_BACKENDS,
     ShardedMatFreeOperator,
     assemble,
     build_plan,
-    make_matvec,
-    make_residual,
     matfree_operator,
     matfree_solve,
     sparse_solve,
@@ -186,15 +183,12 @@ def test_sharded_reapply_hits_compiled_executable():
 
 
 # ---------------------------------------------------------------------------
-# registry / consumer dispatch
+# consumer dispatch
 # ---------------------------------------------------------------------------
 
-def test_registry_has_streaming_and_sharded_backends():
-    assert set(MATVEC_BACKENDS) >= {"csr", "ell", "ell_pallas", "ell_stream",
-                                    "matfree", "matfree_sharded"}
-
-
 def test_registry_dispatch_parity():
+    """The sharded matrix-free apply, its residual, transpose apply and
+    diagonal against the CSR's."""
     m, space, plan = _setup(8)
     form = wf.diffusion(1.0) + 0.2 * wf.mass()
     k = assemble(plan, form)
@@ -202,24 +196,14 @@ def test_registry_dispatch_parity():
     x = jnp.asarray(RNG.standard_normal(k.shape[0]))
     f = jnp.asarray(RNG.standard_normal(k.shape[0]))
     ref = np.asarray(k.matvec(x))
-    for backend, target in [("ell_stream", k), ("matfree_sharded", op)]:
-        mv = make_matvec(target, backend)
-        rs = make_residual(target, backend)
-        np.testing.assert_allclose(np.asarray(mv(x)), ref, atol=1e-12)
-        np.testing.assert_allclose(
-            np.asarray(rs(x, f)), ref - np.asarray(f), atol=1e-12)
-    # already-sharded operators pass through unchanged
-    mv = make_matvec(op.sharded(), "matfree_sharded")
-    np.testing.assert_allclose(np.asarray(mv(x)), ref, atol=1e-12)
-
-
-def test_registry_sharded_rejects_csr():
-    m, space, plan = _setup(4)
-    k = assemble(plan, wf.diffusion())
-    with pytest.raises(TypeError, match="matrix-free"):
-        make_matvec(k, "matfree_sharded")
-    with pytest.raises(TypeError, match="CSR"):
-        make_matvec(matfree_operator(plan, wf.diffusion()), "ell_stream")
+    sop = op.sharded()
+    np.testing.assert_allclose(np.asarray(sop.matvec(x)), ref, atol=1e-12)
+    np.testing.assert_allclose(
+        np.asarray(sop.matvec(x) - f), ref - np.asarray(f), atol=1e-12)
+    np.testing.assert_allclose(np.asarray(sop.rmatvec(x)),
+                               np.asarray(k.rmatvec(x)), atol=1e-12)
+    np.testing.assert_allclose(np.asarray(sop.diagonal()),
+                               np.asarray(k.diagonal()), atol=1e-12)
 
 
 def test_poisson_problem_sharded_backend():
@@ -244,16 +228,3 @@ def test_theta_integrator_sharded_backend():
     np.testing.assert_allclose(
         np.asarray(t1.rollout(u0, 5)), np.asarray(t0.rollout(u0, 5)),
         atol=1e-12)
-
-
-# ---------------------------------------------------------------------------
-# streaming SpMV end-to-end: the CI-scale proof of the million-DOF path
-# (same kernel + schedule, reduced N; full N runs in bench_solver_scaling)
-# ---------------------------------------------------------------------------
-
-def test_streaming_backend_poisson_solve_end_to_end():
-    p = PoissonProblem(unit_square_tri(16))
-    r0 = p.solve(backend="csr", tol=1e-12)
-    r1 = p.solve(backend="ell_stream", tol=1e-12)
-    assert r1.converged
-    np.testing.assert_allclose(np.asarray(r1.u), np.asarray(r0.u), atol=1e-10)

@@ -91,7 +91,7 @@ def test_csr_matvec_equals_dense(n, seed):
     cell_dofs = rng.integers(0, n, size=(max(n // 2, 2), 3))
     routing = build_matrix_routing(cell_dofs, None, n)
     from repro.core.assembly import reduce_matrix
-    from repro.core.sparse import CSR, csr_to_ell
+    from repro.core.sparse import CSR
 
     vals = reduce_matrix(
         jnp.asarray(rng.normal(size=(cell_dofs.shape[0], 3, 3))), routing
@@ -102,8 +102,7 @@ def test_csr_matvec_equals_dense(n, seed):
     dense = np.asarray(a.to_dense())
     np.testing.assert_allclose(np.asarray(a.matvec(x)), dense @ np.asarray(x), atol=1e-11)
     np.testing.assert_allclose(np.asarray(a.rmatvec(x)), dense.T @ np.asarray(x), atol=1e-11)
-    ell = csr_to_ell(a)
-    np.testing.assert_allclose(np.asarray(ell.matvec(x)), dense @ np.asarray(x), atol=1e-11)
+    np.testing.assert_allclose(np.asarray(a.loop_matvec()(x)), dense @ np.asarray(x), atol=1e-11)
 
 
 _WF_CACHE = {}

@@ -148,7 +148,7 @@ def heat():
     return mass, stiff, bc, bc.project_residual(u0)
 
 
-@pytest.mark.parametrize("backend", ["csr", "ell"])
+@pytest.mark.parametrize("backend", ["csr", "matfree"])
 def test_rollout_info_trajectory(heat, backend):
     mass, stiff, bc, u0 = heat
     integ = ThetaIntegrator(mass, stiff,

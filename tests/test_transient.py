@@ -93,19 +93,6 @@ def test_theta_exact_on_linear_in_time_with_moving_dirichlet(heat_setup):
     np.testing.assert_allclose(np.asarray(traj[-1]), np.asarray(exact), atol=1e-10)
 
 
-def test_theta_ell_backend_matches_csr(heat_setup):
-    m, sp, asm, bc, mass, stiff = heat_setup
-    pts = sp.dof_points
-    u0 = (
-        jnp.sin(np.pi * jnp.asarray(pts[:, 0]))
-        * jnp.sin(np.pi * jnp.asarray(pts[:, 1]))
-    ) * bc.free_mask
-    kw = dict(dt=5e-3, theta=CRANK_NICOLSON, bc=bc, tol=1e-13)
-    a = ThetaIntegrator(mass, stiff, **kw).rollout(u0, 3)
-    b = ThetaIntegrator(mass, stiff, backend="ell", **kw).rollout(u0, 3)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-10)
-
-
 def test_grad_through_rollout_matches_finite_differences(heat_setup):
     """∂(trajectory loss)/∂κ through the scanned rollout (adjoint sparse
     solves) vs central finite differences — ≤1e-4 relative error."""

@@ -159,10 +159,13 @@ def test_elasticity_2d_matches_numpy_assembly():
 
 # vertex patterns by the layout they take: banded with pairs off the band
 # (the hollow cube's numbering skips the cavity), banded with none, and not
-# banded (the column table)
+# banded (the column table); the space has one component per dimension, as
+# elasticity's has
 BLOCK_PATTERNS = {
     "hollow12": (hollow_cube_tet(12), "remainder"),
     "cube6": (unit_cube_tet(6), "band"),
+    "cube3": (unit_cube_tet(3), "band"),
+    "square6_2d": (unit_square_tri(6), "band"),
     "renumbered": (_renumbered(hollow_cube_tet(6)), "columns"),
 }
 
@@ -171,7 +174,7 @@ BLOCK_PATTERNS = {
 def test_vertex_block_matvec_matches_column_table(monkeypatch, which):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh, form = BLOCK_PATTERNS[which]
-    space = _space(mesh, 3)
+    space = _space(mesh, mesh.dim)
     plan = build_plan(space)
     vals = jnp.asarray(np.random.default_rng(2).standard_normal(plan.nnz))
     k = plan.csr(vals)
